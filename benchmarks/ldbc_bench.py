@@ -16,12 +16,14 @@ Usage: python benchmarks/ldbc_bench.py [--persons 20000] [--roots 64]
                                        [--json out]
 """
 
+import os as _os
 import sys as _sys
 
-_sys.path.insert(0, "/root/repo") if "/root/repo" not in _sys.path else None
-from dgraph_tpu.devsetup import maybe_force_cpu
+_REPO = _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__)))
+if _REPO not in _sys.path:  # `python benchmarks/x.py` puts only benchmarks/ there
+    _sys.path.insert(0, _REPO)
 
-maybe_force_cpu()
+import dgraph_tpu  # noqa: E402,F401 — places the compile cache before jax loads
 
 import argparse
 import json

@@ -7,7 +7,7 @@ star in films; names are exact/term-indexed strings.
 
 The generator returns BOTH the RDF stream and a plain-Python graph model,
 so conformance goldens are DERIVED independently of the engine
-(VERDICT r1 next-round #4: no hand-typed goldens) — any query the suite
+(no hand-typed goldens) — any query the suite
 runs is answered twice: once by the engine, once by direct dict walks
 here, and the two must agree.
 

@@ -87,6 +87,10 @@ class _Handler(BaseHTTPRequestHandler):
                 self.close_connection = True
                 return
         if path == "/health":
+            from dgraph_tpu.x import device
+
+            # platform / device_kind / device_count: a client can refuse
+            # an answer that did not come from a chip
             self._reply(
                 [
                     {
@@ -94,6 +98,7 @@ class _Handler(BaseHTTPRequestHandler):
                         "status": "healthy",
                         "version": "0.1.0",
                         "uptime": int(time.time() - _START),
+                        **device.info(),
                     }
                 ]
             )
@@ -696,6 +701,12 @@ def _split_rdf_blocks(body: str):
     return body, ""
 
 
+class _Listener(ThreadingHTTPServer):
+    # socketserver's default listen backlog is 5: a burst of 64 clients
+    # connecting at once overflowed it and saw connection resets
+    request_queue_size = 128
+
+
 class HTTPServer:
     """Embeddable HTTP server (the Alpha's 8080 surface)."""
 
@@ -705,7 +716,7 @@ class HTTPServer:
             (_Handler,),
             {"engine": engine, "txns": {}, "txn_owner": {}, "metrics": {}},
         )
-        self.httpd = ThreadingHTTPServer((host, port), handler)
+        self.httpd = _Listener((host, port), handler)
         self.port = self.httpd.server_address[1]
         self._thread: Optional[threading.Thread] = None
 

@@ -36,9 +36,11 @@ import time
 
 
 def _server(args):
+    from dgraph_tpu import native
     from dgraph_tpu.api.server import Server
     from dgraph_tpu.x.flags import STORAGE_DEFAULTS, SuperFlag
 
+    native.require()  # a failed kernel build is an error, not the mirrors
     key = None
     if getattr(args, "encryption_key_file", None):
         from dgraph_tpu.enc.enc import read_key_file
@@ -59,7 +61,11 @@ def _server(args):
 
 def cmd_alpha(args):
     from dgraph_tpu.api.http_server import HTTPServer
+    from dgraph_tpu.x import device
 
+    # initializes the backend: no accelerator (unless JAX_PLATFORMS=cpu
+    # asked for the CPU) stops the alpha here, before it serves anything
+    print(f"alpha {device.describe()}")
     if getattr(args, "cluster", ""):
         from dgraph_tpu.worker.facade import ClusterFacade
         from dgraph_tpu.worker.groups import DistributedCluster

@@ -2,9 +2,11 @@
 
 The C++ layer covers the host-side hot paths (SURVEY.md §2.7): the
 bit-pack codec used by UID pack (de)serialization and the scalar sorted-set
-ops used by the dispatcher's small-op fallback. Python/numpy fallbacks keep
-everything working where no compiler exists (`NATIVE_AVAILABLE` tells you
-which you got).
+ops used by the dispatcher's small-op fallback. Python/numpy mirrors keep
+the library importable where no compiler exists (`NATIVE_AVAILABLE` tells
+you which you got, `BUILD_ERROR` why); a process that serves calls
+`require()` at start-up, so a failed build is an error there and never a
+quiet switch to the mirrors.
 """
 
 from __future__ import annotations
@@ -22,6 +24,7 @@ from dgraph_tpu.x import config
 
 _LIB: Optional[ctypes.CDLL] = None
 NATIVE_AVAILABLE = False
+BUILD_ERROR: Optional[str] = None  # why the build/load failed, if it did
 
 # ---------------------------------------------------------------------------
 # ctypes ABI declarations
@@ -206,7 +209,7 @@ _SAN_FLAGS = {
 }
 
 
-def _build_and_load() -> Optional[ctypes.CDLL]:
+def _build_and_load() -> ctypes.CDLL:
     here = os.path.dirname(__file__)
     srcs = [
         os.path.join(here, "codec.cpp"),
@@ -220,7 +223,8 @@ def _build_and_load() -> Optional[ctypes.CDLL]:
     san = config.get("NATIVE_SAN").strip().lower()
     san_flags = _SAN_FLAGS.get(san)
     if san_flags is None:
-        return None  # unknown sanitizer name: fail to python, don't guess
+        # unknown sanitizer name: fail the build, don't guess
+        raise ValueError(f"unknown DGRAPH_TPU_NATIVE_SAN={san!r}")
     if san:
         tag = f"{tag}-{san}"
     cache_dir = config.get("NATIVE_CACHE") or os.path.join(
@@ -242,15 +246,9 @@ def _build_and_load() -> Optional[ctypes.CDLL]:
             )
         except (subprocess.CalledProcessError, FileNotFoundError,
                 subprocess.TimeoutExpired):
-            try:
-                subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-            except Exception:
-                return None
+            subprocess.run(cmd, check=True, capture_output=True, timeout=120)
         os.replace(tmp, so_path)
-    try:
-        lib = ctypes.CDLL(so_path)
-    except OSError:
-        return None
+    lib = ctypes.CDLL(so_path)
     for name, (restype, argtypes) in DECLS.items():
         fn = getattr(lib, name)
         fn.restype = restype
@@ -264,10 +262,23 @@ def _ptr(arr: np.ndarray, ctype):
 
 try:
     _LIB = _build_and_load()
-    NATIVE_AVAILABLE = _LIB is not None
-except Exception:
-    _LIB = None
-    NATIVE_AVAILABLE = False
+    NATIVE_AVAILABLE = True
+except Exception as _e:  # no compiler, failed compile, unloadable .so
+    # CalledProcessError carries the compiler's stderr
+    BUILD_ERROR = "{!r} {}".format(
+        _e,
+        (getattr(_e, "stderr", None) or b"")[-2000:].decode(errors="replace"),
+    ).strip()
+
+
+def require() -> None:
+    """Raise unless the compiled kernels are loaded — the start-up check
+    of every serving entry point (cli alpha/bulk, chip_smoke.py)."""
+    if not NATIVE_AVAILABLE:
+        raise RuntimeError(
+            "native host kernels failed to build or load "
+            f"(dgraph_tpu/native): {BUILD_ERROR}"
+        )
 
 
 # ---------------------------------------------------------------------------

@@ -1,29 +1,32 @@
 """Pallas TPU kernel for the membership hot loop.
 
 The XLA path (ops/setops.py) lowers membership to searchsorted — binary
-search with gathers, which the TPU executes but does not love. This kernel
-reformulates small-side membership as a *compare-all sweep*: the query set
-(<=128 uids, one VREG lane row) is compared against every 8x128 tile of the
-big sorted list with pure VPU broadcasting — zero gathers, zero
-data-dependent control flow. For the dominant fan-out shape (tiny src list
-vs huge posting list, the reference's IntersectWith ratio>32 regime,
-algo/uidlist.go:156) the sweep is bandwidth-bound at HBM speed, which is
-the roofline for this op.
+search with gathers. This kernel reformulates small-side membership as a
+*compare-all sweep*: the query set (<=128 uids, one VREG lane row) is
+compared against every 8x128 tile of the big sorted list with pure VPU
+broadcasting — zero gathers, zero data-dependent control flow (the
+reference's IntersectWith ratio>32 regime, algo/uidlist.go:156).
+
+Off by default (DGRAPH_TPU_PALLAS). It compiles with Mosaic and matches
+numpy on a TPU v5e (jax 0.9.0, libtpu 0.0.34) at 256 rows x <=128
+queries vs 2^20 — and is far from its bandwidth bound there: 323 ms per
+batch against 15.5 ms for the vmapped XLA searchsorted on the same
+operands (PERF.md, PR 21). One grid step per 4 KiB tile is the likely
+cost; it has not been profiled.
 
 The kernel is written BATCH-AWARE (grid = (batch, b_tiles), block specs
 indexed by batch) rather than as a vmapped single example: Pallas TPU
 lowering rejects the Squeezed SMEM blocks that jax.vmap produces for the
-scalar length operand (found the first time the kernel ran compiled on a
-real v5e — interpret mode accepts them).
+scalar length operand (interpret mode accepts them).
 
 Grid: for each batch row, one step per b-tile; the hit-mask accumulates
 across steps via output revisiting (out block index is constant in the
 tile dimension). TPU grids iterate the last axis fastest, so the
 `step == 0` init runs before that row's accumulation.
 
-Correctness is validated in interpret mode on CPU (tests). The dispatcher
-uses this path for intersect buckets with <=128-element small sides when
-DGRAPH_TPU_PALLAS=1 (query/dispatch.py).
+The tests run it in interpret mode on CPU; on a TPU it always runs
+compiled. The dispatcher uses this path for intersect buckets with
+<=128-element small sides when DGRAPH_TPU_PALLAS=1 (query/dispatch.py).
 """
 
 from __future__ import annotations
@@ -42,12 +45,11 @@ TILE = LANE * SUBLANE  # 1024 u32 per b-tile
 
 
 def _default_interpret() -> bool:
-    """Pallas TPU kernels only run compiled on real TPUs; everywhere else
-    use interpret mode. Resolved from the live backend (the env var can
-    disagree with the configured platform, e.g. under the test conftest)."""
-    import jax
+    """Compiled on a TPU, always; the interpreter only where Mosaic does
+    not exist (the CPU the tests ask for)."""
+    from dgraph_tpu.x import device
 
-    return jax.default_backend() != "tpu"
+    return device.platform() != "tpu"
 
 
 def _member_kernel(lb_ref, a_ref, b_ref, out_ref):

@@ -31,35 +31,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from dgraph_tpu.ops import setops
 
 
-def _resolve_shard_map():
-    """The shard_map entry point across jax versions: `jax.shard_map`
-    (0.5+, takes check_vma=) when present, else the experimental module
-    (0.4.x, same semantics but the kwarg is check_rep=). Returns
-    (callable, vma_supported)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm, True
-    from jax.experimental.shard_map import shard_map as sm_exp
-
-    return sm_exp, False
-
-
-_SHARD_MAP, _SHARD_MAP_VMA = _resolve_shard_map()
-
-
-def shard_map_compat(f=None, *, mesh, in_specs, out_specs, check_vma=None):
-    """Version-portable shard_map: maps the replication-check kwarg to
-    whichever spelling the installed jax understands (check_vma on
-    current jax, check_rep on 0.4.x) and omits it when unset. Usable
-    exactly like jax.shard_map, including via functools.partial."""
-    kw = {"mesh": mesh, "in_specs": in_specs, "out_specs": out_specs}
-    if check_vma is not None:
-        kw["check_vma" if _SHARD_MAP_VMA else "check_rep"] = check_vma
-    if f is None:
-        return partial(_SHARD_MAP, **kw)
-    return _SHARD_MAP(f, **kw)
-
-
 def make_mesh(n_devices: Optional[int] = None, axis: str = "data") -> Mesh:
     devs = jax.devices()
     n = n_devices or len(devs)
@@ -77,7 +48,7 @@ def sharded_membership(mesh: Mesh, a: jnp.ndarray, la, b: jnp.ndarray, lb):
     """mask over row-sharded `a` (padded multiple of n_devices)."""
 
     @partial(
-        shard_map_compat,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P("data"), P(), P(), P()),
         out_specs=P("data"),
@@ -106,7 +77,7 @@ def sharded_rows_membership(mesh: Mesh, A, LA, b, lb):
     (psum>0). Ref worker/task.go fan-out replaced by one collective."""
 
     @partial(
-        shard_map_compat,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(), P(), P("data"), P()),
         out_specs=P(),
@@ -130,7 +101,7 @@ def sharded_intersect_count(mesh: Mesh, a, la, b, lb):
     (psum over the mesh)."""
 
     @partial(
-        shard_map_compat,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P("data"), P(), P(), P()),
         out_specs=P(),
@@ -155,7 +126,7 @@ def sharded_topk(mesh: Mesh, V: jnp.ndarray, valid: jnp.ndarray, q: jnp.ndarray,
     """Returns (global top-k squared-euclidean distances, global row ids)."""
 
     @partial(
-        shard_map_compat,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P("data"), P("data"), P()),
         out_specs=(P(), P()),
@@ -194,7 +165,7 @@ def sharded_kmeans_step(mesh: Mesh, X: jnp.ndarray, valid: jnp.ndarray, C: jnp.n
     nclusters = C.shape[0]
 
     @partial(
-        shard_map_compat,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P("data"), P("data"), P()),
         out_specs=P(),
@@ -202,7 +173,13 @@ def sharded_kmeans_step(mesh: Mesh, X: jnp.ndarray, valid: jnp.ndarray, C: jnp.n
     def _step(X_tile, valid_tile, C_all):
         xsq = (X_tile * X_tile).sum(axis=1)
         csq = (C_all * C_all).sum(axis=1)
-        d2 = xsq[:, None] - 2.0 * (X_tile @ C_all.T) + csq[None, :]
+        # full f32 on the MXU: the TPU default is one bf16 pass, which
+        # would train different centroids than the host does
+        d2 = (
+            xsq[:, None]
+            - 2.0 * jnp.matmul(X_tile, C_all.T, precision="highest")
+            + csq[None, :]
+        )
         assign = jnp.argmin(d2, axis=1)
         w = valid_tile.astype(X_tile.dtype)
         sums = jax.ops.segment_sum(

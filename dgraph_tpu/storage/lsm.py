@@ -2,7 +2,7 @@
 
 The reference keeps everything in BadgerDB (LSM tree + value log,
 /root/reference/worker/server_state.go:95); round-1's MemKV held the whole
-DB in RAM (VERDICT r1 missing #9). LsmKV bounds memory:
+DB in RAM. LsmKV bounds memory:
 
   - writes land in a WAL-backed memtable;
   - when the memtable exceeds `memtable_bytes` it flushes to an immutable
@@ -827,8 +827,7 @@ class LsmKV(KV):
         """ONE streaming k-way merge over every table + memtable snapshot,
         grouped by key: yields (key, {ts: (seq, val)}) with markers applied.
         Replaces the per-key re-probe pattern (O(keys*tables) seeks) that
-        made multi-table iteration 10-100x slower than a single table
-        (VERDICT r2 weak #2 / next #2)."""
+        made multi-table iteration 10-100x slower than a single table."""
         import heapq
 
         with self._mu:

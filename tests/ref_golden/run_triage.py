@@ -8,9 +8,8 @@ import os
 import sys
 import traceback
 
-sys.path.insert(0, "/root/repo")
-
 HERE = os.path.dirname(os.path.abspath(__file__))
+sys.path.insert(0, os.path.dirname(os.path.dirname(HERE)))
 
 
 def canon(x):

@@ -37,7 +37,8 @@ def _spawn_alpha(*extra):
     port = _free_port()
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    env["PYTHONPATH"] = "/root/repo" + os.pathsep + env.get("PYTHONPATH", "")
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env["PYTHONPATH"] = repo + os.pathsep + env.get("PYTHONPATH", "")
     proc = subprocess.Popen(
         [
             sys.executable, "-m", "dgraph_tpu", "alpha",

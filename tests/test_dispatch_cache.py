@@ -1,8 +1,8 @@
 """Device cache + batched chain dispatch + round-1 advisory fixes.
 
-Covers VERDICT r1 next-round #2 (device-resident pack cache, true level
-batching) and the ADVICE r1 findings (reindex aggregation, commit
-visibility barrier, oracle GC, corrupt-record validation).
+Covers the device-resident pack cache and true level batching, plus
+reindex aggregation, the commit visibility barrier, oracle GC and
+corrupt-record validation.
 """
 
 import numpy as np
@@ -138,7 +138,7 @@ def test_corrupt_record_raises():
 
 
 def test_cached_operands_transfer_zero_bytes_on_reuse(monkeypatch):
-    """VERDICT r4 #2: with version tokens present, a repeat dispatch of
+    """With version tokens present, a repeat dispatch of
     the same operands must perform ZERO new host->device transfers —
     the padded uploads are HBM-resident in the DeviceCache."""
     import jax.numpy as jnp_mod

@@ -263,7 +263,7 @@ def test_trailing_bytes_after_stream_rejected():
 
 def test_typed_messages_roundtrip():
     """conn/messages.py: pb-wire-format codec roundtrips every schema
-    (the typed control plane of VERDICT r4 #6)."""
+    (the typed control plane)."""
     from dgraph_tpu.conn import messages as M
 
     kvl = M.KVList(

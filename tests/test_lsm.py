@@ -1,4 +1,4 @@
-"""LsmKV: spill-to-disk storage engine (VERDICT r1 missing #9; ref
+"""LsmKV: spill-to-disk storage engine (ref
 BadgerDB's role at worker/server_state.go:95).
 """
 

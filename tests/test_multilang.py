@@ -70,8 +70,8 @@ def test_engine_lang_aware_fulltext():
 
 
 def test_cjk_fulltext_bigrams():
-    """CJK analyzer (ref tok.go bleve cjk analyzer for zh/ja/ko —
-    thrice-carried VERDICT item): ideograph runs index as overlapping
+    """CJK analyzer (ref tok.go bleve cjk analyzer for zh/ja/ko):
+    ideograph runs index as overlapping
     bigrams, searchable via alloftext with @lang."""
     from dgraph_tpu.api.server import Server
 

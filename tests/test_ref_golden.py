@@ -7,8 +7,8 @@ extract_goldens.py) against the ported common_test.go fixture
 extract_fixture.py), comparing with testify-JSONEq semantics (exact
 structure; Go numbers are float64).
 
-This replaces self-derived goldens with the reference's own answers
-(VERDICT r2 missing #1). Cases the engine doesn't match yet are tracked in
+This replaces self-derived goldens with the reference's own answers.
+Cases the engine doesn't match yet are tracked in
 known_fails.json and xfail — shrinking that file is the conformance metric
 (currently 444/535 exact).
 """

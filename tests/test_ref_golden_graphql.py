@@ -1,4 +1,4 @@
-"""GraphQL conformance against the reference's own oracles (VERDICT r3 #4).
+"""GraphQL conformance against the reference's own oracles.
 
 Two tiers, mirroring how tests/test_ref_golden.py gave DQL its oracle:
 
@@ -372,7 +372,7 @@ def _normalize_pair(ours_data, ref_data):
     if not _has_vd(got):
         want = _drop_vd(want)
     # rewriter helper blocks appear in the dgquery response but have no
-    # GraphQL counterpart — an EXPLICIT allowlist only (VERDICT r4 #5:
+    # GraphQL counterpart — an EXPLICIT allowlist only:
     # a blanket subset-drop would also hide root fields our resolver
     # silently failed to return)
     _HELPER_KEYS = ("checkPwd",)

@@ -1,5 +1,4 @@
-"""GraphQL @auth conformance against the reference's rewriter oracles
-(VERDICT r4 #3, auth half).
+"""GraphQL @auth conformance against the reference's rewriter oracles.
 
 Cases: tests/ref_golden_graphql/auth_cases.json, extracted from
 /root/reference/graphql/resolve/auth_*_test.yaml (driven there by

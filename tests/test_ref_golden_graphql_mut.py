@@ -1,5 +1,5 @@
 """GraphQL *mutation* conformance against the reference's rewriter
-oracles (VERDICT r4 #3).
+oracles.
 
 Cases: tests/ref_golden_graphql/mutation_cases.json, extracted from
 /root/reference/graphql/resolve/{add,update,delete,validate}_mutation_test.yaml

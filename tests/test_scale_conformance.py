@@ -1,13 +1,16 @@
 """Scale-suite conformance at test size: engine answers vs goldens
-derived independently from the corpus model (VERDICT r1 next-round #4 —
-goldens by reasoned derivation, not hand-typed).
+derived independently from the corpus model (goldens by reasoned
+derivation, not hand-typed).
 """
 
+import os
 import sys
 
 
 def test_scale_suite_conformance():
-    sys.path.insert(0, "/root/repo")
+    sys.path.insert(
+        0, os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    )
     from benchmarks.movie_corpus import generate
     from benchmarks.scale_suite import load, run_suite
 

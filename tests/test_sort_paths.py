@@ -1,5 +1,5 @@
-"""Index-assisted and device top-k ordering (VERDICT r1 next-round #9;
-ref worker/sort.go:189 sortWithIndex, :245 sortWithoutIndex).
+"""Index-assisted and device top-k ordering (ref
+worker/sort.go:189 sortWithIndex, :245 sortWithoutIndex).
 """
 
 import numpy as np

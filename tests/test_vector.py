@@ -158,7 +158,7 @@ def test_mesh_sharded_engine_search(monkeypatch):
         idx.insert(i + 1, V[i])
     q = V[17] + 0.001 * rng.standard_normal(d).astype(np.float32)
     got = idx.search(q, 5)
-    assert idx._mesh is not None  # actually sharded
+    assert idx._device["mesh"] is not None  # actually sharded
     # exact result parity with the single-device brute force
     monkeypatch.delenv("DGRAPH_TPU_SHARD_VECTORS")
     idx2 = VectorIndex("m2", ivf_threshold=1 << 62)
@@ -188,3 +188,78 @@ def test_mesh_sharded_engine_search(monkeypatch):
         '{ q(func: similar_to(emb, 3, "%s")) { name } }' % vec_str
     )
     assert out["data"]["q"][0]["name"] == "v8"
+
+
+def _small_jit_index(ivf_threshold):
+    """300 rows: below the quantized engine's floor, so searches take the
+    jitted device path on the CPU backend too."""
+    import numpy as np
+
+    from dgraph_tpu.models.vector import VectorIndex
+
+    rng = np.random.default_rng(5)
+    V = rng.standard_normal((300, 16)).astype(np.float32)
+    idx = VectorIndex("r", ivf_threshold=ivf_threshold)
+    idx.bulk_load(np.arange(1, 301, dtype=np.uint64), V)
+    return idx, V
+
+
+def test_device_rebuild_releases_old_snapshot_first_and_retries(monkeypatch):
+    """At 1M x 768 the old and the new device copy do not fit in HBM
+    together: the rebuild after an insert lets go of the old snapshot
+    before it uploads, and a rebuild that dies leaves nothing half-built
+    to serve — the next search builds again."""
+    import numpy as np
+    import pytest
+
+    from dgraph_tpu.models.vector import VectorIndex
+
+    idx, V = _small_jit_index(ivf_threshold=100)
+    assert idx.search(V[7], 1)[0] == 8
+    assert idx._device is not None and idx._ivf is not None
+
+    import gc
+    import weakref
+
+    new = (V[7] + 5.0).astype(np.float32)
+    idx.insert(999, new)
+    old_corpus = weakref.ref(idx._device["vecs"])
+    old_slabs = weakref.ref(idx._ivf["dev"]["flat_vecs"])
+    seen_during_build = []
+
+    def dies(self, mat):
+        gc.collect()
+        # not only unlinked from the index: no frame on the way here may
+        # still name the old arrays, or their HBM stays allocated
+        seen_during_build.append(
+            (self._device, old_corpus() is None, old_slabs() is None)
+        )
+        raise MemoryError("RESOURCE_EXHAUSTED")
+
+    with monkeypatch.context() as m:
+        m.setattr(VectorIndex, "_train_ivf", dies)
+        with pytest.raises(MemoryError):
+            idx.search(new, 1)
+    assert seen_during_build == [(None, True, True)]
+    assert idx._device is None
+    assert idx.search(new, 1)[0] == 999  # rebuilt, and the insert is in it
+
+
+def test_concurrent_searches_share_one_device_rebuild():
+    import time
+    from concurrent.futures import ThreadPoolExecutor
+
+    idx, V = _small_jit_index(ivf_threshold=1 << 62)
+    rebuilds = []
+    orig = idx._rebuild_device
+
+    def slow_rebuild():
+        rebuilds.append(1)
+        time.sleep(0.05)
+        orig()
+
+    idx._rebuild_device = slow_rebuild
+    with ThreadPoolExecutor(8) as pool:
+        got = list(pool.map(lambda i: idx.search(V[i], 1)[0], range(8)))
+    assert got == list(range(1, 9))
+    assert len(rebuilds) == 1
