@@ -1,0 +1,442 @@
+"""chipbench: one cell of BENCHMARK.json, measured on the served path.
+
+  python3 -m chipbench.run --workload <cell> --seed <n> --seconds <s> --trace <0|1>
+
+The process that holds the chip runs the alpha (`chipbench/alpha.py`);
+the closed-loop clients run in a child process (`chipbench/client.py`).
+Set-up (data, store, index, upload, compile or cache load, warm-up)
+ends when the window's first request is sent. After the window closes
+the device's peak memory is read, the alpha is stopped, and the answers
+the window produced are compared with the plain reference.
+
+Everything that belongs to one configuration, mix, query kind or layer
+metric is a file of its own, found by the name in BENCHMARK.json:
+configs/<config>.json, data/<maker>.py, mixes/<traffic>.json,
+queries/<kind>.py, layer_metrics/<metric>.py.
+
+Without a TPU it exits non-zero and prints no result. `--rehearsal` runs
+the same code at the configuration's tiny `rehearsal` sizes on whatever
+platform jax has; its output says so and is never a result.
+"""
+
+from __future__ import annotations
+
+import time
+
+T0 = time.perf_counter()  # process start, as near as Python lets us
+
+import argparse
+import gc
+import hashlib
+import importlib
+import json
+import os
+import pickle
+import re
+import shutil
+import subprocess
+import sys
+import tempfile
+
+import numpy as np
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(HERE)
+KEEP_STORES = 16  # over the seeds of a check's two sets, so that none is evicted
+QUIET_ROUNDS, CAP_ROUNDS = 2, 12  # warm-up: rounds that compile nothing, cap
+
+
+def say(msg: str) -> None:
+    print(f"[{time.perf_counter() - T0:7.1f}s] {msg}", file=sys.stderr,
+          flush=True)
+
+
+def load_json(path: str) -> dict:
+    with open(path) as f:
+        return json.load(f)
+
+
+def find_cell(bench: dict, name: str):
+    cells = {w["name"]: w for w in bench["workloads"]}
+    if name not in cells:
+        raise SystemExit(f"chipbench: no cell {name!r} in BENCHMARK.json "
+                         f"(has {sorted(cells)})")
+    cell = cells[name]
+    entry = {c["name"]: c for c in bench["configs"]}[cell["config"]]
+    config = load_json(os.path.join(ROOT, entry["file"]))
+    mix = load_json(os.path.join(HERE, "mixes", cell["traffic"] + ".json"))
+    return cell, config, mix
+
+
+def metrics_of(bench: dict, group: str, cell: str) -> list:
+    return [m for m in bench[group]
+            if "workloads" not in m or cell in m["workloads"]]
+
+
+def store_dir(config: dict, maker, seed: int) -> str:
+    """chipbench/.store/<config>-<seed>-<hash of the maker's source and
+    the sizes>: kept between the runs of a cell, as the compile cache
+    is; the oldest beyond KEEP_STORES are removed."""
+    with open(maker.__file__, "rb") as f:
+        h = hashlib.sha256(f.read())
+    h.update(json.dumps([config["sizes"], config.get("assumed")],
+                        sort_keys=True).encode())
+    base = os.path.join(HERE, ".store")
+    os.makedirs(base, exist_ok=True)
+    mine = f"{config['name']}-{seed}-{h.hexdigest()[:12]}"
+    old = sorted((d for d in os.listdir(base) if d != mine),
+                 key=lambda d: os.path.getmtime(os.path.join(base, d)))
+    for d in old[: max(0, len(old) - (KEEP_STORES - 1))]:
+        shutil.rmtree(os.path.join(base, d), ignore_errors=True)
+    return os.path.join(base, mine)
+
+
+class Child:
+    """The load generator's process and its line protocol."""
+
+    def __init__(self, spec: dict):
+        path = os.pathsep.join(
+            p for p in (ROOT, os.environ.get("PYTHONPATH")) if p)
+        env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=path)
+        self.proc = subprocess.Popen(
+            [sys.executable, "-m", "chipbench.client"], cwd=ROOT, env=env,
+            stdin=subprocess.PIPE, stdout=subprocess.PIPE, text=True)
+        self.ask(spec)
+
+    def send(self, obj: dict) -> None:
+        self.proc.stdin.write(json.dumps(obj) + "\n")
+        self.proc.stdin.flush()
+
+    def recv(self) -> dict:
+        line = self.proc.stdout.readline()
+        if not line:
+            raise RuntimeError("the load generator died")
+        return json.loads(line)
+
+    def ask(self, obj: dict) -> dict:
+        self.send(obj)
+        return self.recv()
+
+    def close(self) -> None:
+        if self.proc.poll() is None:
+            try:
+                self.send({"cmd": "stop"})
+                self.proc.wait(timeout=10)
+            except (OSError, subprocess.TimeoutExpired):
+                self.proc.kill()
+        self.proc.wait()
+
+
+def warm_up(child: Child, clock, mix: dict) -> dict:
+    """Run the mix itself, one request per client at a time, until
+    QUIET_ROUNDS rounds in a row compiled nothing, under CAP_ROUNDS. Before
+    that, where the query kinds know shape classes, send a warm-up
+    request of every class the window's planned requests have: which
+    pow2 buckets a request reaches depends on the roots drawn, and a
+    rare bucket would otherwise compile inside the window."""
+    quiet = rounds = 0
+    first_s = None
+    covered = None
+    while quiet < QUIET_ROUNDS and rounds < CAP_ROUNDS:
+        before = clock.compiles
+        r = child.ask({"cmd": "warm"})
+        if r["errors"]:
+            raise RuntimeError(f"warm-up request failed: {r['errors'][0]}")
+        first_s = r["seconds"] if first_s is None else first_s
+        if covered is None:
+            covered = child.ask({"cmd": "cover"})
+            if covered["errors"]:
+                raise RuntimeError(
+                    f"warm-up request failed: {covered['errors'][0]}")
+        rounds += 1
+        quiet = quiet + 1 if clock.compiles == before else 0
+    return {"rounds": rounds, "quiet": quiet, "first_round_s": first_s,
+            "cover": {k: v for k, v in covered.items() if k != "errors"}}
+
+
+def percentile(values, p: float) -> float:
+    return float(np.percentile(np.asarray(values, np.float64), p))
+
+
+def end_to_end(name: str, recs: list, t0: float, seconds: float,
+               setup_s: float):
+    """One end-to-end metric over ALL requests of the window."""
+    if name == "setup_s":
+        return setup_s
+    if name == "qps":
+        done = sum(1 for r in recs
+                   if r["error"] is None and r["done"] <= t0 + seconds)
+        return done / seconds
+    m = re.fullmatch(r"latency_p(\d+)_ms", name)
+    if m and recs:
+        return percentile([(r["done"] - r["sent"]) * 1e3 for r in recs],
+                          float(m.group(1)))
+    return None
+
+
+def sample_records(recs: list, mix: dict, seed: int) -> list:
+    """The answers compared: all of them, or a sample drawn from the
+    seed with the slowest request in it."""
+    ok = [r for r in recs if r["error"] is None]
+    n = mix.get("compare_sample", 0)
+    if not n or len(ok) <= n:
+        return ok
+    slowest = max(range(len(ok)), key=lambda i: ok[i]["done"] - ok[i]["sent"])
+    pick = set(np.random.default_rng([seed, 77]).choice(
+        len(ok), n, replace=False).tolist()) | {slowest}
+    return [ok[i] for i in sorted(pick)]
+
+
+AGG = {"sum": np.sum, "mean": np.mean, "max": np.max, "min": np.min}
+OPS = {"<=": lambda v, lim: v <= lim, ">=": lambda v, lim: v >= lim}
+
+
+def kind_of(entry: dict):
+    return importlib.import_module(f"chipbench.queries.{entry['kind']}")
+
+
+def numbers_of(mix: dict, model, recs: list, captured=None,
+               answers_of=None) -> dict:
+    """{name: [one number per answer or tapped probe]} from every query
+    kind's `check`. `captured` is what the data maker tapped from the
+    timed programs, if it taps any. `answers_of(kind, params, keys,
+    answers)` -> (answers, captured) replaces what the program produced
+    (the control, a planted fault)."""
+    numbers: dict = {}
+    for ki, k in enumerate(mix["kinds"]):
+        kind = kind_of(k)
+        mine = [r for r in recs if r["kind"] == ki]
+        if not mine:
+            continue
+        keys = [r["key"] for r in mine]
+        answers = [r["answer"] for r in mine]
+        if answers_of is not None:
+            answers, captured = answers_of(kind, k["params"], keys, answers)
+        for name, vals in kind.check(model, k["params"], keys, answers,
+                                     captured).items():
+            numbers.setdefault(name, []).extend(vals)
+    return numbers
+
+
+def judge(config: dict, numbers: dict, failed_requests: int) -> dict:
+    """{name: {"value", "op", "limit", "ok"}} for every number the
+    configuration's file gives a limit."""
+    out = {}
+    for name, rule in config["checks"].items():
+        vals = numbers.get(name, [])
+        value = float(AGG[rule["agg"]](vals)) if vals else 0.0
+        out[name] = {"value": value, "op": rule["op"], "limit": rule["limit"],
+                     "ok": bool(OPS[rule["op"]](value, rule["limit"]))}
+    out["failed_requests"] = {"value": failed_requests, "op": "<=",
+                              "limit": 0, "ok": failed_requests == 0}
+    return out
+
+
+def traced(child: Child, seconds: float, mix: dict, out_path: str):
+    """Send the window and trace a few seconds of its steady part."""
+    import jax
+
+    lead = min(2.0, seconds / 4)
+    span = max(0.5, min(mix.get("trace_seconds", 5), seconds - lead - 0.5))
+    tdir = tempfile.mkdtemp(prefix="chipbench_trace_")
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    child.send({"cmd": "run", "seconds": seconds, "out": out_path})
+    time.sleep(lead)
+    t_a = time.perf_counter()
+    jax.profiler.start_trace(tdir, profiler_options=opts)
+    time.sleep(span)
+    t_b = time.perf_counter()
+    jax.profiler.stop_trace()
+    reply = child.recv()
+    return reply, {"dir": tdir, "start": t_a, "stop": t_b}
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seed", type=int, required=True)
+    ap.add_argument("--seconds", type=float, required=True)
+    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    ap.add_argument("--rehearsal", action="store_true",
+                    help="tiny sizes on whatever platform jax has; never a "
+                    "result")
+    args = ap.parse_args(argv)
+    result = run(args)
+    print(json.dumps(result))
+    return 0
+
+
+def run(args, after=None) -> dict:
+    """One run of one cell. `after(state)` (the control) is called with
+    what the comparison had in hand — model, config, mix, sample and the
+    program's numbers — and what it returns goes into the result under
+    "after"."""
+    bench = load_json(os.path.join(ROOT, "BENCHMARK.json"))
+    cell, config, mix = find_cell(bench, args.workload)
+    if args.rehearsal:
+        config = dict(config, sizes=dict(config["sizes"],
+                                         **config["rehearsal"]))
+
+    # the device first, before any data is built. dgraph_tpu before jax:
+    # the package places the persistent compile cache in the checkout
+    # (<checkout>/.jax_cache) unless JAX_COMPILATION_CACHE_DIR says where
+    import dgraph_tpu  # noqa: F401
+    import jax
+
+    from chipbench import alpha as alpha_mod
+    from chipbench import trace_reduce
+
+    devs = jax.devices()
+    device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
+              "count": len(devs)}
+    if not args.rehearsal:
+        if device["platform"] != "tpu" or len(devs) < cell["chips"]:
+            raise SystemExit(f"chipbench: {args.workload} needs "
+                             f"{cell['chips']} TPU chip(s); jax reports "
+                             f"{device}")
+        pinned = alpha_mod.pinned_knobs()
+        if pinned:
+            raise SystemExit(f"chipbench: runs at default knobs, but "
+                             f"{pinned} are set")
+    say(f"{'REHEARSAL, never a result: ' if args.rehearsal else ''}"
+        f"{args.workload} seed {args.seed} on {device}")
+
+    maker = importlib.import_module(f"chipbench.data.{config['data']}")
+    clock = alpha_mod.CompileClock()
+    alpha = alpha_mod.Alpha()
+    fetches = alpha.fetches
+    child = None
+    tmp = tempfile.mkdtemp(prefix="chipbench_")
+    try:
+        model, install = maker.install(
+            config, args.seed, alpha, store_dir(config, maker, args.seed))
+        say(f"installed: {install}")
+        child = Child({"url": alpha.serve(), "config": config, "mix": mix,
+                       "seed": args.seed})
+        warm = warm_up(child, clock, mix)
+        say(f"warm-up: {warm}; compiles so far {clock.snap()}")
+
+        out_path = os.path.join(tmp, "records.pkl")
+        c0, f0 = clock.snap(), fetches.snap()
+        if hasattr(maker, "window_opens"):
+            maker.window_opens(model)
+        gc.collect()
+        cpu0 = time.process_time()
+        setup_s = time.perf_counter() - T0
+        trace = None
+        if args.trace:
+            reply, trace = traced(child, args.seconds, mix, out_path)
+        else:
+            reply = child.ask({"cmd": "run", "seconds": args.seconds,
+                               "out": out_path})
+        c1, f1 = clock.snap(), fetches.snap()
+        peak = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
+                   for d in devs)
+        alpha_cpu_s = time.process_time() - cpu0
+        say(f"window closed: {reply}; hbm {alpha_mod.hbm(devs[0])}; the "
+            f"alpha's process used {alpha_cpu_s:.1f} cpu-seconds")
+        captured = (maker.captured(model) if hasattr(maker, "captured")
+                    else None)
+        describe = (maker.describe(alpha, model)
+                    if hasattr(maker, "describe") else {})
+        with open(out_path, "rb") as f:
+            got = pickle.load(f)
+    finally:
+        if child is not None:
+            child.close()
+        alpha.close()
+        shutil.rmtree(tmp, ignore_errors=True)
+    gc.collect()
+
+    t0, recs = got["t0"], got["records"]
+    failed_requests = sum(1 for r in recs if r["error"] is not None)
+    for r in recs:
+        if r["error"] is not None:
+            say(f"failed request: {r['error']}")
+            break
+    device["memory_peak_bytes"] = int(peak)
+    # a witness of a stalled host, for whoever reads a far-off run
+    ends = np.sort([t0] + [r["done"] for r in recs])
+    longest_gap_s = float(np.max(np.diff(ends))) if len(recs) else 0.0
+    say(f"longest time with no answer: {longest_gap_s:.3f}s")
+    metrics = {}
+    ctx = None
+    if args.trace:
+        reduced = trace_reduce.reduce_dir(trace["dir"])
+        say(f"trace: busy {reduced['busy_s']:.4f}s on "
+            f"{reduced['device_planes']} device plane(s); planes "
+            f"{[p for p in reduced['planes'] if 'device' in p[0]]}")
+        shutil.rmtree(trace["dir"], ignore_errors=True)
+        window_s = trace["stop"] - trace["start"]
+        device["busy_s"] = reduced["busy_s"]
+        device["window_s"] = window_s
+        ctx = {
+            "trace": dict(reduced, window_s=window_s),
+            "traced_requests": sum(
+                1 for r in recs if r["error"] is None
+                and trace["start"] <= r["done"] <= trace["stop"]),
+            "requests": sum(1 for r in recs if r["error"] is None),
+            "fetches": alpha_mod.delta(f0, f1),
+            "compiles_in_window": c1["compiles"] - c0["compiles"],
+            "install": install, "warm": warm, "describe": describe,
+            "config": config, "device_kind": device["kind"],
+            "peaks": load_json(os.path.join(HERE, "peaks.json")),
+        }
+        for m in metrics_of(bench, "per_layer", args.workload):
+            reader = importlib.import_module(
+                f"chipbench.layer_metrics.{m['name']}")
+            value = reader.read(ctx)
+            if value is not None:
+                metrics[m["name"]] = {"value": value, "unit": m["unit"]}
+    else:
+        for m in metrics_of(bench, "end_to_end", args.workload):
+            value = end_to_end(m["name"], recs, t0, args.seconds, setup_s)
+            if value is not None:
+                metrics[m["name"]] = {"value": value, "unit": m["unit"]}
+
+    # the comparison that decides `correct`: after the window has closed,
+    # the peak has been read and the alpha's state is freed
+    t_ref = time.perf_counter()
+    sample = sample_records(recs, mix, args.seed)
+    if captured and mix.get("compare_sample"):
+        pick = np.random.default_rng([args.seed, 78]).permutation(
+            len(captured))[: mix["compare_sample"]]
+        captured = [captured[i] for i in sorted(pick)]
+    numbers = numbers_of(mix, model, sample, captured)
+    checks = judge(config, numbers, failed_requests)
+    correct = all(c["ok"] for c in checks.values())
+    say(f"compared {len(sample)} of {len(recs)} answers in "
+        f"{time.perf_counter() - t_ref:.1f}s")
+
+    result = {"correct": correct, "attempted": len(recs),
+              "failed": failed_requests + int(
+                  checks.get("wrong_answers", {"value": 0})["value"]),
+              "metrics": metrics, "device": device}
+    if ctx is not None:
+        result["breakdown"] = {
+            "device_ops": ctx["trace"]["top_ops"][:10],
+            "idle_gaps": ctx["trace"]["top_gaps"][:10],
+        }
+    result["rehearsal"] = bool(args.rehearsal)
+    result["setup"] = {"install": install, "warm": warm,
+                       "compiles": c0, "compiles_in_window":
+                       c1["compiles"] - c0["compiles"],
+                       "longest_gap_s": longest_gap_s,
+                       "alpha_cpu_s": alpha_cpu_s}
+    if after is not None:
+        result["after"] = after({"model": model, "config": config,
+                                 "mix": mix, "sample": sample,
+                                 "numbers": numbers, "captured": captured,
+                                 "seed": args.seed})
+    result["checks"] = {n: [c["value"], c["op"], c["limit"]]
+                        for n, c in checks.items()}
+    for n, c in checks.items():
+        print(f"check {n}: {c['value']!r} {c['op']} {c['limit']!r} -> "
+              f"{'ok' if c['ok'] else 'NOT OK'}", file=sys.stderr, flush=True)
+    return result
+
+
+if __name__ == "__main__":
+    sys.exit(main())

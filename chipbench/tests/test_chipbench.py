@@ -1,0 +1,491 @@
+"""CPU tests of the benchmark's own code, at tiny sizes (`--rehearsal`).
+
+  JAX_PLATFORMS=cpu python -m pytest chipbench/tests -q
+
+They live under `chipbench/` because the PR that defines the benchmark
+may add files only there; a later PR may move them to tests/.
+"""
+
+from __future__ import annotations
+
+import importlib
+import json
+import os
+import re
+import shutil
+import subprocess
+import sys
+
+import numpy as np
+import pytest
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+CHIPBENCH = os.path.dirname(HERE)
+ROOT = os.path.dirname(CHIPBENCH)
+sys.path.insert(0, ROOT)
+
+with open(os.path.join(ROOT, "BENCHMARK.json")) as _f:
+    BENCH = json.load(_f)
+CELLS = [w["name"] for w in BENCH["workloads"]]
+NAME = re.compile(r"[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}")
+UNIT = re.compile(r"[A-Za-z0-9_/%.\-]{1,16}")
+# what each configuration needs on the CPU to take the jitted paths
+CPU_ENV = {"snb-sf1": {"DGRAPH_TPU_DEVICE_MIN_TOTAL": "32768"},
+           "vec-1m-768": {"DGRAPH_TPU_VEC_QUANT": "0"}}
+FAULT = {"snb-sf1": "setops", "vec-1m-768": "vector_search"}
+BIG_SEED = 2**31 + 11
+
+
+def cell(name):
+    return next(w for w in BENCH["workloads"] if w["name"] == name)
+
+
+def config_of(name):
+    entry = next(c for c in BENCH["configs"] if c["name"] == cell(name)["config"])
+    with open(os.path.join(ROOT, entry["file"])) as f:
+        return json.load(f)
+
+
+def mix_of(name):
+    with open(os.path.join(CHIPBENCH, "mixes",
+                           cell(name)["traffic"] + ".json")) as f:
+        return json.load(f)
+
+
+def drive(module, name, *args, cwd=ROOT, extra_path=()):
+    """Run `python -m <module> --workload <name> ...` on the CPU."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.pathsep.join([cwd, *extra_path]),
+               **CPU_ENV.get(cell(name)["config"], {})
+               if name in CELLS else {})
+    env.pop("BENCH_RUN", None)
+    return subprocess.run(
+        [sys.executable, "-m", module, *args], cwd=cwd, env=env,
+        capture_output=True, text=True, timeout=600)
+
+
+def last_json(proc):
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    return json.loads(proc.stdout.strip().splitlines()[-1])
+
+
+# -- BENCHMARK.json against the contract ------------------------------------
+
+
+def test_names_and_units_use_the_allowed_characters():
+    names = [c["name"] for c in BENCH["configs"]]
+    names += [k for c in BENCH["configs"] for k in c["reduced"]]
+    for w in BENCH["workloads"]:
+        names += [w["name"], w["config"], w["traffic"]]
+    metrics = BENCH["end_to_end"] + BENCH["per_layer"]
+    names += [m["name"] for m in metrics]
+    assert all(NAME.fullmatch(n) for n in names), names
+    assert all(UNIT.fullmatch(m["unit"]) for m in metrics)
+    assert len(set(m["name"] for m in metrics)) == len(metrics)
+    assert all(m["better"] in ("lower", "higher") for m in metrics)
+    for text in ([w["why"] for w in BENCH["workloads"]]
+                 + [c["why"] for c in BENCH["configs"]]
+                 + [c["source"] for c in BENCH["configs"]]
+                 + [m["layer"] for m in BENCH["per_layer"]]
+                 + BENCH["command"]):
+        assert 1 <= len(text) <= 200 and "\n" not in text and "\t" not in text
+
+
+def test_benchmark_json_keeps_the_contract():
+    assert set(BENCH) == {"command", "paths", "run_seconds", "configs",
+                          "workloads", "end_to_end", "per_layer"}
+    e2e = {m["name"]: m for m in BENCH["end_to_end"]}
+    assert "setup_s" in e2e and e2e["setup_s"]["bound"] <= 0.25
+    assert all(0.01 <= m["bound"] <= 0.25 and
+               m["source"] in ("host_clock", "device_trace")
+               for m in e2e.values())
+    n = 24  # the limit has to fit with the full 24 cells
+    assert (2 + 14 * n) * (BENCH["run_seconds"] + 60) + n * 180 + 1200 <= 43200
+    assert sum(w["chips"] == 4 for w in BENCH["workloads"]) <= max(
+        1, len(BENCH["workloads"]) // 2)
+
+    def reports(metric, name):
+        return "workloads" not in metric or name in metric["workloads"]
+
+    for m in BENCH["per_layer"]:
+        assert set(m) <= {"name", "unit", "better", "source", "layer",
+                          "moves", "workloads"}
+        assert m["moves"] in e2e
+        for w in m.get("workloads", CELLS):
+            assert reports(e2e[m["moves"]], w), (m["name"], w)
+    for w in CELLS:
+        assert reports(e2e["setup_s"], w)
+        assert sum(reports(m, w) for m in e2e.values()) >= 2
+        assert any(reports(m, w) for m in BENCH["per_layer"])
+    for c in BENCH["configs"]:
+        assert c["file"].startswith(tuple(p + "/" for p in BENCH["paths"]))
+        with open(os.path.join(ROOT, c["file"])) as f:
+            body = json.load(f)
+        assert sorted(body["reduced"]) == sorted(c["reduced"])
+        assert set(body["checks"]) and body["guarantees"]
+
+
+@pytest.mark.parametrize("name", CELLS)
+def test_every_piece_of_a_cell_is_found_by_name(name):
+    config, mix = config_of(name), mix_of(name)
+    maker = importlib.import_module(f"chipbench.data.{config['data']}")
+    assert all(hasattr(maker, f) for f in ("make", "install", "catalog"))
+    for k in mix["kinds"]:
+        kind = importlib.import_module(f"chipbench.queries.{k['kind']}")
+        assert all(hasattr(kind, f)
+                   for f in ("request", "parse", "check", "control"))
+    for m in BENCH["per_layer"]:
+        if "workloads" not in m or name in m["workloads"]:
+            reader = importlib.import_module(
+                f"chipbench.layer_metrics.{m['name']}")
+            assert callable(reader.read)
+
+
+# -- a whole run, rehearsed ---------------------------------------------------
+
+
+@pytest.mark.parametrize("name", CELLS)
+@pytest.mark.parametrize("trace", [0, 1])
+def test_rehearsal_runs_a_cell_end_to_end(name, trace):
+    out = last_json(drive("chipbench.run", name, "--workload", name,
+                          "--seed", str(BIG_SEED), "--seconds", "3",
+                          "--trace", str(trace), "--rehearsal"))
+    assert {"correct", "attempted", "failed", "metrics", "device"} <= set(out)
+    assert out["rehearsal"] is True and list(out)[-1] == "checks"
+    assert out["correct"] is True and out["failed"] == 0
+    assert out["attempted"] > 0
+    group = "per_layer" if trace else "end_to_end"
+    want = {m["name"] for m in BENCH[group]
+            if "workloads" not in m or name in m["workloads"]}
+    assert set(out["metrics"]) <= want
+    for m, v in out["metrics"].items():
+        assert isinstance(v["value"], float) and v["unit"]
+    if trace:
+        assert {"busy_s", "window_s"} <= set(out["device"])
+        assert "compiles_in_window" in out["metrics"]
+        # no device plane on the CPU: the device readers return nothing
+        assert "device_idle_share" not in out["metrics"]
+    else:
+        assert set(out["metrics"]) == want
+        assert out["metrics"]["qps"]["value"] > 0
+
+
+def test_without_a_tpu_there_is_no_result():
+    proc = drive("chipbench.run", CELLS[0], "--workload", CELLS[0], "--seed",
+                 "1", "--seconds", "1", "--trace", "0")
+    assert proc.returncode != 0 and proc.stdout.strip() == ""
+
+
+def test_outside_the_repo_there_is_no_result(tmp_path):
+    shutil.copytree(CHIPBENCH, tmp_path / "chipbench",
+                    ignore=shutil.ignore_patterns(".store", "__pycache__"))
+    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), tmp_path)
+    proc = drive("chipbench.run", CELLS[0], "--workload", CELLS[0], "--seed",
+                 "1", "--seconds", "1", "--trace", "0", "--rehearsal",
+                 cwd=str(tmp_path))
+    assert proc.returncode != 0 and proc.stdout.strip() == ""
+
+
+# -- correct: the faults and the control ---------------------------------------
+
+
+@pytest.mark.parametrize("name", CELLS)
+def test_an_answer_altered_where_it_is_produced_is_not_correct(name):
+    out = last_json(drive(
+        "chipbench.tests.faults", name, FAULT[cell(name)["config"]],
+        "--workload", name, "--seed", str(BIG_SEED), "--seconds", "3",
+        "--trace", "0"))
+    assert out["correct"] is False
+    assert any(not _ok(c) for c in out["checks"].values())
+
+
+def _ok(c):
+    value, op, limit = c
+    return value <= limit if op == "<=" else value >= limit
+
+
+@pytest.mark.parametrize("name", CELLS)
+def test_the_control_comes_out_not_correct(name):
+    out = last_json(drive("chipbench.control", name, "--workload", name,
+                          "--seed", str(BIG_SEED), "--seconds", "3",
+                          "--rehearsal"))
+    assert out["program"]["correct"] is True
+    assert out["control"]["correct"] is False
+
+
+def test_compare_counts_a_wrong_answer():
+    from chipbench import run
+    from chipbench.data import snb
+    from chipbench.queries import ic1
+
+    config = config_of("snb.ic1")
+    config["sizes"].update(config["rehearsal"])
+    model = snb.make(config, 5)
+    mix = mix_of("snb.ic1")
+    params = mix["kinds"][0]["params"]
+    rng = np.random.default_rng(1)
+    cat = snb.catalog(config, 5)
+    keys = [ic1.request(cat, params, rng)[0] for _ in range(4)]
+    recs = [{"kind": 0, "key": k, "answer": a, "error": None}
+            for k, a in zip(keys, ic1.reference(model, params, keys))]
+    assert all(0 < len(r["answer"]) <= 60 for r in recs)
+
+    def compare(recs, failed_requests):
+        return run.judge(config, run.numbers_of(mix, model, recs),
+                         failed_requests)
+
+    assert all(c["ok"] for c in compare(recs, 0).values())
+    recs[2]["answer"] = recs[2]["answer"][1:]
+    got = compare(recs, 0)
+    assert got["wrong_answers"]["value"] == 1 and not got["wrong_answers"]["ok"]
+    row = list(recs[1]["answer"][0])
+    row[2] = "Nobody"  # one field of one row
+    recs[1]["answer"] = [tuple(row)] + recs[1]["answer"][1:]
+    assert compare(recs, 0)["wrong_answers"]["value"] == 2
+    assert not compare(recs[:1], 1)["failed_requests"]["ok"]
+
+
+def _vector_case(n_keys, vectors=6000):
+    from chipbench.data import mog
+    from chipbench.queries import similar_to as kind
+
+    vconf = config_of("vec.solo16")
+    vconf["sizes"].update(vectors=vectors, dim=32)
+    model, params = mog.make(vconf, 3), {"k": 10}
+    cat = mog.catalog(vconf, 3)
+    rng = np.random.default_rng(2)
+    keys = [kind.request(cat, params, rng)[0] for _ in range(n_keys)]
+    exact = [np.sort(u) for u, _ in model.topk(np.stack(keys), 10)]
+    return vconf, model, params, keys, exact
+
+
+def _vector_verdict(vconf, model, params, keys, answers):
+    """The configuration's own limits over the answers' numbers (the
+    probe tap's numbers left out: nothing was tapped here)."""
+    from chipbench import run
+    from chipbench.queries import similar_to as kind
+
+    checks = run.judge(vconf, kind.check(model, params, keys, answers), 0)
+    return {n: c for n, c in checks.items() if not n.startswith("probe")}
+
+
+def test_planted_vector_faults_read_over_the_limit():
+    """A wrong neighbour, as near a miss as can be made, in every answer
+    or in every third, comes out not correct; the exact answers read 0."""
+    from chipbench.queries import similar_to as kind
+
+    vconf, model, params, keys, exact = _vector_case(48)
+    got = kind.check(model, params, keys, exact)
+    assert max(got["dist_excess"]) == 0 and max(got["inexact_answers"]) == 0
+    assert all(c["ok"] for c in _vector_verdict(
+        vconf, model, params, keys, exact).values())
+    planted = kind.faults(model, params, keys, exact, 3)
+    assert set(planted) == set(kind.FAULTS)
+    for name, answers in planted.items():
+        got = kind.check(model, params, keys, answers)
+        wrong = [i for i, x in enumerate(got["inexact_answers"]) if x]
+        assert len(wrong) == (16 if name.endswith("_third") else 48)
+        assert min(got["dist_excess"][i] for i in wrong) > 0
+        assert max(got["recall_at_k"][i] for i in wrong) == 0.9
+        verdict = _vector_verdict(vconf, model, params, keys, answers)
+        assert not verdict["inexact_answers"]["ok"], name
+        assert verdict["recall_at_k"]["ok"] == name.endswith("_third")
+
+
+def test_a_lone_miss_of_the_approximate_index_stays_correct():
+    """The index is approximate: one answer of a run's 257 that names
+    the next nearest row in place of a neighbour is a sound answer (the
+    check's refusal of PR 24 was one such). The same answer naming a row
+    of another gaussian is not."""
+    from chipbench.data import mog
+    from chipbench.queries import similar_to as kind
+
+    vconf, model, params, keys, exact = _vector_case(257, vectors=20000)
+    miss = kind.faults(model, params, keys, exact, 3)["next_nearest"]
+    answers = list(exact)
+    answers[100] = miss[100]
+    verdict = _vector_verdict(vconf, model, params, keys, answers)
+    assert verdict["inexact_answers"]["value"] == 1 / 257
+    assert verdict["dist_excess"]["value"] > 0
+    assert all(c["ok"] for c in verdict.values())
+    far = exact[100].copy()
+    own = model.labels[int(far[0]) - mog.UID_BASE]
+    far[0] = mog.UID_BASE + int(np.flatnonzero(model.labels != own)[0])
+    answers[100] = np.sort(far)
+    verdict = _vector_verdict(vconf, model, params, keys, answers)
+    assert verdict["inexact_answers"]["ok"]
+    assert not verdict["dist_excess"]["ok"]
+
+
+# -- the trace reduction ----------------------------------------------------------
+
+
+def test_union_of_busy_intervals():
+    from chipbench import trace_reduce as T
+
+    secs, merged = T.union_seconds([(0, 10), (5, 20), (30, 40), (32, 35)])
+    assert secs == 30 / 1e9 and merged == [[0, 20], [30, 40]]
+    planes = [("/device:TPU:0", [
+        ("XLA Modules", [("jit_f(1)", 0, 650), ("jit_g(2)", 790, 200)]),
+        ("XLA Ops", [("a", 0, 400), ("b", 300, 200), ("c", 600, 50),
+                     ("a", 800, 100)])]),
+        ("/host:CPU", [("t", [("py", 0, 5000)])])]
+    r = T.reduce_planes(planes)
+    assert r["device_planes"] == 1 and r["busy_s"] == 650 / 1e9
+    assert r["top_ops"][0] == ["a", 500 / 1e9]
+    assert r["top_gaps"] == [["before:jit_g", 150 / 1e9],
+                             ["within:jit_f", 100 / 1e9]]
+    assert T.reduce_planes([])["device_planes"] == 0
+
+
+def test_reduction_of_the_recorded_chip_trace():
+    """The first 1.2 s of a 64-root friends-of-friends trace from the v5e
+    (PR 24, the same `intersect#shared` programs snb.ic1 runs): the
+    busy time is known from an independent sweep over its op events."""
+    from chipbench import trace_reduce as T
+
+    path = os.path.join(HERE, "recorded_trace.json")
+    r = T.reduce_dir(path)
+    with open(path) as f:
+        ops = dict(dict((p, l) for p, l in json.load(f))["/device:TPU:0"])[
+            "XLA Ops"]
+    edges = sorted([(s, 1) for _, s, d in ops] + [(s + d, -1) for _, s, d in ops])
+    depth = busy = 0
+    for (t, step), (t_next, _) in zip(edges, edges[1:]):
+        depth += step
+        busy += (t_next - t) if depth > 0 else 0
+    assert r["device_planes"] == 1
+    assert r["busy_s"] == pytest.approx(busy / 1e9, rel=1e-9)
+    assert r["busy_s"] == pytest.approx(1.168174364, rel=1e-6)
+    idle = importlib.import_module("chipbench.layer_metrics.device_idle_share")
+    share = idle.read({"trace": dict(r, window_s=1.2)})
+    assert share == pytest.approx(100 * (1 - 1.168174364 / 1.2), rel=1e-6)
+    assert idle.read({"trace": dict(T.reduce_planes([]), window_s=1.2)}) is None
+
+
+def test_roofline_reader_counts_bytes_and_refuses_unknown_devices():
+    R = importlib.import_module("chipbench.layer_metrics.vec_probe_roofline")
+    assert R.probe_bytes(1000, 768, 10) == 1000 * (768 * 4 + 8) + 10 * (768 * 4 + 4)
+    with open(os.path.join(CHIPBENCH, "peaks.json")) as f:
+        peaks = json.load(f)
+    ctx = {"describe": {"probe_rows": 65536, "dim": 768, "nlist": 2000},
+           "trace": {"busy_s": 0.5}, "traced_requests": 500, "requests": 900,
+           "fetches": {"vector:ivf_batch": 900}, "device_kind": "TPU v5 lite",
+           "peaks": peaks}
+    least = R.probe_bytes(65536, 768, 2000) / 819e9
+    assert R.read(ctx) == pytest.approx(100 * least / 1e-3)
+    assert R.read(dict(ctx, fetches={"vector:brute_batch": 1,
+                                     "vector:ivf_batch": 899})) is None
+    with pytest.raises(KeyError):
+        R.read(dict(ctx, device_kind="TPU v9"))
+
+
+# -- draws and data are functions of the seed alone -----------------------------
+
+
+def test_draws_and_data_are_functions_of_the_seed():
+    from chipbench import draw
+    from chipbench.data import mog, snb
+
+    a, b, c = (draw.stream(BIG_SEED, 1, n).integers(0, 1 << 30, 8)
+               for n in (3, 3, 4))
+    assert np.array_equal(a, b) and not np.array_equal(a, c)
+
+    config = config_of("snb.ic1")
+    config["sizes"].update(config["rehearsal"])
+    m1, m2, m3 = (snb.make(config, s) for s in (BIG_SEED, BIG_SEED, 5))
+    assert np.array_equal(m1.pairs, m2.pairs)
+    assert not np.array_equal(m1.pairs, m3.pairs)
+    # every seed has the same degree wanted at every popularity rank
+    n = config["sizes"]["persons"]
+    assert np.array_equal(
+        snb.degree_sequence(n, config["sizes"]["knows_pairs"], 1.1, 700),
+        snb.degree_sequence(n, config["sizes"]["knows_pairs"], 1.1, 700))
+    # every seed serves the same graph under other names
+    assert np.array_equal(m1.degrees()[snb.rank_order(n, BIG_SEED)],
+                          m3.degrees()[snb.rank_order(n, 5)])
+    # so the curated start persons are the same ranks under every seed,
+    # and a client's requests are a function of the seed
+    from chipbench.queries import ic1
+
+    params = mix_of("snb.ic1")["kinds"][0]["params"]
+    cats = [snb.catalog(config, s) for s in (BIG_SEED, BIG_SEED, 5)]
+    ranks = [np.sort(np.argsort(snb.rank_order(n, s))[ic1.curated(c, params)])
+             for c, s in zip(cats, (BIG_SEED, BIG_SEED, 5))]
+    assert np.array_equal(ranks[0], ranks[2]) and 0 < len(ranks[0]) < n / 5
+    texts = [[ic1.request(c, params, draw.stream(s, 1, 0))[1]
+              for _ in range(3)] for c, s in zip(cats, (BIG_SEED, BIG_SEED, 5))]
+    assert texts[0] == texts[1] and texts[0] != texts[2]
+    assert m1.person(7) == m2.person(7) and m1.person(7) != m3.person(7)
+
+    vconf = config_of("vec.solo16")
+    vconf["sizes"].update(vconf["rehearsal"])
+    assert np.array_equal(mog.corpus(vconf, BIG_SEED), mog.corpus(vconf, BIG_SEED))
+    assert not np.array_equal(mog.corpus(vconf, BIG_SEED), mog.corpus(vconf, 5))
+
+
+def test_exact_topk_reference_is_exact():
+    from chipbench.data import mog
+
+    vconf = config_of("vec.solo16")
+    vconf["sizes"].update(vectors=3000, dim=32)
+    model = mog.make(vconf, 3)
+    Q = mog.centers_of(vconf, 3)[:5] + 0.5
+    for q, (uids, d) in zip(Q, model.topk(Q, 10)):
+        full = ((model.V.astype(np.float64) - q.astype(np.float64)) ** 2).sum(1)
+        order = np.argsort(full, kind="stable")[:10]
+        assert np.array_equal(uids, order.astype(np.uint64) + mog.UID_BASE)
+        assert np.allclose(d, full[order])
+
+
+# -- a later PR adds files and entries, and edits nothing ---------------------------
+
+
+def test_new_pieces_need_only_new_files(tmp_path):
+    """A configuration, a mix, a query kind and a layer reader added as
+    new files plus BENCHMARK.json entries, in a copy of the benchmark."""
+    shutil.copytree(CHIPBENCH, tmp_path / "chipbench",
+                    ignore=shutil.ignore_patterns(".store", "__pycache__"))
+    cb = tmp_path / "chipbench"
+    config = config_of("snb.ic1")
+    config.update(name="snb-small", rehearsal={"persons": 1500,
+                                                "knows_pairs": 12000,
+                                                "posts": 50, "comments": 50,
+                                                "forums": 5})
+    (cb / "configs" / "snb-small.json").write_text(json.dumps(config))
+    (cb / "queries" / "ic1_again.py").write_text(
+        "from chipbench.queries.ic1 import *  # noqa: F401,F403\n")
+    mix = mix_of("snb.ic1")
+    mix.update(name="ic1wide", clients=2,
+               kinds=[{"kind": "ic1_again", "weight": 1,
+                       "params": {"limit": 5, "band": [0.3, 0.7]}}])
+    (cb / "mixes" / "ic1wide.json").write_text(json.dumps(mix))
+    (cb / "layer_metrics" / "requests_answered.py").write_text(
+        "def read(ctx):\n    return float(ctx['requests'])\n")
+    bench = json.loads(json.dumps(BENCH))
+    bench["configs"].append({
+        "name": "snb-small", "source": "test",
+        "file": "chipbench/configs/snb-small.json",
+        "reduced": config["reduced"], "why": "test"})
+    bench["workloads"].append({"name": "small.ic1wide", "config": "snb-small",
+                               "traffic": "ic1wide", "chips": 1, "why": "test"})
+    bench["per_layer"].append({
+        "name": "requests_answered", "unit": "count", "better": "higher",
+        "source": "program_counter", "layer": "wire", "moves": "qps",
+        "workloads": ["small.ic1wide"]})
+    for m in bench["per_layer"]:
+        if m["name"] == "store_open_s":
+            m["workloads"].append("small.ic1wide")
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.pathsep.join([str(tmp_path), ROOT]),
+               DGRAPH_TPU_DEVICE_MIN_TOTAL="32768")
+    proc = subprocess.run(
+        [sys.executable, "-m", "chipbench.run", "--workload", "small.ic1wide",
+         "--seed", "9", "--seconds", "2", "--trace", "1", "--rehearsal"],
+        cwd=str(tmp_path), env=env, capture_output=True, text=True,
+        timeout=600)
+    out = last_json(proc)
+    assert out["correct"] is True
+    assert out["metrics"]["requests_answered"]["value"] > 0
+    assert "store_open_s" in out["metrics"]
