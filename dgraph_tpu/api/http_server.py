@@ -24,6 +24,7 @@ from dgraph_tpu.query import streamjson
 from dgraph_tpu.query.functions import QueryError
 from dgraph_tpu.api.server import Server, TxnHandle
 from dgraph_tpu.serving import TooManyRequestsError
+from dgraph_tpu.utils.observe import TRACER
 from dgraph_tpu.worker.remote import RetryBudgetExhausted
 from dgraph_tpu.worker.tabletmove import TabletFencedError
 from dgraph_tpu.zero.zero import TxnConflictError
@@ -35,6 +36,9 @@ class _Handler(BaseHTTPRequestHandler):
     txns: Dict[int, TxnHandle] = {}
     txn_owner: Dict[int, str] = {}
     metrics: Dict[str, float] = {}
+    # body and reply sizes of the request in hand (http.request's attrs)
+    _bytes_in = 0
+    _bytes_out = 0
 
     def log_message(self, *a):  # quiet
         pass
@@ -43,9 +47,17 @@ class _Handler(BaseHTTPRequestHandler):
 
     def _body(self) -> bytes:
         n = int(self.headers.get("Content-Length", 0))
+        self._bytes_in = n
         return self.rfile.read(n) if n else b""
 
     def _reply(self, obj, code=200):
+        # `http.reply`: response assembly and the socket write; a fine
+        # span, so it is recorded only below `http.request` (the /query
+        # and /mutate routes) and is a no-op on every other route
+        with TRACER.span("http.reply", cpu=True, fine=True) as sp:
+            sp.attrs["bytes"] = self._bytes_out = self._write(obj, code)
+
+    def _write(self, obj, code) -> int:
         # responses whose `data` carries pre-encoded wire bytes (the
         # streaming arena encoder, query/streamjson.py) are SPLICED —
         # the result tree never runs through json.dumps a second time
@@ -60,6 +72,7 @@ class _Handler(BaseHTTPRequestHandler):
         self.send_header("Content-Length", str(len(data)))
         self.end_headers()
         self.wfile.write(data)
+        return len(data)
 
     def _error(self, msg, code=400):
         self._reply(
@@ -128,9 +141,17 @@ class _Handler(BaseHTTPRequestHandler):
             # spans; single-process engines serve the local ring
             merged_traces = getattr(self.engine, "merged_traces", None)
             if merged_traces is not None:
-                self._reply({"spans": merged_traces(200)})
+                out = {"spans": merged_traces(200)}
             else:
-                self._reply({"spans": TRACER.recent(200)})
+                out = {"spans": TRACER.recent(200)}
+            if get_qs.get("requests"):
+                # ?requests=<n>: this process's newest n request records
+                # (per-span-name self wall and CPU time, counts, bytes;
+                # `detail` false marks a tree without its fine spans)
+                out["requests"] = TRACER.request_records(
+                    int(get_qs["requests"][0])
+                )
+            self._reply(out)
         elif path == "/debug/tablets":
             from dgraph_tpu.utils.observe import TABLETS
 
@@ -262,6 +283,18 @@ class _Handler(BaseHTTPRequestHandler):
         t0 = time.time()
         parsed = urlparse(self.path)
         path = parsed.path
+        if path not in ("/query", "/mutate"):
+            return self._post(t0, parsed, path)
+        # `http.request`: the root of a served request's span tree, from
+        # the handler's first line to after the reply has been written
+        # (the request line and headers were parsed before do_POST)
+        self._bytes_in = self._bytes_out = 0
+        with TRACER.span("http.request", cpu=True, path=path) as root:
+            self._post(t0, parsed, path)
+            root.attrs["bytes_in"] = self._bytes_in
+            root.attrs["bytes_out"] = self._bytes_out
+
+    def _post(self, t0, parsed, path):
         qs = parse_qs(parsed.query)
         token = self.headers.get("X-Dgraph-AccessToken")
         # admin/DDL routes are guardian-only once ACL is enabled
@@ -319,24 +352,34 @@ class _Handler(BaseHTTPRequestHandler):
                     self.send_header("Content-Length", str(len(data)))
                     self.end_headers()
                     self.wfile.write(data)
+                    self._bytes_out = len(data)
                     return
-                raw = self._body().decode("utf-8")
                 variables = None
                 # EXPLAIN/ANALYZE: ?debug=true (the reference's debug
                 # query param) or a "debug": true JSON body field turns
                 # on plan capture; data bytes are unchanged by it
                 debug = qs.get("debug", ["false"])[0] == "true"
-                if "json" in self.headers.get("Content-Type", ""):
-                    body = json.loads(raw)
-                    if not isinstance(body, dict):
-                        raise ValueError("JSON query body must be an object")
-                    raw = body.get("query", "")
-                    variables = body.get("variables")
-                    if variables is not None and not isinstance(variables, dict):
-                        raise ValueError('"variables" must be an object')
-                    # accept only explicit truthy spellings: a client
-                    # sending the STRING "false" must not enable debug
-                    debug = body.get("debug", debug) in (True, "true", "1")
+                # `http.read`: the body off the socket and the JSON
+                # decode of its envelope
+                with TRACER.span("http.read", cpu=True, fine=True):
+                    raw = self._body().decode("utf-8")
+                    if "json" in self.headers.get("Content-Type", ""):
+                        body = json.loads(raw)
+                        if not isinstance(body, dict):
+                            raise ValueError(
+                                "JSON query body must be an object"
+                            )
+                        raw = body.get("query", "")
+                        variables = body.get("variables")
+                        if variables is not None and not isinstance(
+                            variables, dict
+                        ):
+                            raise ValueError('"variables" must be an object')
+                        # accept only explicit truthy spellings: a client
+                        # sending the STRING "false" must not enable debug
+                        debug = body.get("debug", debug) in (
+                            True, "true", "1",
+                        )
                 timeout_ms = None
                 if qs.get("timeout"):
                     t = qs["timeout"][0]  # "5s" / "500ms" (ref ?timeout=)
@@ -609,10 +652,13 @@ class _Handler(BaseHTTPRequestHandler):
             self._error(e, 500)
 
     def _handle_mutate(self, qs, token=None):
-        body = self._body().decode("utf-8")
+        ctype = self.headers.get("Content-Type", "application/rdf")
+        with TRACER.span("http.read", cpu=True, fine=True):
+            body = self._body().decode("utf-8")
+            if "json" in ctype:
+                obj = json.loads(body) if body.strip() else {}
         commit_now = qs.get("commitNow", ["false"])[0] == "true"
         start_ts = int(qs.get("startTs", ["0"])[0])
-        ctype = self.headers.get("Content-Type", "application/rdf")
 
         if start_ts and start_ts in self.txns:
             txn = self.txns[start_ts]
@@ -620,7 +666,6 @@ class _Handler(BaseHTTPRequestHandler):
             txn = self.engine.new_txn()
 
         if "json" in ctype:
-            obj = json.loads(body) if body.strip() else {}
             uids = txn.mutate_json(
                 set_obj=obj.get("set"),
                 del_obj=obj.get("delete"),

@@ -1204,7 +1204,21 @@ class Server:
         attaches the structured plan tree as `extensions.plan`. Capture
         is observation-only: response `data` bytes are identical with
         the flag on or off (golden-enforced, tests/test_explain.py)."""
+        # the `query` span opens before anything else: `parse` and
+        # `admit` are its children, as `process` and `encode` are
+        with observe.TRACER.span("query", cpu=True) as root:
+            return self._query_spanned(
+                root, q, read_ts, access_jwt, variables, timeout_ms, want,
+                debug,
+            )
+
+    def _query_spanned(
+        self, root, q, read_ts, access_jwt, variables, timeout_ms, want,
+        debug,
+    ) -> dict:
         import time as _time
+
+        from dgraph_tpu.utils.observe import METRICS, TRACER, profile_scope
 
         t_begin = _time.monotonic()
         # info is now always collected: the digest store records the
@@ -1214,9 +1228,11 @@ class Server:
         digested = False  # one digest record per query, on every path
         try:
             # plan cache: repeated query shapes skip parse entirely
-            blocks, shape, literals = self.serving.parse(
-                q, variables, info=parse_info
-            )
+            with TRACER.span("parse", cpu=True, fine=True) as sp:
+                blocks, shape, literals = self.serving.parse(
+                    q, variables, info=parse_info
+                )
+                sp.attrs["plan_cache_hit"] = bool(parse_info.get("hit"))
         except Exception:
             # unparseable queries accrue to the per-ns `other` bucket —
             # a flood of malformed text is an operator-visible shape
@@ -1232,81 +1248,80 @@ class Server:
         # applied-barrier wait is exactly where queries queue, and a
         # request that will be refused must neither join that queue
         # nor lease a timestamp
-        ticket = self.serving.admit(shape, blocks)
+        # `admit`, in two stretches around the `try`: the admission
+        # gate here; ACL, audit and the read ts below
+        with TRACER.span("admit", cpu=True, fine=True) as sp:
+            ticket = self.serving.admit(shape, blocks)
+            sp.attrs["degrade"] = bool(ticket.degrade)
         slow = False
         completed = False  # clean, untruncated execution
         try:
-            ns = keys.GALAXY_NS
-            allowed = None
-            user = ""
-            if self.acl is not None:
-                from dgraph_tpu.acl.acl import READ, AclError
+            with TRACER.span("admit", cpu=True, fine=True):
+                ns = keys.GALAXY_NS
+                allowed = None
+                user = ""
+                if self.acl is not None:
+                    from dgraph_tpu.acl.acl import READ, AclError
 
-                try:
-                    if access_jwt is None:
-                        raise AclError("no access token (ACL enabled)")
-                    claims = self.acl.claims(access_jwt)
-                    user = claims.get("userid", "")
-                    ns = int(claims.get("namespace", 0))
-                    self.acl.authorize_preds(
-                        access_jwt, _query_preds(blocks), READ,
-                        claims=claims,
-                    )
-                    allowed = self.acl.readable_preds(claims)
-                except Exception:
-                    self._audit("query", user=user, body=q, status="DENIED")
-                    raise
-            self._audit("query", user=user, ns=ns, body=q)
-            from dgraph_tpu.query.functions import QueryBudgetError
-            from dgraph_tpu.utils import observe
-            from dgraph_tpu.utils.observe import (
-                METRICS,
-                TRACER,
-                profile_scope,
-            )
+                    try:
+                        if access_jwt is None:
+                            raise AclError("no access token (ACL enabled)")
+                        claims = self.acl.claims(access_jwt)
+                        user = claims.get("userid", "")
+                        ns = int(claims.get("namespace", 0))
+                        self.acl.authorize_preds(
+                            access_jwt, _query_preds(blocks), READ,
+                            claims=claims,
+                        )
+                        allowed = self.acl.readable_preds(claims)
+                    except Exception:
+                        self._audit("query", user=user, body=q, status="DENIED")
+                        raise
+                self._audit("query", user=user, ns=ns, body=q)
+                from dgraph_tpu.query.functions import QueryBudgetError
 
-            deadline = (
-                _time.monotonic() + timeout_ms / 1e3
-                if timeout_ms is not None
-                else None
-            )
-            degrade_deadline = None
-            if ticket.degrade:
-                # saturated: run under a bounded budget and return a
-                # partial/degraded response on exhaustion instead of
-                # queueing at full budget (PR 3's partial-result shape)
-                degrade_deadline = (
-                    _time.monotonic() + self.serving.degrade_budget_s()
-                )
                 deadline = (
-                    degrade_deadline
-                    if deadline is None
-                    else min(deadline, degrade_deadline)
+                    _time.monotonic() + timeout_ms / 1e3
+                    if timeout_ms is not None
+                    else None
                 )
-            truncated = False
-            # snapshot-watermark read (ref worker/oracle MaxAssigned):
-            # `_snapshot_ts` is published only after a commit's deltas
-            # are written, and advances in commit-ts order — so a read
-            # AT the watermark sees a complete store without leasing a
-            # fresh ts and waiting out the apply barrier. Under mixed
-            # traffic that wait serialized every read behind the write
-            # pipeline's in-flight window; an in-flight (unacked)
-            # commit is legitimately excluded from the snapshot. 0 =
-            # nothing committed yet: fall back to a fresh barrier-
-            # waited lease.
-            # the watermark is sampled ONCE and reused for BOTH the
-            # read ts and the result-cache key: re-reading
-            # _snapshot_ts at key time would let a commit landing in
-            # between cache watermark-N bytes under the watermark-N+1
-            # key (a one-line TOCTOU that breaks the never-stale
-            # proof)
-            wm = self._snapshot_ts
-            ts = (
-                read_ts
-                if read_ts is not None
-                else (wm or self.zero.read_ts())
-            )
-            t_assigned = _time.monotonic()
+                degrade_deadline = None
+                if ticket.degrade:
+                    # saturated: run under a bounded budget and return a
+                    # partial/degraded response on exhaustion instead of
+                    # queueing at full budget (PR 3's partial-result shape)
+                    degrade_deadline = (
+                        _time.monotonic() + self.serving.degrade_budget_s()
+                    )
+                    deadline = (
+                        degrade_deadline
+                        if deadline is None
+                        else min(deadline, degrade_deadline)
+                    )
+                truncated = False
+                # snapshot-watermark read (ref worker/oracle MaxAssigned):
+                # `_snapshot_ts` is published only after a commit's deltas
+                # are written, and advances in commit-ts order — so a read
+                # AT the watermark sees a complete store without leasing a
+                # fresh ts and waiting out the apply barrier. Under mixed
+                # traffic that wait serialized every read behind the write
+                # pipeline's in-flight window; an in-flight (unacked)
+                # commit is legitimately excluded from the snapshot. 0 =
+                # nothing committed yet: fall back to a fresh barrier-
+                # waited lease.
+                # the watermark is sampled ONCE and reused for BOTH the
+                # read ts and the result-cache key: re-reading
+                # _snapshot_ts at key time would let a commit landing in
+                # between cache watermark-N bytes under the watermark-N+1
+                # key (a one-line TOCTOU that breaks the never-stale
+                # proof)
+                wm = self._snapshot_ts
+                ts = (
+                    read_ts
+                    if read_ts is not None
+                    else (wm or self.zero.read_ts())
+                )
+                t_assigned = _time.monotonic()
             # snapshot-keyed result reuse (serving/resultcache.py):
             # watermark reads with no ACL are a pure function of
             # (shape, literals, vars, ns, watermark) — the PR 7/11
@@ -1356,8 +1371,8 @@ class Server:
                     watermark=wm,
                 )
             cache_base = self._plan_cache_tiers() if debug else None
-            with TRACER.span("query", ns=ns) as root, \
-                    profile_scope(debug=debug) as prof, \
+            root.attrs["ns"] = ns
+            with profile_scope(debug=debug) as prof, \
                     METRICS.timer("query_latency_seconds"):
                 try:
                     cache = LocalCache(self.kv, ts, mem=self.mem)
@@ -1603,10 +1618,13 @@ class Server:
             deadline=deadline,
             batcher=batcher,
         )
-        nodes = ex.process(blocks)
-        data, enc_stats = encode_response_data(
-            nodes, val_vars=ex.val_vars, schema=self.schema, want=want
-        )
+        with observe.TRACER.span("process", cpu=True, fine=True):
+            nodes = ex.process(blocks)
+        with observe.TRACER.span("encode", cpu=True, fine=True) as sp:
+            data, enc_stats = encode_response_data(
+                nodes, val_vars=ex.val_vars, schema=self.schema, want=want
+            )
+            sp.attrs["bytes"] = int(enc_stats.get("bytes", 0))
         prof = observe.current_profile()
         if prof is not None:
             prof.encode.update(enc_stats)

@@ -157,6 +157,42 @@ def _metrics():
     return METRICS
 
 
+def _span(name: str, **attrs):
+    """A tracer span with the thread's CPU time; the per-query `vec.*`
+    spans are fine ones (utils/observe.py), the `ivf.*` of a build not."""
+    from dgraph_tpu.utils.observe import TRACER
+
+    return TRACER.span(
+        name, cpu=True, fine=name.startswith("vec."), **attrs
+    )
+
+
+def _run_tier(tier: str, nq: int, fetch, args, up_bytes: int, want=(0, 1)):
+    """What every jitted tier shares between its host plan (`vec.plan`:
+    probe plan, query upload; at the call site) and its host
+    post-processing (`vec.post`, there too): `vec.launch` (jit fetch
+    and the call that enqueues the program) and `vec.wait` (queueing
+    behind other requests' programs, execution, read-back of the
+    outputs in `want`; the others come back as None)."""
+    with _span("vec.launch", tier=tier, nq=nq):
+        out = fetch()(*args)
+    # read for its wall time; its CPU time (the read-back's copy) is
+    # taken too, so that it comes off the caller's self CPU time
+    with _span("vec.wait", tier=tier, nq=nq) as sp:
+        host = tuple(
+            np.asarray(o) if i in want else None for i, o in enumerate(out)
+        )
+        down = sum(h.nbytes for h in host if h is not None)
+        sp.attrs["bytes"] = down
+    _metrics().inc_many({
+        "device_dispatch_total": 1,
+        f'device_dispatch_total{{family="vec.{tier}"}}': 1,
+        "device_upload_bytes_total": up_bytes,
+        "device_download_bytes_total": down,
+    })
+    return host
+
+
 def _pow2_rows(n: int) -> int:
     return max(_PAD_ROWS, 1 << (max(1, n) - 1).bit_length())
 
@@ -168,11 +204,12 @@ def _jit_brute(metric: str, npool: int):
     import jax.numpy as jnp
 
     def run(V, sqnorm, valid, q):
-        d = _distances(V, sqnorm, q, metric)
-        d = jnp.where(valid, d, jnp.inf)
-        d = jax.lax.optimization_barrier(d)
-        neg, idx = jax.lax.top_k(-d, npool)
-        return -neg, idx
+        with jax.named_scope("vec.brute"):
+            d = _distances(V, sqnorm, q, metric)
+            d = jnp.where(valid, d, jnp.inf)
+            d = jax.lax.optimization_barrier(d)
+            neg, idx = jax.lax.top_k(-d, npool)
+            return -neg, idx
 
     return jax.jit(run)
 
@@ -183,11 +220,12 @@ def _jit_brute_batch(metric: str, npool: int):
     import jax.numpy as jnp
 
     def run(V, sqnorm, valid, Q):
-        d = _distances_batch(V, sqnorm, Q, metric)
-        d = jnp.where(valid[None, :], d, jnp.inf)
-        d = jax.lax.optimization_barrier(d)
-        neg, idx = jax.lax.top_k(-d, npool)
-        return -neg, idx
+        with jax.named_scope("vec.brute"):
+            d = _distances_batch(V, sqnorm, Q, metric)
+            d = jnp.where(valid[None, :], d, jnp.inf)
+            d = jax.lax.optimization_barrier(d)
+            neg, idx = jax.lax.top_k(-d, npool)
+            return -neg, idx
 
     return jax.jit(run)
 
@@ -223,7 +261,7 @@ def _ivf_probe(metric: str, m_slabs: int, npool: int):
     import jax
     import jax.numpy as jnp
 
-    def run(cents, csq, slab_cell, flat_vecs, flat_sq, flat_rows, q):
+    def probe(cents, csq, slab_cell, flat_vecs, flat_sq, flat_rows, q):
         # nearest cells by centroid distance (always euclidean on the
         # centroid geometry — probe selection only, not result ranking)
         cd = (
@@ -242,6 +280,10 @@ def _ivf_probe(metric: str, m_slabs: int, npool: int):
         dd = jax.lax.optimization_barrier(dd)
         neg, idx = jax.lax.top_k(-dd, npool)
         return -neg, rows[idx]
+
+    def run(*args):
+        with jax.named_scope("vec.ivf"):
+            return probe(*args)
 
     return run
 
@@ -734,9 +776,11 @@ class VectorIndex:
             valid_d = jax.device_put(jnp.asarray(valid), sh)
             sqnorm = None  # no replicated sqnorm on the sharded corpus
         else:
-            vecs = jnp.asarray(mat)
-            valid_d = jnp.asarray(valid)
-            sqnorm = jnp.asarray((mat * mat).sum(axis=1))
+            with _span("ivf.upload", rows=cap, bytes=int(mat.nbytes)):
+                vecs = jnp.asarray(mat)
+                valid_d = jnp.asarray(valid)
+                sqnorm = jnp.asarray((mat * mat).sum(axis=1))
+            _metrics().inc("device_upload_bytes_total", int(mat.nbytes))
         ivf = None
         if nlive >= self.ivf_threshold:
             ivf = self._train_ivf(mat[:nlive])
@@ -790,16 +834,18 @@ class VectorIndex:
             if dev["mesh"] is not None:
                 from dgraph_tpu.parallel import mesh as pmesh
 
-                npool = min(max(pool, kk), self._live)
-                dd, idx = pmesh.sharded_topk(
-                    dev["mesh"],
-                    dev["vecs"],
-                    dev["valid"],
-                    jnp.asarray(q),
-                    npool,
+                with _span("vec.plan", tier="sharded", nq=1):
+                    npool = min(max(pool, kk), self._live)
+                    qd = jnp.asarray(q)
+                cand_dists, idx = _run_tier(
+                    "sharded", 1,
+                    lambda: functools.partial(
+                        pmesh.sharded_topk, dev["mesh"]
+                    ),
+                    (dev["vecs"], dev["valid"], qd, npool),
+                    q.nbytes,
                 )
-                cand_dists = np.asarray(dd)
-                cand_uids = dev["uids"][np.asarray(idx)]
+                cand_uids = dev["uids"][idx]
             elif self._jit_ivf_wins(1, dev["ivf"]):
                 COUNTERS.path_jit_ivf += 1
                 cand_uids, cand_dists = self._ivf_search(
@@ -807,20 +853,22 @@ class VectorIndex:
                 )
             else:
                 COUNTERS.path_jit_brute += 1
-                npool = min(max(pool, kk), self._live)
-                fn = _jit_brute(self.metric, int(npool))
-                dd, idx = fn(
-                    dev["vecs"],
-                    dev["sqnorm"],
-                    dev["valid"],
-                    jnp.asarray(q),
+                with _span("vec.plan", tier="brute", nq=1):
+                    npool = min(max(pool, kk), self._live)
+                    qd = jnp.asarray(q)
+                cand_dists, idx = _run_tier(
+                    "brute", 1,
+                    lambda: _jit_brute(self.metric, int(npool)),
+                    (dev["vecs"], dev["sqnorm"], dev["valid"], qd),
+                    q.nbytes,
                 )
-                cand_dists = np.asarray(dd)
-                cand_uids = dev["uids"][np.asarray(idx)]
+                cand_uids = dev["uids"][idx]
 
-            out = self._filter_candidates(
-                cand_uids, cand_dists, kk, distance_threshold, allowed_set
-            )
+            with _span("vec.post", nq=1):
+                out = self._filter_candidates(
+                    cand_uids, cand_dists, kk, distance_threshold,
+                    allowed_set,
+                )
             exhausted = len(cand_uids) >= self._live or pool >= self._live
             if len(out) == kk or exhausted or allowed_set is None:
                 return np.asarray(out, np.uint64)
@@ -871,23 +919,25 @@ class VectorIndex:
             COUNTERS.path_jit_ivf += len(Q)
             return self._ivf_search_batch(dev["ivf"], dev["uids"], Q, kk)
         COUNTERS.path_jit_brute += len(Q)
-        fn = _jit_brute_batch(self.metric, int(kk))
-        # pad the batch to a pow2 width: coalesced similar_to dispatches
-        # arrive at widths 1..4 and each distinct width is a fresh jit
-        # signature otherwise (padded rows are scored and discarded —
-        # per-row top-k, so real rows are unaffected)
         m = len(Q)
-        mp = max(1, 1 << (m - 1).bit_length())
-        Qp = Q if mp == m else np.vstack(
-            [Q, np.zeros((mp - m, Q.shape[1]), np.float32)]
+        with _span("vec.plan", tier="brute", nq=m):
+            # pad the batch to a pow2 width: coalesced similar_to
+            # dispatches arrive at widths 1..4 and each distinct width
+            # is a fresh jit signature otherwise (padded rows are scored
+            # and discarded — per-row top-k, so real rows are unaffected)
+            mp = max(1, 1 << (m - 1).bit_length())
+            Qp = Q if mp == m else np.vstack(
+                [Q, np.zeros((mp - m, Q.shape[1]), np.float32)]
+            )
+            Qd = jnp.asarray(Qp)
+        _, idx = _run_tier(
+            "brute", m,
+            lambda: _jit_brute_batch(self.metric, int(kk)),
+            (dev["vecs"], dev["sqnorm"], dev["valid"], Qd),
+            Qp.nbytes, want=(1,),
         )
-        dd, idx = fn(
-            dev["vecs"],
-            dev["sqnorm"],
-            dev["valid"],
-            jnp.asarray(Qp),
-        )
-        return dev["uids"][np.asarray(idx)[:m]]
+        with _span("vec.post", tier="brute", nq=m):
+            return dev["uids"][idx[:m]]
 
     def search_one(self, q, k: int) -> np.ndarray:
         """Plain (unfiltered) top-k for ONE query — exactly row 0 of
@@ -1561,69 +1611,75 @@ class VectorIndex:
         nlist = self.nlist or knob or int(max(16, math.sqrt(n) * 2))
         nlist = max(1, min(nlist, n))
         rng = np.random.default_rng(0)
-        c_np = _train_centroids(mat, nlist, rng)
+        with _span("ivf.kmeans", rows=n):
+            c_np = _train_centroids(mat, nlist, rng)
         nlist = len(c_np)
         self.build_count += 1
 
         # multi-assignment: each vector lands in its 2 nearest cells —
         # big recall win for weakly-clustered data at 2x cell memory
         # (the reference's HNSW achieves the same via graph redundancy)
-        t2 = _assign_top2(mat, c_np, rng)
-        rows_rep = np.repeat(np.arange(n), 2)
-        cells_rep = t2.reshape(-1)
+        with _span("ivf.assign", rows=n):
+            t2 = _assign_top2(mat, c_np, rng)
+        with _span("ivf.slab_gather", rows=n):
+            rows_rep = np.repeat(np.arange(n), 2)
+            cells_rep = t2.reshape(-1)
 
-        order = np.argsort(cells_rep, kind="stable")
-        sorted_cells = cells_rep[order]
-        flat_rows_cm = rows_rep[order]  # cell-major row ids
-        starts = np.searchsorted(sorted_cells, np.arange(nlist))
-        ends = np.searchsorted(sorted_cells, np.arange(nlist), side="right")
-        lens = (ends - starts).astype(np.int64)
+            order = np.argsort(cells_rep, kind="stable")
+            sorted_cells = cells_rep[order]
+            flat_rows_cm = rows_rep[order]  # cell-major row ids
+            starts = np.searchsorted(sorted_cells, np.arange(nlist))
+            ends = np.searchsorted(sorted_cells, np.arange(nlist), side="right")
+            lens = (ends - starts).astype(np.int64)
 
-        # slab layout: pad each cell to a multiple of _SLAB so every slab
-        # belongs to exactly one cell; top-M slab probing is then a
-        # static-shape device op (_jit_ivf)
-        S = _SLAB
-        slabs_per_cell = np.maximum(1, -(-lens // S))
-        n_slabs = int(slabs_per_cell.sum())
-        flat_rows = np.full((n_slabs * S,), -1, np.int64)
-        slab_cell = np.zeros((n_slabs,), np.int32)
-        off = 0
-        for ci in range(nlist):
-            rws = flat_rows_cm[starts[ci] : ends[ci]]
-            nsl = int(slabs_per_cell[ci])
-            flat_rows[off * S : off * S + len(rws)] = rws
-            slab_cell[off : off + nsl] = ci
-            off += nsl
-        fr2 = flat_rows.reshape(n_slabs, S)
-        fv = np.zeros((n_slabs * S, d), np.float32)
-        sel = flat_rows >= 0
-        fv[sel] = mat[flat_rows[sel]]
+            # slab layout: pad each cell to a multiple of _SLAB so every slab
+            # belongs to exactly one cell; top-M slab probing is then a
+            # static-shape device op (_jit_ivf)
+            S = _SLAB
+            slabs_per_cell = np.maximum(1, -(-lens // S))
+            n_slabs = int(slabs_per_cell.sum())
+            flat_rows = np.full((n_slabs * S,), -1, np.int64)
+            slab_cell = np.zeros((n_slabs,), np.int32)
+            off = 0
+            for ci in range(nlist):
+                rws = flat_rows_cm[starts[ci] : ends[ci]]
+                nsl = int(slabs_per_cell[ci])
+                flat_rows[off * S : off * S + len(rws)] = rws
+                slab_cell[off : off + nsl] = ci
+                off += nsl
+            fr2 = flat_rows.reshape(n_slabs, S)
+            fv = np.zeros((n_slabs * S, d), np.float32)
+            sel = flat_rows >= 0
+            fv[sel] = mat[flat_rows[sel]]
 
-        if self.nprobe is None:
-            # embedding corpora cluster (the index contract); a handful of
-            # nearest cells holds the true neighbors, and multi-assignment
-            # covers boundary queries. ef/pool widening scales the probe
-            # (the HNSW ef analog) when callers need more.
-            pknob = int(config.get("VEC_NPROBE"))
-            self.nprobe = pknob if pknob > 0 else max(8, nlist // 32)
-        # static slab budget ~ nprobe cells' worth of average slabs
-        avg_slabs = max(1.0, n_slabs / nlist)
-        m_slabs = int(min(n_slabs, max(8, round(self.nprobe * avg_slabs))))
-        fsq = (fv * fv).sum(axis=1).astype(np.float32)
-        ivf = {
-            "centroids": c_np,
-            "cell_lens": lens.astype(np.int32),
-            "m_slabs": m_slabs,
-            "n_slabs": n_slabs,
-            "dev": {
-                "cents": jnp.asarray(c_np),
-                "csq": jnp.asarray((c_np * c_np).sum(axis=1)),
-                "slab_cell": jnp.asarray(slab_cell),
-                "flat_vecs": jnp.asarray(fv.reshape(n_slabs, S, d)),
-                "flat_sq": jnp.asarray(fsq.reshape(n_slabs, S)),
-                "flat_rows": jnp.asarray(fr2.astype(np.int32)),
-            },
-        }
+            if self.nprobe is None:
+                # embedding corpora cluster (the index contract); a handful of
+                # nearest cells holds the true neighbors, and multi-assignment
+                # covers boundary queries. ef/pool widening scales the probe
+                # (the HNSW ef analog) when callers need more.
+                pknob = int(config.get("VEC_NPROBE"))
+                self.nprobe = pknob if pknob > 0 else max(8, nlist // 32)
+            # static slab budget ~ nprobe cells' worth of average slabs
+            avg_slabs = max(1.0, n_slabs / nlist)
+            m_slabs = int(min(n_slabs, max(8, round(self.nprobe * avg_slabs))))
+            fsq = (fv * fv).sum(axis=1).astype(np.float32)
+        up_bytes = int(fv.nbytes + fsq.nbytes + c_np.nbytes)
+        with _span("ivf.upload", rows=n_slabs * S, bytes=up_bytes):
+            ivf = {
+                "centroids": c_np,
+                "cell_lens": lens.astype(np.int32),
+                "m_slabs": m_slabs,
+                "n_slabs": n_slabs,
+                "dev": {
+                    "cents": jnp.asarray(c_np),
+                    "csq": jnp.asarray((c_np * c_np).sum(axis=1)),
+                    "slab_cell": jnp.asarray(slab_cell),
+                    "flat_vecs": jnp.asarray(fv.reshape(n_slabs, S, d)),
+                    "flat_sq": jnp.asarray(fsq.reshape(n_slabs, S)),
+                    "flat_rows": jnp.asarray(fr2.astype(np.int32)),
+                },
+            }
+        _metrics().inc("device_upload_bytes_total", up_bytes)
         _metrics().set_gauge(
             "vector_index_build_seconds", time.perf_counter() - t0
         )
@@ -1639,26 +1695,31 @@ class VectorIndex:
         recall lever callers expect from raising ef."""
         import jax.numpy as jnp
 
-        m, npool = _probe_plan(ivf, pool)
-        fn = _jit_ivf(self.metric, int(m), npool)
+        with _span("vec.plan", tier="ivf", nq=1):
+            m, npool = _probe_plan(ivf, pool)
+            qd = jnp.asarray(q, jnp.float32)
         dev = ivf["dev"]
-        dd, rows = fn(
-            dev["cents"],
-            dev["csq"],
-            dev["slab_cell"],
-            dev["flat_vecs"],
-            dev["flat_sq"],
-            dev["flat_rows"],
-            jnp.asarray(q, jnp.float32),
+        dd, rows = _run_tier(
+            "ivf", 1,
+            lambda: _jit_ivf(self.metric, int(m), npool),
+            (
+                dev["cents"],
+                dev["csq"],
+                dev["slab_cell"],
+                dev["flat_vecs"],
+                dev["flat_sq"],
+                dev["flat_rows"],
+                qd,
+            ),
+            q.nbytes,
         )
-        rows = np.asarray(rows)
-        dd = np.asarray(dd)
-        ok = rows >= 0
-        rows, dd = rows[ok], dd[ok]
-        first = _dedup_first(rows)
-        rows, dd = rows[first], dd[first]
-        k = min(pool, rows.size)
-        return uids[rows[:k]], dd[:k]
+        with _span("vec.post", tier="ivf", nq=1):
+            ok = rows >= 0
+            rows, dd = rows[ok], dd[ok]
+            first = _dedup_first(rows)
+            rows, dd = rows[first], dd[first]
+            k = min(pool, rows.size)
+            return uids[rows[:k]], dd[:k]
 
     def _ivf_search_batch(
         self, ivf: dict, uids: np.ndarray, Q: np.ndarray, k: int
@@ -1677,30 +1738,41 @@ class VectorIndex:
         d = int(ivf["dev"]["flat_vecs"].shape[2])
         per_q = m * _SLAB * d * 4  # gather bytes per query
         chunk = max(1, min(len(Q), int(2e9 // max(per_q, 1))))
-        fn = _jit_ivf_batch(self.metric, int(m), npool)
         dev = ivf["dev"]
         out = np.zeros((len(Q), k), np.uint64)
         for off in range(0, len(Q), chunk):
-            qc = np.asarray(Q[off : off + chunk], np.float32)
-            if len(qc) < chunk:  # pad to the compiled batch shape
-                qc = np.vstack(
-                    [qc, np.zeros((chunk - len(qc), qc.shape[1]), np.float32)]
-                )
-            _, rows = fn(
-                dev["cents"],
-                dev["csq"],
-                dev["slab_cell"],
-                dev["flat_vecs"],
-                dev["flat_sq"],
-                dev["flat_rows"],
-                jnp.asarray(qc),
+            with _span("vec.plan", tier="ivf", nq=chunk):
+                qc = np.asarray(Q[off : off + chunk], np.float32)
+                if len(qc) < chunk:  # pad to the compiled batch shape
+                    qc = np.vstack(
+                        [
+                            qc,
+                            np.zeros(
+                                (chunk - len(qc), qc.shape[1]), np.float32
+                            ),
+                        ]
+                    )
+                qd = jnp.asarray(qc)
+            _, rows = _run_tier(
+                "ivf", chunk,
+                lambda: _jit_ivf_batch(self.metric, int(m), npool),
+                (
+                    dev["cents"],
+                    dev["csq"],
+                    dev["slab_cell"],
+                    dev["flat_vecs"],
+                    dev["flat_sq"],
+                    dev["flat_rows"],
+                    qd,
+                ),
+                qc.nbytes, want=(1,),
             )
-            rows = np.asarray(rows)
-            for i in range(min(chunk, len(Q) - off)):
-                r = rows[i]
-                r = r[r >= 0]
-                r = r[_dedup_first(r)][:k]
-                out[off + i, : len(r)] = uids[r]
+            with _span("vec.post", tier="ivf", nq=chunk):
+                for i in range(min(chunk, len(Q) - off)):
+                    r = rows[i]
+                    r = r[r >= 0]
+                    r = r[_dedup_first(r)][:k]
+                    out[off + i, : len(r)] = uids[r]
         return out
 
 

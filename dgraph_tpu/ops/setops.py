@@ -26,6 +26,8 @@ flow) and have `jax.vmap` applied by the batch dispatcher (query/dispatch.py).
 
 from __future__ import annotations
 
+import functools
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -41,6 +43,20 @@ def pad_sorted(arr: np.ndarray, size: int) -> np.ndarray:
     out = np.full((size,), UINT32_MAX, dtype=np.uint32)
     out[: arr.shape[0]] = arr
     return out
+
+
+def scoped(name: str, fn):
+    """`fn`, traced under `jax.named_scope(name)`: the profiler's
+    name-scope lines then group the program's fusions and sorts under a
+    name of ours (`setop.<op>.<family>`), whatever XLA calls them. HLO
+    metadata only; the computation is the same."""
+
+    @functools.wraps(fn)  # the jitted program keeps fn's name
+    def run(*args):
+        with jax.named_scope(name):
+            return fn(*args)
+
+    return run
 
 
 def _iota_mask(n: int, length) -> jnp.ndarray:

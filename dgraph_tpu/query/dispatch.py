@@ -24,7 +24,7 @@ from __future__ import annotations
 
 import threading
 from collections import OrderedDict
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 import jax
@@ -33,6 +33,7 @@ import jax.numpy as jnp
 from dgraph_tpu.codec import uidpack
 from dgraph_tpu.codec.uidpack import join_segments, split_segments
 from dgraph_tpu.ops import packed_setops, setops
+from dgraph_tpu.utils.observe import METRICS, TRACER
 from dgraph_tpu.x import config, device
 
 # Below this much total work, host kernels win (dispatch overhead
@@ -229,6 +230,13 @@ class PackedOperand:
         return self._uids
 
 
+class _Sharded(NamedTuple):
+    """A host operand `_run_device` uploads row-sharded over the mesh."""
+
+    arr: np.ndarray
+    sharding: object
+
+
 def _as_array(x) -> np.ndarray:
     return x.decode() if isinstance(x, PackedOperand) else np.asarray(
         x, np.uint64
@@ -384,6 +392,59 @@ class SetOpDispatcher:
             return _DEVICE_MIN_TOTAL
         return _HOST_ONLY if platform == "cpu" else _ACCEL_MIN_TOTAL
 
+    def _run_device(self, family: str, fetch, operands, keep=None) -> tuple:
+        """What every device call shares after its operands are padded
+        (`setop.pad`, at the call site) and before its result is cut
+        back into rows (`setop.split`, there too): `setop.upload` of
+        the operands still on the host — a numpy array, or a `_Sharded`
+        one for the mesh; a device array is a DeviceCache hit — and,
+        through `keep(operands as the device holds them)`, the
+        caller's DeviceCache inserts, which stay where they always
+        were: before the launch, and inside a span (an insert that
+        evicts walks every cached key: PERF.md, PR 26);
+        `setop.launch` (jit fetch and the call that enqueues the
+        program) and `setop.wait` (queueing behind other requests'
+        programs, execution, download). Returns the outputs as numpy
+        arrays."""
+        with TRACER.span("setop.upload", cpu=True, fine=True) as sp:
+            nbytes = hits = misses = 0
+            dev = []
+            for x in operands:
+                if isinstance(x, _Sharded):
+                    nbytes += x.arr.nbytes
+                    misses += 1
+                    x = jax.device_put(jnp.asarray(x.arr), x.sharding)
+                elif isinstance(x, np.ndarray):
+                    nbytes += x.nbytes
+                    misses += 1
+                    x = jnp.asarray(x)
+                elif isinstance(x, jax.Array):
+                    hits += 1
+                dev.append(x)
+            sp.attrs.update(
+                bytes=nbytes, cache_hits=hits, cache_misses=misses
+            )
+            if keep is not None:
+                keep(dev)
+        with TRACER.span(
+            "setop.launch", cpu=True, fine=True, family=family
+        ):
+            out = fetch()(*dev)
+        # read for its wall time; its CPU time (the read-back's copy) is
+        # taken too, so that it comes off the caller's self CPU time
+        with TRACER.span("setop.wait", cpu=True, fine=True) as sp:
+            out = out if isinstance(out, tuple) else (out,)
+            host = tuple(np.asarray(o) for o in out)
+            down = sum(h.nbytes for h in host)
+            sp.attrs["bytes"] = down
+        METRICS.inc_many({
+            "device_dispatch_total": 1,
+            f'device_dispatch_total{{family="{family}"}}': 1,
+            "device_upload_bytes_total": nbytes,
+            "device_download_bytes_total": down,
+        })
+        return host
+
     # -- shared-big-operand fan-out -----------------------------------------
 
     def run_rows_vs_one(
@@ -411,6 +472,9 @@ class SetOpDispatcher:
             return []
         total = sum(len(r) for r in rows) + len(b)
         if total < self._min_total():
+            # the host kernels answer it: no span (these are the
+            # small, frequent ones), one counter
+            METRICS.inc("device_host_kept_total")
             if op in ("intersect", "difference") and len(rows) > 4:
                 # vectorized host fallback: ONE searchsorted over the
                 # concatenated rows beats per-row native calls (ctypes
@@ -443,64 +507,80 @@ class SetOpDispatcher:
             got = self._run_rows_sharded(op, rows, b, b_token)
             if got is not None:
                 return got
-        bseg = split_segments(np.asarray(b, np.uint64))
-        row_segs = [split_segments(np.asarray(r, np.uint64)) for r in rows]
-        his = set(bseg)
-        for rs in row_segs:
-            his |= set(rs)
-        if len(his) > 1 or any(len(rs) > 1 for rs in row_segs):
+        family = op + "#shared"
+        with TRACER.span(
+            "setop.pad", cpu=True, fine=True, family=family
+        ) as sp:
+            bseg = split_segments(np.asarray(b, np.uint64))
+            row_segs = [
+                split_segments(np.asarray(r, np.uint64)) for r in rows
+            ]
+            his = set(bseg)
+            for rs in row_segs:
+                his |= set(rs)
+            one_segment = len(his) <= 1 and all(
+                len(rs) <= 1 for rs in row_segs
+            )
+            if one_segment:
+                hi = next(iter(his)) if his else 0
+                b32 = bseg.get(hi, np.zeros((0,), np.uint32))
+                pb = _pow2(len(b32))
+                b_key = stack_tok = None
+                B = A = None
+                if b_token is not None:
+                    b_key = ("b", b_token, hi, pb)
+                    cached = self.device_cache.get(b_key)
+                    if cached is not None:
+                        B = cached[0]
+                if B is None:
+                    B = setops.pad_sorted(b32, pb)
+                LB = np.int32(len(b32))
+
+                pa = _pow2(
+                    max((len(rs.get(hi, ())) for rs in row_segs), default=1)
+                )
+                n = len(rows)
+                nb = _pow2(n)
+                if row_tokens is not None and len(row_tokens) == n and all(
+                    t is not None for t in row_tokens
+                ):
+                    stack_tok = ("stack", hi, pa, nb, tuple(row_tokens))
+                    cached = self.device_cache.get(stack_tok)
+                    if cached is not None:
+                        A, LA = cached
+                if A is None:
+                    A = np.full((nb, pa), setops.UINT32_MAX, np.uint32)
+                    LA = np.zeros((nb,), np.int32)
+                    for i, rs in enumerate(row_segs):
+                        r32 = rs.get(hi, np.zeros((0,), np.uint32))
+                        A[i, : len(r32)] = r32
+                        LA[i] = len(r32)
+                sp.attrs.update(rows=n, pa=pa, pb=pb)
+        if not one_segment:
             return self.run_pairs(op, [(r, b) for r in rows])
 
-        hi = next(iter(his)) if his else 0
-        b32 = bseg.get(hi, np.zeros((0,), np.uint32))
-        pb = _pow2(len(b32))
-        Bd = None
-        if b_token is not None:
-            cached = self.device_cache.get(("b", b_token, hi, pb))
-            if cached is not None:
-                Bd = cached[0]
-        if Bd is None:
-            Bd = jnp.asarray(setops.pad_sorted(b32, pb))
-            if b_token is not None:
-                self.device_cache.put(
-                    ("b", b_token, hi, pb), [b_token[0]], (Bd,), pb * 4
-                )
-        LB = np.int32(len(b32))
-
-        pa = _pow2(max((len(rs.get(hi, ())) for rs in row_segs), default=1))
-        n = len(rows)
-        nb = _pow2(n)
-        Ad = LAd = None
-        stack_tok = None
-        if row_tokens is not None and len(row_tokens) == n and all(
-            t is not None for t in row_tokens
-        ):
-            stack_tok = ("stack", hi, pa, nb, tuple(row_tokens))
-            cached = self.device_cache.get(stack_tok)
-            if cached is not None:
-                Ad, LAd = cached
-        if Ad is None:
-            A = np.full((nb, pa), setops.UINT32_MAX, np.uint32)
-            LA = np.zeros((nb,), np.int32)
-            for i, rs in enumerate(row_segs):
-                r32 = rs.get(hi, np.zeros((0,), np.uint32))
-                A[i, : len(r32)] = r32
-                LA[i] = len(r32)
-            Ad, LAd = jnp.asarray(A), jnp.asarray(LA)
-            if stack_tok is not None:
+        def keep(dev):
+            Ad, LAd, Bd, _ = dev
+            if b_key is not None and Bd is not B:
+                self.device_cache.put(b_key, [b_token[0]], (Bd,), pb * 4)
+            if stack_tok is not None and Ad is not A:
                 self.device_cache.put(
                     stack_tok,
                     [t[0] for t in row_tokens],
                     (Ad, LAd),
                     int(nb * pa * 4 + nb * 4),
                 )
-        fn = self._get_jitted_shared(op, pa, pb)
-        out, cnt = fn(Ad, LAd, Bd, LB)
-        out = np.asarray(out)
-        cnt = np.asarray(cnt)
-        res = []
-        for i in range(n):
-            res.append(join_segments({hi: out[i, : cnt[i]]}))
+
+        out, cnt = self._run_device(
+            family,
+            lambda: self._get_jitted_shared(op, pa, pb),
+            [A, LA, B, LB],
+            keep,
+        )
+        with TRACER.span("setop.split", cpu=True, fine=True):
+            res = []
+            for i in range(n):
+                res.append(join_segments({hi: out[i, : cnt[i]]}))
         return res
 
     def run_rows_vs_one_ragged(
@@ -536,6 +616,7 @@ class SetOpDispatcher:
         total = flat.size + b64.size
         host = total < self._min_total()
         if host and op in ("intersect", "difference") and flat.size:
+            METRICS.inc("device_host_kept_total")
             idx = np.minimum(
                 np.searchsorted(b64, flat), b64.size - 1
             )
@@ -590,33 +671,42 @@ class SetOpDispatcher:
             # air (the uid_in reverse fan-out shape at 5M+ scale)
             return np.unique(np.concatenate(parts))
         if total < self._min_total():
+            METRICS.inc("device_host_kept_total")
             if op == "union" and len(parts) > 4:
                 return np.unique(np.concatenate(parts))
             out = parts[0]
             for p in parts[1:]:
                 out = _np_op(op, out, p)
             return out
-        segs = [split_segments(p) for p in parts]
-        his = set()
-        for s in segs:
-            his |= set(s)
+        family = op + "#chain"
+        with TRACER.span(
+            "setop.pad", cpu=True, fine=True, family=family
+        ) as sp:
+            segs = [split_segments(p) for p in parts]
+            his = set()
+            for s in segs:
+                his |= set(s)
+            if len(his) <= 1:
+                hi = next(iter(his)) if his else 0
+                arrs = [s.get(hi, np.zeros((0,), np.uint32)) for s in segs]
+                k = len(arrs)
+                pad = _pow2(max(len(a) for a in arrs))
+                M = np.full((k, pad), setops.UINT32_MAX, np.uint32)
+                L = np.zeros((k,), np.int32)
+                for i, a in enumerate(arrs):
+                    M[i, : len(a)] = a
+                    L[i] = len(a)
+                sp.attrs.update(rows=k, pa=pad, pb=0)
         if len(his) > 1:
             out = parts[0]
             for p in parts[1:]:
                 out = self.run_pairs(op, [(out, p)])[0]
             return out
-        hi = next(iter(his)) if his else 0
-        arrs = [s.get(hi, np.zeros((0,), np.uint32)) for s in segs]
-        k = len(arrs)
-        pad = _pow2(max(len(a) for a in arrs))
-        M = np.full((k, pad), setops.UINT32_MAX, np.uint32)
-        L = np.zeros((k,), np.int32)
-        for i, a in enumerate(arrs):
-            M[i, : len(a)] = a
-            L[i] = len(a)
-        fn = self._get_jitted_chain(op, k, pad)
-        out, cnt = fn(jnp.asarray(M), jnp.asarray(L))
-        return join_segments({hi: np.asarray(out)[: int(cnt)]})
+        out, cnt = self._run_device(
+            family, lambda: self._get_jitted_chain(op, k, pad), [M, L]
+        )
+        with TRACER.span("setop.split", cpu=True, fine=True):
+            return join_segments({hi: out[: int(cnt)]})
 
     def _run_chain_packed_intersect(self, parts: List) -> np.ndarray:
         """Intersect chain with packed operands: fold from the smallest
@@ -661,7 +751,9 @@ class SetOpDispatcher:
                         if op == "intersect"
                         else setops.merge_sorted
                     )
-                    fn = self._jit_cache[key] = jax.jit(base)
+                    fn = self._jit_cache[key] = jax.jit(
+                        setops.scoped(f"setop.{op}.chain", base)
+                    )
         return fn
 
     def _run_rows_sharded(self, op, rows, b, b_token):
@@ -672,54 +764,74 @@ class SetOpDispatcher:
         from dgraph_tpu.parallel import mesh as pmesh
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        b64 = np.asarray(b, np.uint64)
-        bseg = split_segments(b64)
-        row_segs = [split_segments(np.asarray(r, np.uint64)) for r in rows]
-        his = set(bseg)
-        for rs in row_segs:
-            his |= set(rs)
-        if len(his) != 1:
-            return None
-        hi = next(iter(his))
-        b32 = bseg[hi]
-        mesh = pmesh.make_mesh()
-        ndev = mesh.devices.size
-        sh = NamedSharding(mesh, P("data"))
+        family = op + "#sharded"
+        with TRACER.span(
+            "setop.pad", cpu=True, fine=True, family=family
+        ) as sp:
+            b64 = np.asarray(b, np.uint64)
+            bseg = split_segments(b64)
+            row_segs = [
+                split_segments(np.asarray(r, np.uint64)) for r in rows
+            ]
+            his = set(bseg)
+            for rs in row_segs:
+                his |= set(rs)
+            if len(his) != 1:
+                return None
+            hi = next(iter(his))
+            b32 = bseg[hi]
+            mesh = pmesh.make_mesh()
+            ndev = mesh.devices.size
 
-        Bd = None
-        tile = -(-len(b32) // ndev)
-        tile = max(_MIN_PAD, 1 << (tile - 1).bit_length())
-        pb = tile * ndev
-        if b_token is not None:
-            cached = self.device_cache.get(("bshard", b_token, hi, pb))
-            if cached is not None:
-                Bd = cached[0]
-        if Bd is None:
-            Bd = jax.device_put(
-                jnp.asarray(setops.pad_sorted(b32, pb)), sh
-            )
+            tile = -(-len(b32) // ndev)
+            tile = max(_MIN_PAD, 1 << (tile - 1).bit_length())
+            pb = tile * ndev
+            b_key = B = None
             if b_token is not None:
-                self.device_cache.put(
-                    ("bshard", b_token, hi, pb), [b_token[0]], (Bd,), pb * 4
+                b_key = ("bshard", b_token, hi, pb)
+                cached = self.device_cache.get(b_key)
+                if cached is not None:
+                    B = cached[0]
+            if B is None:
+                B = _Sharded(
+                    setops.pad_sorted(b32, pb),
+                    NamedSharding(mesh, P("data")),
                 )
 
-        n = len(rows)
-        pa = _pow2(max((len(rs.get(hi, ())) for rs in row_segs), default=1))
-        A = np.full((n, pa), setops.UINT32_MAX, np.uint32)
-        LA = np.zeros((n,), np.int32)
-        for i, rs in enumerate(row_segs):
-            r32 = rs.get(hi, np.zeros((0,), np.uint32))
-            A[i, : len(r32)] = r32
-            LA[i] = len(r32)
-        mask = np.asarray(
-            pmesh.sharded_rows_membership(mesh, jnp.asarray(A), LA, Bd, len(b32))
+            n = len(rows)
+            pa = _pow2(
+                max((len(rs.get(hi, ())) for rs in row_segs), default=1)
+            )
+            A = np.full((n, pa), setops.UINT32_MAX, np.uint32)
+            LA = np.zeros((n,), np.int32)
+            for i, rs in enumerate(row_segs):
+                r32 = rs.get(hi, np.zeros((0,), np.uint32))
+                A[i, : len(r32)] = r32
+                LA[i] = len(r32)
+            sp.attrs.update(rows=n, pa=pa, pb=pb)
+        # LA rides along as a numpy array: sharded_rows_membership
+        # converts it itself, as it always did
+        def keep(dev):
+            if b_key is not None and dev[1] is not B:
+                self.device_cache.put(
+                    b_key, [b_token[0]], (dev[1],), pb * 4
+                )
+
+        (mask,) = self._run_device(
+            family,
+            lambda: lambda Ad, Bd: pmesh.sharded_rows_membership(
+                mesh, Ad, LA, Bd, len(b32)
+            ),
+            [A, B],
+            keep,
         )
-        out = []
-        for i in range(n):
-            row = A[i, : LA[i]]
-            m = mask[i, : LA[i]]
-            kept = row[m] if op == "intersect" else row[~m]
-            out.append(join_segments({hi: kept}))
+        with TRACER.span("setop.split", cpu=True, fine=True):
+            out = []
+            for i in range(n):
+                row = A[i, : LA[i]]
+                m = mask[i, : LA[i]]
+                kept = row[m] if op == "intersect" else row[~m]
+                out.append(join_segments({hi: kept}))
         return out
 
     def _get_jitted_shared(self, op: str, pa: int, pb: int):
@@ -735,7 +847,10 @@ class SetOpDispatcher:
                         "union": setops.union,
                     }[op]
                     fn = self._jit_cache[key] = jax.jit(
-                        jax.vmap(base, in_axes=(0, 0, None, None))
+                        jax.vmap(
+                            setops.scoped(f"setop.{op}.shared", base),
+                            in_axes=(0, 0, None, None),
+                        )
                     )
         return fn
 
@@ -766,14 +881,13 @@ class SetOpDispatcher:
             dense_at.append(i)
         # kernel-choice accounting (packed vs decoded) for the per-query
         # profile and the cluster metrics endpoint
-        from dgraph_tpu.utils.observe import METRICS
-
         METRICS.inc("setop_pairs_total", len(pairs))
         if len(dense) < len(pairs):
             METRICS.inc("setop_packed_total", len(pairs) - len(dense))
         if dense:
             total = sum(len(a) + len(b) for a, b in dense)
             if total < self._min_total():
+                METRICS.inc("device_host_kept_total")
                 got = [_np_op(op, a, b) for a, b in dense]
             else:
                 got = self._run_pairs_device(op, dense)
@@ -793,26 +907,30 @@ class SetOpDispatcher:
     # -- device path --------------------------------------------------------
 
     def _run_pairs_device(self, op, pairs):
-        # Explode u64 pairs into u32 segment sub-jobs.
-        sub: List[Tuple[int, int, np.ndarray, np.ndarray]] = []  # (pair, hi, a, b)
-        passthrough: List[Tuple[int, int, np.ndarray]] = []  # (pair, hi, lo)
-        for pi, (a, b) in enumerate(pairs):
-            sa = split_segments(np.asarray(a, np.uint64))
-            sb = split_segments(np.asarray(b, np.uint64))
-            his = set(sa) | set(sb)
-            for hi in his:
-                la, lb = sa.get(hi), sb.get(hi)
-                if la is not None and lb is not None:
-                    sub.append((pi, hi, la, lb))
-                elif la is not None and op in ("union", "difference"):
-                    passthrough.append((pi, hi, la))
-                elif lb is not None and op == "union":
-                    passthrough.append((pi, hi, lb))
+        with TRACER.span("setop.pad", cpu=True, fine=True, family=op) as sp:
+            # Explode u64 pairs into u32 segment sub-jobs.
+            sub: List[Tuple[int, int, np.ndarray, np.ndarray]] = []  # (pair, hi, a, b)
+            passthrough: List[Tuple[int, int, np.ndarray]] = []  # (pair, hi, lo)
+            for pi, (a, b) in enumerate(pairs):
+                sa = split_segments(np.asarray(a, np.uint64))
+                sb = split_segments(np.asarray(b, np.uint64))
+                his = set(sa) | set(sb)
+                for hi in his:
+                    la, lb = sa.get(hi), sb.get(hi)
+                    if la is not None and lb is not None:
+                        sub.append((pi, hi, la, lb))
+                    elif la is not None and op in ("union", "difference"):
+                        passthrough.append((pi, hi, la))
+                    elif lb is not None and op == "union":
+                        passthrough.append((pi, hi, lb))
 
-        # Bucket sub-jobs by padded shapes.
-        buckets: Dict[Tuple[int, int], List[int]] = {}
-        for i, (_, _, a, b) in enumerate(sub):
-            buckets.setdefault((_pow2(len(a)), _pow2(len(b))), []).append(i)
+            # Bucket sub-jobs by padded shapes.
+            buckets: Dict[Tuple[int, int], List[int]] = {}
+            for i, (_, _, a, b) in enumerate(sub):
+                buckets.setdefault(
+                    (_pow2(len(a)), _pow2(len(b))), []
+                ).append(i)
+            sp.attrs.update(rows=len(sub), buckets=len(buckets))
 
         # Regroup per pair in one pass. A (pair, hi) key lands either in a
         # device sub-job (segment present in both operands) or in
@@ -824,7 +942,8 @@ class SetOpDispatcher:
                 by_pair[pi][hi] = res
         for pi, hi, lo in passthrough:
             by_pair[pi][hi] = lo
-        return [join_segments(segs) for segs in by_pair]
+        with TRACER.span("setop.split", cpu=True, fine=True):
+            return [join_segments(segs) for segs in by_pair]
 
     def _get_jitted(self, op: str, pa: int, pb: int):
         key = (op, pa, pb)
@@ -844,29 +963,40 @@ class SetOpDispatcher:
                         # batch-aware pallas entry point — do NOT vmap a
                         # single-example pallas kernel (TPU lowering
                         # rejects the Squeezed SMEM blocks vmap produces)
-                        fn = jax.jit(pallas_setops.intersect_batch)
+                        fn = jax.jit(
+                            setops.scoped(
+                                f"setop.{op}.pairs",
+                                pallas_setops.intersect_batch,
+                            )
+                        )
                     else:
-                        fn = jax.jit(jax.vmap(base))
+                        fn = jax.jit(
+                            jax.vmap(setops.scoped(f"setop.{op}.pairs", base))
+                        )
                     self._jit_cache[key] = fn
         return fn
 
     def _run_bucket(self, op, pa, pb, jobs):
-        n = len(jobs)
-        nb = _pow2(n)
-        A = np.full((nb, pa), setops.UINT32_MAX, np.uint32)
-        B = np.full((nb, pb), setops.UINT32_MAX, np.uint32)
-        LA = np.zeros((nb,), np.int32)
-        LB = np.zeros((nb,), np.int32)
-        for i, (_, _, a, b) in enumerate(jobs):
-            A[i, : len(a)] = a
-            B[i, : len(b)] = b
-            LA[i] = len(a)
-            LB[i] = len(b)
-        fn = self._get_jitted(op, pa, pb)
-        out, cnt = fn(jnp.asarray(A), jnp.asarray(LA), jnp.asarray(B), jnp.asarray(LB))
-        out = np.asarray(out)
-        cnt = np.asarray(cnt)
-        return [out[i, : cnt[i]] for i in range(n)]
+        with TRACER.span(
+            "setop.pad", cpu=True, fine=True, family=op, rows=len(jobs),
+            pa=pa, pb=pb,
+        ):
+            n = len(jobs)
+            nb = _pow2(n)
+            A = np.full((nb, pa), setops.UINT32_MAX, np.uint32)
+            B = np.full((nb, pb), setops.UINT32_MAX, np.uint32)
+            LA = np.zeros((nb,), np.int32)
+            LB = np.zeros((nb,), np.int32)
+            for i, (_, _, a, b) in enumerate(jobs):
+                A[i, : len(a)] = a
+                B[i, : len(b)] = b
+                LA[i] = len(a)
+                LB[i] = len(b)
+        out, cnt = self._run_device(
+            op, lambda: self._get_jitted(op, pa, pb), [A, LA, B, LB]
+        )
+        with TRACER.span("setop.split", cpu=True, fine=True):
+            return [out[i, : cnt[i]] for i in range(n)]
 
 
 # Module-level singleton used by the executor.

@@ -1078,8 +1078,9 @@ class Executor:
             # per-uid loop is the DGRAPH_TPU_LEVEL_BATCH=0 escape hatch)
             t0 = time.perf_counter()
             with TRACER.span(
-                "level_task", attr=attr, parents=len(level_keys)
-            ):
+                "level_task", cpu=True, attr=attr,
+                parents=len(level_keys), level=self._level_of(parent),
+            ) as sp:
                 METRICS.inc("level_tasks_started")
                 METRICS.inc("level_task_uids", len(level_keys))
                 if self.level_batch:
@@ -1103,6 +1104,7 @@ class Executor:
                         rows.append(r)
                         row_toks.append(tok)
                     flat, offs = ragged.pack_rows(rows)
+                sp.attrs["decoded_bytes"] = int(flat.nbytes)
             self._record_level_task(
                 attr, parent, len(level_keys), t0,
                 uids_out=len(flat), decoded_bytes=int(flat.nbytes),
@@ -1220,7 +1222,8 @@ class Executor:
             ]
             t0 = time.perf_counter()
             with TRACER.span(
-                "level_task", attr=attr, parents=len(dkeys)
+                "level_task", cpu=True, attr=attr, parents=len(dkeys),
+                level=self._level_of(parent),
             ):
                 METRICS.inc("level_tasks_started")
                 METRICS.inc("level_task_uids", len(dkeys))

@@ -41,10 +41,12 @@ Three subsystems:
 
 from __future__ import annotations
 
+import bisect
 import fnmatch
 import json
 import os
 import random
+import sys
 import threading
 import time
 from collections import OrderedDict, deque
@@ -134,18 +136,14 @@ class Histogram:
             [None] * (len(self.buckets) + 1)
         )
 
-    def observe(self, v: float, trace_id: int = 0):
+    def observe(self, v: float, trace_id: int = 0, ts: float = 0.0):
         self.sum += v
         self.total += 1
-        for i, b in enumerate(self.buckets):
-            if v <= b:
-                self.counts[i] += 1
-                if trace_id:
-                    self.exemplars[i] = (v, trace_id, time.time())
-                return
-        self.counts[-1] += 1
+        # first bucket whose bound is >= v; past the last one is +Inf
+        i = bisect.bisect_left(self.buckets, v)
+        self.counts[i] += 1
         if trace_id:
-            self.exemplars[-1] = (v, trace_id, time.time())
+            self.exemplars[i] = (v, trace_id, ts or time.time())
 
 
 class Metrics:
@@ -154,6 +152,11 @@ class Metrics:
     def __init__(self, prefix: str = "dgraph_tpu"):
         self.prefix = prefix
         self._lock = threading.Lock()
+        # the `span_<name>_seconds` histograms are written under a lock
+        # of their own (`observe_spans`): a request tree's batch must
+        # not hold up every thread's next `inc` (PERF.md, PR 26).
+        # Readers of `_hists` take both, `_lock` first.
+        self._span_lock = threading.Lock()
         self._counters: Dict[str, float] = {}
         self._gauges: Dict[str, float] = {}
         self._hists: Dict[str, Histogram] = {}
@@ -216,11 +219,44 @@ class Metrics:
                 h = self._hists[name] = Histogram(buckets)
             h.observe(seconds, trace_id)
 
+    def inc_many(self, deltas: Dict[str, float]) -> None:
+        """Several counters under ONE acquisition of the lock: a call
+        site on a request's path that bumps a handful (a device
+        dispatch) pays for one."""
+        with self._lock:
+            for name, delta in deltas.items():
+                self._counters[name] = self._counters.get(name, 0) + delta
+
+    def observe_spans(self, spans) -> None:
+        """Tracer._finish's insert of finished spans into their
+        `span_<name>_seconds` histograms, a whole request tree under
+        ONE acquisition of the span lock (under 16 handler threads on
+        the chip's host a lock taken per span cost ~20 us a span:
+        PERF.md). A span is observed once, whoever brings it. The
+        exemplar's trace id and time are the span's own: nothing is
+        read from the environment or the context."""
+        with self._span_lock:
+            for sp in spans:
+                if sp._observed:
+                    continue
+                sp._observed = True
+                name = _SPAN_HIST.get(sp.name)
+                if name is None:
+                    name = _SPAN_HIST[sp.name] = f"span_{sp.name}_seconds"
+                h = self._hists.get(name)
+                if h is None:
+                    h = self._hists[name] = Histogram()
+                h.observe(
+                    sp.end - sp.start,
+                    sp.trace_id if sp._exemplar else 0,
+                    sp.end,
+                )
+
     def hist_stats(self, name: str) -> Tuple[float, int]:
         """(sum, count) of one histogram (0, 0 when never observed) —
         benchmarks diff this around a run for realized batch widths
         without parsing the exposition text."""
-        with self._lock:
+        with self._lock, self._span_lock:
             h = self._hists.get(name)
             return (h.sum, h.total) if h is not None else (0.0, 0)
 
@@ -228,14 +264,14 @@ class Metrics:
         """(sum, count) of EVERY histogram — the metrics-history ring's
         histogram component (per-bucket counts stay out of the ring;
         windowed mean latency needs only sum/count deltas)."""
-        with self._lock:
+        with self._lock, self._span_lock:
             return {k: (h.sum, h.total) for k, h in self._hists.items()}
 
     def exemplars(self, name: str) -> List[dict]:
         """The retained exemplars of one histogram: [{le, value,
         trace_id, ts}] — what the slow-query log embeds to close the
         metrics→trace loop without parsing the exposition."""
-        with self._lock:
+        with self._lock, self._span_lock:
             h = self._hists.get(name)
             if h is None:
                 return []
@@ -263,7 +299,7 @@ class Metrics:
 
     def render(self) -> str:
         out: List[str] = []
-        with self._lock:
+        with self._lock, self._span_lock:
             for k, v in sorted(self._counters.items()):
                 out.append(f"# TYPE {self.prefix}_{k} counter")
                 out.append(f"{self.prefix}_{k} {v}")
@@ -293,7 +329,7 @@ class Metrics:
         not need exemplars — they are per-process trace anchors, not
         aggregatable counts). Terminated by `# EOF` per the spec."""
         out: List[str] = []
-        with self._lock:
+        with self._lock, self._span_lock:
             for k, v in sorted(self._counters.items()):
                 # OpenMetrics counters sample as <name>_total with the
                 # metric FAMILY name in TYPE; most of our counter names
@@ -764,9 +800,16 @@ def _gen_span_id() -> int:
 
 
 class Span:
+    """One span, and its own context manager: `with TRACER.span(..) as
+    sp`. `cpu_ms` is the thread's CPU time between enter and exit (only
+    on spans opened with cpu=True, in a tree whose root began under a
+    profiler session); `tid` the thread it ran on."""
+
     __slots__ = (
         "name", "trace_id", "span_id", "parent_id", "start", "end",
-        "attrs", "sampled", "_exported",
+        "attrs", "sampled", "_exported", "tid", "cpu_ms",
+        "_tracer", "_buf", "_root", "_annotate", "_exemplar", "_detail",
+        "_observed", "_ann", "_cpu0", "_token",
     )
 
     def __init__(self, name, trace_id, span_id, parent_id, sampled=True):
@@ -779,6 +822,48 @@ class Span:
         self.attrs: Dict[str, object] = {}
         self.sampled = sampled
         self._exported = False
+        self.tid = 0
+        self.cpu_ms: Optional[float] = None
+        self._tracer = None
+        self._buf = None
+        self._root = None  # the tree's local root; a root's is itself
+        self._annotate = False
+        self._exemplar = False
+        # on a local root: whether its tree takes the fine spans; None
+        # until the first fine site below it asks
+        self._detail: Optional[bool] = None
+        self._observed = False
+        self._ann = None
+        self._cpu0 = None
+        self._token = None
+
+    def __enter__(self) -> "Span":
+        self._token = _CURRENT.set(self)
+        self.tid = threading.get_ident()
+        if self._annotate:
+            # the same span as an event on the profiler's host plane,
+            # so program spans and the device's "XLA Ops" line share
+            # one clock (jax is imported: _profiler_active saw it)
+            self._ann = sys.modules["jax"].profiler.TraceAnnotation(
+                self.name,
+                trace_id=f"{self.trace_id:032x}",
+                span_id=f"{self.span_id:016x}",
+            )
+            self._ann.__enter__()
+        if self._cpu0 is not None:
+            self._cpu0 = time.thread_time_ns()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.end = time.time()
+        if self._cpu0 is not None:
+            self.cpu_ms = (time.thread_time_ns() - self._cpu0) / 1e6
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+            self._ann = None
+        _CURRENT.reset(self._token)
+        self._token = None
+        self._tracer._finish(self)
 
     def to_dict(self) -> dict:
         return {
@@ -791,9 +876,67 @@ class Span:
             "duration_ms": (
                 None if self.end is None else (self.end - self.start) * 1e3
             ),
+            "cpu_ms": self.cpu_ms,
+            "tid": self.tid,
             "sampled": self.sampled,
             "attrs": self.attrs,
         }
+
+
+class _DropDict(dict):
+    """attrs of a span that records nothing: writes are dropped."""
+
+    __slots__ = ()
+
+    def __setitem__(self, key, value) -> None:
+        pass
+
+    def update(self, *a, **kw) -> None:
+        pass
+
+
+class _NullSpan:
+    """What a span site gets while tracing is off: no ids, no clock, no
+    ring, and nothing allocated — every child site of an untraced root
+    shares the one NULL_SPAN."""
+
+    __slots__ = ()
+    name = ""
+    trace_id = 0
+    span_id = 0
+    parent_id = None
+    sampled = False
+    cpu_ms = None
+    attrs = _DropDict()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc) -> None:
+        pass
+
+
+NULL_SPAN = _NullSpan()
+
+
+class _NullRoot(_NullSpan):
+    """The untraced ROOT of an execution context. It is installed as
+    the current span so that the sites below it neither read TRACE nor
+    draw a sample again; `outer` keeps a remote parent that was
+    attached, so an untraced process still propagates it."""
+
+    __slots__ = ("outer", "_token")
+
+    def __init__(self, outer):
+        self.outer = outer
+        self._token = None
+
+    def __enter__(self):
+        self._token = _CURRENT.set(self)
+        return self
+
+    def __exit__(self, *exc) -> None:
+        _CURRENT.reset(self._token)
 
 
 def _trace_enabled() -> bool:
@@ -813,6 +956,19 @@ def _sample_root() -> bool:
     return int.from_bytes(os.urandom(4), "big") / 2.0**32 < ratio
 
 
+def _profiler_active() -> bool:
+    """True while a jax profiler session is collecting. Asked once per
+    root span and inherited, like the sampling decision; never imports
+    jax (zero, the tools and the loaders must not start to)."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return False
+    try:
+        return bool(jax.profiler.TraceAnnotation.is_enabled())
+    except AttributeError:  # jax half-imported, or an older TraceMe
+        return False
+
+
 # the CURRENT span/context: a ContextVar (not a thread-local stack) so
 # executor pools inherit parents via contextvars.copy_context().run and
 # RPC servers restore remote parents with attach/detach
@@ -820,9 +976,97 @@ _CURRENT: "ContextVar[Optional[object]]" = ContextVar(
     "dgraph_tpu_current_span", default=None
 )
 
-# cap on the per-trace retention buffer (slow-query force-sampling)
-_TRACE_BUF_TRACES = 256
+def _covered(start: float, end: float, kids: list) -> float:
+    """Seconds of [start, end] that the children's intervals cover."""
+    covered = 0.0
+    at = start
+    for s0, e0 in sorted((k.start, k.end) for k in kids):
+        s0, e0 = max(s0, at), min(e0, end)
+        if e0 > s0:
+            covered += e0 - s0
+            at = e0
+    return covered
+
+
+def _request_record(root: "Span", spans: list) -> dict:
+    """`Tracer.request_records`' record of one finished local root."""
+    by_id = {sp.span_id: sp for sp in spans}
+    kids: Dict[int, list] = {}
+    for sp in spans:
+        if sp is not root and sp.end is not None:
+            kids.setdefault(sp.parent_id, []).append(sp)
+    tree = []
+    todo = [root]
+    while todo:
+        sp = todo.pop()
+        tree.append(sp)
+        todo.extend(kids.get(sp.span_id, ()))
+    self_cpu = {sp.span_id: sp.cpu_ms for sp in tree if sp.cpu_ms is not None}
+    for sp in tree:
+        if sp.cpu_ms is None or sp is root:
+            continue
+        up = by_id.get(sp.parent_id)
+        while up is not None and up.cpu_ms is None and up is not root:
+            up = by_id.get(up.parent_id)
+        if up is not None and up.span_id in self_cpu and up.tid == sp.tid:
+            self_cpu[up.span_id] -= sp.cpu_ms
+    wall: Dict[str, float] = {}
+    cpu: Dict[str, float] = {}
+    counts: Dict[str, int] = {}
+    attrs: Dict[str, float] = {}
+    for sp in tree:
+        mine = (sp.end - sp.start) - _covered(
+            sp.start, sp.end, kids.get(sp.span_id, ())
+        )
+        wall[sp.name] = wall.get(sp.name, 0.0) + mine * 1e3
+        counts[sp.name] = counts.get(sp.name, 0) + 1
+        if sp.span_id in self_cpu:
+            cpu[sp.name] = cpu.get(sp.name, 0.0) + self_cpu[sp.span_id]
+        for k, v in sp.attrs.items():
+            if isinstance(v, (int, float)) and not isinstance(v, bool):
+                key = f"{sp.name}.{k}"
+                attrs[key] = attrs.get(key, 0) + v
+    return {
+        "name": root.name,
+        "trace_id": f"{root.trace_id:032x}",
+        "start": root.start,
+        "end": root.end,
+        "wall_ms": (root.end - root.start) * 1e3,
+        "threads": len({sp.tid for sp in tree}),
+        # False: a coarse tree, whose fine spans' time is folded into
+        # their parents' self time (`_DETAIL_EVERY_S`)
+        "detail": root._detail is not False,
+        # True: the root began under a profiler session, so the tree's
+        # cpu=True spans read the thread CPU clock
+        "profiled": root._annotate,
+        "root_attrs": dict(root.attrs),
+        "self_wall_ms": wall,
+        "self_cpu_ms": cpu,
+        "counts": counts,
+        "attrs": attrs,
+    }
+
+
+# caps on the per-trace retention buffer: the slow-query path copies a
+# trace's spans out of it, and `request_records` reads the newest
+# requests' trees from it (1,024: a benchmark window's last requests)
+_TRACE_BUF_TRACES = 1024
 _TRACE_BUF_SPANS = 512
+
+# "span_<name>_seconds", made once per span name and not per finish
+_SPAN_HIST: Dict[str, str] = {}
+
+# A tree takes its FINE spans (the per-layer ones inside a request:
+# `parse`, `encode`, `setop.*`, `vec.*`, ...) while a profiler session
+# collects, and otherwise at most once in this many seconds: twenty
+# request trees a second in full, the rest with their coarse spans
+# (`http.request`, `query`, `level_task`, `commit`). The turn is drawn
+# when a tree's first fine site asks, so a root that has none (`commit`,
+# `rpc_server`, `raft_recv`) never takes a request's. On the chip's
+# host a span costs a loaded alpha ~20 us of its rate, and twelve fine
+# spans on every 5 ms request cost 5% of it (PERF.md); an alpha that
+# serves under twenty requests a second keeps every tree whole.
+_DETAIL_EVERY_S = 0.05
 
 
 class Tracer:
@@ -832,9 +1076,18 @@ class Tracer:
     def __init__(self, capacity: int = 2048, sink_path: Optional[str] = None):
         self._lock = threading.Lock()
         self.finished: deque = deque(maxlen=capacity)
+        # trace id -> the trace's finished spans, in finish order; the
+        # list is handed down from a local root to its descendants, so a
+        # finish appends to it without the lock
         self._by_trace: "OrderedDict[int, List[Span]]" = OrderedDict()
+        # the local roots that began under a profiler session, each
+        # holding its trace's span list: the request trees someone is
+        # about to read against that session's device trace
+        self._profiled: deque = deque(maxlen=_TRACE_BUF_TRACES)
+        self._detailed = 0.0  # when a tree last took its fine spans
         self.sink_path = sink_path
         self._sink = open(sink_path, "a") if sink_path else None
+        self._otlp: Optional[dict] = None
 
     # -- context API ----------------------------------------------------
 
@@ -849,6 +1102,8 @@ class Tracer:
 
     def current_context(self) -> Optional[SpanContext]:
         cur = _CURRENT.get()
+        if type(cur) is _NullRoot:
+            cur = cur.outer
         if cur is None:
             return None
         return SpanContext(cur.trace_id, cur.span_id, cur.sampled)
@@ -869,52 +1124,101 @@ class Tracer:
 
     # -- spans ----------------------------------------------------------
 
-    @contextmanager
-    def span(self, name: str, parent: Optional[SpanContext] = None, **attrs):
-        if not _trace_enabled():
-            sp = Span(name, 0, 0, None)
-            sp.attrs.update(attrs)
-            yield sp
-            return
+    def span(
+        self, name: str, parent: Optional[SpanContext] = None,
+        cpu: bool = False, fine: bool = False, **attrs,
+    ):
+        """A context manager for one span. TRACE, the sampling draw and
+        whether a profiler session is collecting are read at a ROOT
+        only: a child of a live span is traced like its parent, a child
+        of an untraced root is the shared no-op. `cpu=True` also takes
+        the thread's CPU time at both ends (`cpu_ms`), in a tree whose
+        root began under a profiler session: someone is paying for a
+        look, and on the chip's host a read costs 6 us, ~30 under load
+        (PERF.md). A `fine` span is recorded only below a live span of
+        a tree that takes its fine spans (`_DETAIL_EVERY_S`); elsewhere
+        it is the shared no-op."""
         par = parent if parent is not None else _CURRENT.get()
-        if par is None:
-            sp = Span(
-                name, _gen_trace_id(), _gen_span_id(), None,
-                sampled=_sample_root(),
-            )
+        if type(par) is Span:
+            if fine:
+                root = par._root
+                if root._detail is None:
+                    # unlocked: two trees that race both take theirs
+                    now = time.time()
+                    root._detail = now - self._detailed >= _DETAIL_EVERY_S
+                    if root._detail:
+                        self._detailed = now
+                if not root._detail:
+                    return NULL_SPAN
+            sp = Span(name, par.trace_id, _gen_span_id(), par.span_id,
+                      par.sampled)
+            sp._buf = par._buf
+            sp._root = par._root
+            sp._annotate = par._annotate
+            sp._exemplar = par._exemplar
+        elif fine or type(par) is _NullRoot:
+            return NULL_SPAN
+        elif not _trace_enabled():
+            return _NullRoot(par)
         else:
-            sp = Span(
-                name, par.trace_id, _gen_span_id(), par.span_id,
-                sampled=par.sampled,
-            )
-        sp.attrs.update(attrs)
-        token = _CURRENT.set(sp)
-        try:
-            yield sp
-        finally:
-            sp.end = time.time()
-            _CURRENT.reset(token)
-            self._finish(sp)
-            METRICS.observe(f"span_{name}_seconds", sp.end - sp.start)
+            if par is None:
+                sp = Span(name, _gen_trace_id(), _gen_span_id(), None,
+                          _sample_root())
+            else:  # a remote parent's context
+                sp = Span(name, par.trace_id, _gen_span_id(), par.span_id,
+                          par.sampled)
+            sp._root = sp
+            sp._annotate = _profiler_active()
+            sp._exemplar = _exemplars_enabled()
+            if sp._annotate:
+                sp._detail = True
+            with self._lock:
+                buf = self._by_trace.get(sp.trace_id)
+                if buf is None:
+                    buf = self._by_trace[sp.trace_id] = []
+                    while len(self._by_trace) > _TRACE_BUF_TRACES:
+                        self._by_trace.popitem(last=False)
+                else:  # a trace that gets another local root is in use
+                    self._by_trace.move_to_end(sp.trace_id)
+                sp._buf = buf
+        sp._tracer = self
+        if cpu and sp._annotate:
+            sp._cpu0 = 0
+        if attrs:
+            sp.attrs.update(attrs)
+        return sp
 
     def _finish(self, sp: Span) -> None:
-        with self._lock:
-            self.finished.append(sp)
-            buf = self._by_trace.setdefault(sp.trace_id, [])
-            if len(buf) < _TRACE_BUF_SPANS:
-                buf.append(sp)
-            self._by_trace.move_to_end(sp.trace_id)
-            while len(self._by_trace) > _TRACE_BUF_TRACES:
-                self._by_trace.popitem(last=False)
-            if sp.sampled:
-                self._export_locked(sp)
+        self.finished.append(sp)
+        buf = sp._buf
+        # a local root is kept whatever the trace's size: the request
+        # record is read off it
+        root = sp._root
+        kept = len(buf) < _TRACE_BUF_SPANS or sp is root
+        if kept:
+            buf.append(sp)
+        if sp.sampled:
+            if self._sink is None and self._otlp is None:
+                sp._exported = True
+            else:
+                with self._lock:
+                    self._export_locked(sp)
+        # the `span_<name>_seconds` histograms take the whole tree when
+        # its local root finishes, under one lock; a span that outlives
+        # its root, or that the buffer did not keep, goes in alone
+        if sp is root:
+            if sp._annotate:
+                self._profiled.append(sp)
+            METRICS.observe_spans(buf)
+        elif root.end is not None or not kept:
+            METRICS.observe_spans((sp,))
 
     def _export_locked(self, sp: Span) -> None:
         sp._exported = True
         if self._sink is not None:
             self._sink.write(json.dumps(sp.to_dict()) + "\n")
             self._sink.flush()
-        if getattr(self, "_otlp", None) is not None:
+        if self._otlp is not None:
             try:  # never block or raise into the traced path
                 self._otlp["q"].put_nowait(self._otlp_span_json(sp))
             except Exception:
@@ -927,7 +1231,7 @@ class Tracer:
         sink. Returns the number of spans exported."""
         n = 0
         with self._lock:
-            for sp in self._by_trace.get(trace_id, ()):  # oldest first
+            for sp in list(self._by_trace.get(trace_id, ())):  # oldest first
                 if not sp._exported and sp.end is not None:
                     self._export_locked(sp)
                     n += 1
@@ -936,11 +1240,44 @@ class Tracer:
     def trace_spans(self, trace_id: int) -> List[dict]:
         """The retained spans of one trace (this process only)."""
         with self._lock:
-            return [s.to_dict() for s in self._by_trace.get(trace_id, ())]
+            buf = self._by_trace.get(trace_id, ())
+        return [s.to_dict() for s in list(buf)]
 
     def recent(self, n: int = 100) -> List[dict]:
-        with self._lock:
-            return [s.to_dict() for s in list(self.finished)[-n:]]
+        return [s.to_dict() for s in list(self.finished)[-n:]]
+
+    def request_records(
+        self, n: int = 256, profiled: bool = False
+    ) -> List[dict]:
+        """One record for each of the newest `n` finished local roots
+        (a served request's `http.request`, an in-process `query`),
+        newest first; with `profiled`, of those that began while a
+        profiler session was collecting (kept apart, so that a busy
+        alpha's later requests do not push them out). A record holds
+        the root's name, start, end and wall_ms, whether the tree took
+        its fine spans (`detail`) and the CPU clock (`profiled`), and
+        per span name of its tree the self wall time, the self CPU time
+        (profiled trees only), the count, and its numeric attrs summed
+        as "<span>.<attr>".
+        Self wall time is a span's duration less the part its children
+        cover; self CPU time is its `cpu_ms` less that of the nearest
+        CPU-timed descendants on the SAME thread (a child on a pool
+        thread burns its own). Computed when asked, never on the
+        request path."""
+        roots = []
+        if profiled:
+            roots = [(sp, list(sp._buf)) for sp in list(self._profiled)]
+        else:
+            with self._lock:
+                bufs = list(self._by_trace.values())
+            for buf in bufs:
+                spans = list(buf)
+                roots.extend(
+                    (sp, spans) for sp in spans
+                    if sp._root is sp and sp.end is not None
+                )
+        roots.sort(key=lambda rs: rs[0].end, reverse=True)
+        return [_request_record(root, spans) for root, spans in roots[:n]]
 
     # -- OTLP/HTTP export (ref x/metrics.go:610 otlp trace wiring) ------
 
@@ -1012,7 +1349,7 @@ class Tracer:
     def otlp_flush(self):
         """Synchronously export everything queued AND whatever the
         drain thread has already dequeued (tests/shutdown)."""
-        cfg = getattr(self, "_otlp", None)
+        cfg = self._otlp
         if cfg is None:
             return
         import queue
@@ -1937,13 +2274,21 @@ def maybe_log_slow(
     tid = int(getattr(root_span, "trace_id", 0) or 0)
     if tid:
         tr.force_sample(tid)
+    spans = tr.trace_spans(tid) if tid else []
+    if tid and getattr(root_span, "end", 0) is None:
+        # the caller is still inside its root span (Server.query logs
+        # from within `query`): it rides along as it stands, open
+        spans.append(dict(
+            root_span.to_dict(),
+            duration_ms=(time.time() - root_span.start) * 1e3,
+        ))
     record = {
         "ts": time.time(),
         "kind": kind,
         "took_ms": round(took_ms, 2),
         "trace_id": f"{tid:032x}",
         "query": text[:2000],
-        "spans": tr.trace_spans(tid) if tid else [],
+        "spans": spans,
     }
     if _exemplars_enabled():
         # close the metrics→trace loop from the log side too: the
@@ -2005,10 +2350,13 @@ def start_debug_http(host: str = "127.0.0.1", port: int = 0):
                     "charset=utf-8",
                 )
             elif self.path.startswith("/debug/traces"):
-                self._send(
-                    json.dumps({"spans": TRACER.recent(200)}).encode(),
-                    "application/json",
-                )
+                from urllib.parse import parse_qs, urlparse
+
+                out = {"spans": TRACER.recent(200)}
+                want = parse_qs(urlparse(self.path).query).get("requests")
+                if want:
+                    out["requests"] = TRACER.request_records(int(want[0]))
+                self._send(json.dumps(out).encode(), "application/json")
             elif self.path == "/debug/tablets":
                 TABLETS.publish()
                 self._send(
@@ -2277,6 +2625,43 @@ declare_metric(
 declare_metric(
     "counter", "degraded_queries_total",
     "Queries that returned a degraded/partial response.",
+)
+declare_metric(
+    "counter", "device_dispatch_total",
+    "Programs enqueued on the device: one per jitted set-op call "
+    "(query/dispatch.py) and per jitted vector tier call "
+    "(models/vector.py), counted where the call is made: exact where "
+    "the *.launch spans ride in one request tree per 50 ms. Per-family "
+    "split in the device_dispatch_total{family=\"*\"} family.",
+)
+declare_metric(
+    "counter", "device_dispatch_total{family=\"*\"}",
+    "Per-family split of device_dispatch_total: intersect#shared, "
+    "union#chain, intersect (pairs), intersect#sharded, vec.ivf, "
+    "vec.brute, vec.sharded — the `family` attr of the setop.launch / "
+    "vec.launch spans.",
+)
+declare_metric(
+    "counter", "device_download_bytes_total",
+    "Bytes read back from the device by the set-op dispatcher and the "
+    "jitted vector tiers (the `bytes` of setop.wait / vec.wait spans); "
+    "over device_dispatch_total, the bytes a dispatch brings back.",
+)
+declare_metric(
+    "counter", "device_host_kept_total",
+    "Set-op calls the dispatcher answered with the host kernels "
+    "because their combined size was under the device threshold "
+    "(query/dispatch.py _min_total); they open no span. Against the "
+    "set-op families of device_dispatch_total: the share of set-op "
+    "traffic the threshold keeps off the device.",
+)
+declare_metric(
+    "counter", "device_upload_bytes_total",
+    "Bytes uploaded to the device by the set-op dispatcher (operands "
+    "that missed the DeviceCache) and the jitted vector tiers (query "
+    "vectors; the index's own arrays at a rebuild). A rate that "
+    "climbs while device_dispatch_total's stays flat is a DeviceCache "
+    "that stopped hitting.",
 )
 declare_metric(
     "counter", "digest_evicted_total",

@@ -641,14 +641,15 @@ _define(
     "every level read and committed mutation records into a sharded "
     "(namespace, predicate) accumulator served at /debug/tablets and "
     "consumed by the traffic-driven rebalancer. Always-on by design "
-    "(overhead proven within noise in BENCH_OBS.json); 0 is the A/B "
-    "escape hatch for that capture.",
+    "(its cost is unresolved: BENCH_OBS.json's CPU arms differ by less "
+    "than their spread); 0 is the A/B escape hatch.",
 )
 _define(
     "TRACE", "bool", True,
-    "Master tracing switch. 0 = spans become allocation-only no-ops "
-    "(no ids, no ring, no histograms) — the benchmarking baseline for "
-    "BENCH_OBS.json (utils/observe.py).",
+    "Master tracing switch, read once per root span. 0 = every span "
+    "site under an untraced root is one shared no-op (no ids, no ring, "
+    "no histograms, nothing allocated) — the baseline of PERF.md's "
+    "chip pairs (utils/observe.py).",
 )
 _define(
     "TRACE_SAMPLE", "float", 1.0,
