@@ -24,6 +24,7 @@ from __future__ import annotations
 
 import threading
 from collections import OrderedDict
+from dataclasses import dataclass
 from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
@@ -104,6 +105,19 @@ def _np_op(op: str, a: np.ndarray, b: np.ndarray) -> np.ndarray:
     raise ValueError(op)
 
 
+@dataclass(eq=False)
+class _CacheEntry:
+    """One DeviceCache entry. eq=False: `_by_key` holds the entry
+    itself, hashed by identity, so no index operation hashes its
+    token."""
+
+    __slots__ = ("token", "arrays", "nbytes", "keys")
+    token: tuple
+    arrays: tuple
+    nbytes: int
+    keys: frozenset
+
+
 class DeviceCache:
     """Device-resident operand cache — the HBM analog of the reference's
     MemoryLayer (posting/mvcc.go:387).
@@ -113,30 +127,41 @@ class DeviceCache:
     hot predicate's pack uploads once and every later query level reuses
     the HBM copy. Commits invalidate by key (mvcc.go:510); a version bump
     also changes the token, so even a missed invalidation only costs a
-    re-upload, never staleness. LRU-bounded by device bytes."""
+    re-upload, never staleness. LRU-bounded by device bytes.
+
+    A token can be a tuple of thousands of row tokens, and a tuple is
+    hashed anew each time: only the `_entries` lookups of `get`, `put`
+    and an invalidation's removal hash one, once each. An entry
+    remembers the keys it was put under (a token names its keys: the
+    same token always comes with the same ones), so an eviction or an
+    invalidation costs the keys of the entries it removes, whatever the
+    cache holds besides, and a key whose last entry went is forgotten."""
 
     def __init__(self, max_bytes: Optional[int] = None):
         self.max_bytes = max_bytes if max_bytes is not None else int(
             config.get("DEVCACHE_BYTES")
         )
         self._lock = threading.Lock()
-        # cache token -> (device arrays tuple, nbytes)
-        self._entries: "OrderedDict[tuple, Tuple[tuple, int]]" = OrderedDict()
-        # key bytes -> tokens referencing it (for commit invalidation)
+        # cache token -> entry, in LRU order
+        self._entries: "OrderedDict[tuple, _CacheEntry]" = OrderedDict()
+        # key bytes -> live entries put under it (for commit invalidation)
         self._by_key: Dict[bytes, set] = {}
         self._bytes = 0
         self.hits = 0
         self.misses = 0
+        self.evictions = 0
 
     def get(self, token: tuple):
         with self._lock:
-            got = self._entries.get(token)
-            if got is None:
+            entry = self._entries.get(token)
+            if entry is None:
                 self.misses += 1
+                METRICS.inc("device_cache_misses_total")
                 return None
             self._entries.move_to_end(token)
             self.hits += 1
-            return got[0]
+            METRICS.inc("device_cache_hits_total")
+            return entry.arrays
 
     def put(self, token: tuple, keys_involved, arrays: tuple, nbytes: int):
         if nbytes > self.max_bytes:
@@ -144,23 +169,40 @@ class DeviceCache:
         with self._lock:
             if token in self._entries:
                 return
-            self._entries[token] = (arrays, nbytes)
+            entry = _CacheEntry(
+                token, arrays, nbytes, frozenset(keys_involved)
+            )
+            self._entries[token] = entry
             self._bytes += nbytes
-            for k in keys_involved:
-                self._by_key.setdefault(k, set()).add(token)
+            for k in entry.keys:
+                self._by_key.setdefault(k, set()).add(entry)
             while self._bytes > self.max_bytes and self._entries:
-                old_tok, (_, old_n) = self._entries.popitem(last=False)
-                self._bytes -= old_n
-                for toks in self._by_key.values():
-                    toks.discard(old_tok)
+                # popitem takes the hash the dict stored: no rehash
+                _, old = self._entries.popitem(last=False)
+                self._forget(old)
+                self.evictions += 1
+                METRICS.inc("device_cache_evictions_total")
+
+    def _forget(self, entry: _CacheEntry) -> None:
+        """Bookkeeping for an entry already out of `_entries`: its bytes,
+        and its place under each of its keys (lock held)."""
+        self._bytes -= entry.nbytes
+        for k in entry.keys:
+            held = self._by_key.get(k)
+            if held is not None:
+                held.discard(entry)
+                if not held:
+                    del self._by_key[k]
+
+    def _invalidate_locked(self, keys) -> None:
+        for k in keys:
+            for entry in self._by_key.pop(k, ()):
+                del self._entries[entry.token]
+                self._forget(entry)
 
     def invalidate(self, keys) -> None:
         with self._lock:
-            for k in keys:
-                for tok in self._by_key.pop(k, ()):
-                    got = self._entries.pop(tok, None)
-                    if got is not None:
-                        self._bytes -= got[1]
+            self._invalidate_locked(keys)
 
     def invalidate_prefix(self, prefixes) -> None:
         """Drop cached operands for every key under any prefix (the
@@ -169,15 +211,10 @@ class DeviceCache:
         if not pfx:
             return
         with self._lock:
-            hit = [
+            self._invalidate_locked([
                 k for k in self._by_key
                 if isinstance(k, (bytes, bytearray)) and bytes(k).startswith(pfx)
-            ]
-            for k in hit:
-                for tok in self._by_key.pop(k, ()):
-                    got = self._entries.pop(tok, None)
-                    if got is not None:
-                        self._bytes -= got[1]
+            ])
 
     def clear(self):
         with self._lock:
@@ -191,6 +228,8 @@ class DeviceCache:
             "bytes": self._bytes,
             "hits": self.hits,
             "misses": self.misses,
+            "evictions": self.evictions,
+            "keys": len(self._by_key),
         }
 
 
@@ -400,8 +439,8 @@ class SetOpDispatcher:
         one for the mesh; a device array is a DeviceCache hit — and,
         through `keep(operands as the device holds them)`, the
         caller's DeviceCache inserts, which stay where they always
-        were: before the launch, and inside a span (an insert that
-        evicts walks every cached key: PERF.md, PR 26);
+        were: before the launch, and inside a span (PR 26 measured
+        -5.2% qps with them after the wait: PERF.md);
         `setop.launch` (jit fetch and the call that enqueues the
         program) and `setop.wait` (queueing behind other requests'
         programs, execution, download). Returns the outputs as numpy

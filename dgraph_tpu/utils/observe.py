@@ -2627,6 +2627,24 @@ declare_metric(
     "Queries that returned a degraded/partial response.",
 )
 declare_metric(
+    "counter", "device_cache_evictions_total",
+    "DeviceCache entries pushed out by an insert to keep the cache "
+    "under DGRAPH_TPU_DEVCACHE_BYTES (query/dispatch.py). Near "
+    "device_cache_misses_total's rate, the working set does not fit: "
+    "every insert pays for one entry's removal and nothing is reused.",
+)
+declare_metric(
+    "counter", "device_cache_hits_total",
+    "DeviceCache lookups that found the operand's padded arrays "
+    "resident on the device: that operand is not uploaded again.",
+)
+declare_metric(
+    "counter", "device_cache_misses_total",
+    "DeviceCache lookups that found nothing: the operand is padded on "
+    "the host, uploaded, and inserted (one lookup per cached operand: "
+    "a level's stacked rows, a shared filter list).",
+)
+declare_metric(
     "counter", "device_dispatch_total",
     "Programs enqueued on the device: one per jitted set-op call "
     "(query/dispatch.py) and per jitted vector tier call "

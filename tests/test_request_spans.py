@@ -276,11 +276,14 @@ def test_every_device_variant_has_the_five_spans(device_path, variant):
 
 def test_device_cache_inserts_ride_in_the_upload_span(device_path,
                                                       monkeypatch):
-    """An insert that evicts walks every cached key, so it is neither
-    after the wait (it would delay the next launch's thread) nor
+    """An insert is host work on the launching thread, so it is neither
+    after the wait (PR 26 measured -5.2% qps with it there) nor
     outside a span (its time would read as the executor's): both
     inserts happen under `setop.upload`, before the launch, and a
-    second call with the same tokens uploads nothing."""
+    second call with the same tokens uploads nothing. The span counts
+    arrays, the DeviceCache's counters entries (the stack is one entry
+    of two arrays): `device_cache_misses_total` rises by the entries
+    whose arrays the span reports as misses."""
     d = dispatch.SetOpDispatcher()
     rng = np.random.default_rng(5)
     rows = [np.unique(rng.integers(1, 4000, 300)).astype(np.uint64)
@@ -294,15 +297,22 @@ def test_device_cache_inserts_ride_in_the_upload_span(device_path,
                     if sp.trace_id == root.trace_id
                     and sp.name == "setop.launch"]
         seen.append((token[0], observe._CURRENT.get().name, launched))
+        inserted.append(len(arrays))
         return put(self, token, keys, arrays, nbytes)
 
+    inserted = []
     monkeypatch.setattr(dispatch.DeviceCache, "put", spy)
     toks = [(b"k%d" % i, 7) for i in range(3)]
+    counted, names = [], ("misses", "hits", "evictions")
     with TRACER.span("process") as root:
-        first = d.run_rows_vs_one(
-            "intersect", rows, b, row_tokens=toks, b_token=(b"kb", 7))
-        again = d.run_rows_vs_one(
-            "intersect", rows, b, row_tokens=toks, b_token=(b"kb", 7))
+        for _ in range(2):
+            c0 = [METRICS.value(f"device_cache_{n}_total") for n in names]
+            got = d.run_rows_vs_one(
+                "intersect", rows, b, row_tokens=toks, b_token=(b"kb", 7))
+            counted.append((got, [
+                METRICS.value(f"device_cache_{n}_total") - was
+                for n, was in zip(names, c0)]))
+    (first, cold), (again, warm) = counted
     assert seen == [("b", "setop.upload", []),
                     ("stack", "setop.upload", [])]
     for got, want in zip(first, again):
@@ -311,6 +321,9 @@ def test_device_cache_inserts_ride_in_the_upload_span(device_path,
                if sp["name"] == "setop.upload"]
     assert [u["cache_misses"] for u in uploads] == [3, 0]
     assert uploads[1]["cache_hits"] == 3 and uploads[1]["bytes"] == 0
+    assert sum(inserted) == uploads[0]["cache_misses"]
+    assert cold == [len(inserted), 0, 0] and warm == [0, len(inserted), 0]
+    assert d.device_cache.stats()["misses"] == len(inserted) == 2
 
 
 def test_spans_are_events_on_the_profilers_host_plane(served, device_path,
