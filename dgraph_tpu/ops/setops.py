@@ -21,7 +21,18 @@ MSBs differ (codec/codec.go:117) — and ops run per matching segment in the
 32-bit local space.
 
 All functions are jit-friendly (static shapes, no data-dependent control
-flow) and have `jax.vmap` applied by the batch dispatcher (query/dispatch.py).
+flow). The batch dispatcher (query/dispatch.py) runs them in two forms:
+
+- vmapped over a stack of padded rows: the pair buckets (`intersect`,
+  `difference`, `union`, every row with its own `b`) and `union#shared`
+  (one shared `b`, unbatched: a row's union needs the row's own output
+  width). Under the vmap a sort-based search sorts a copy of `b` with every
+  row, so a stack costs rows x (row width + b's width) elements.
+- flat, no vmap: `intersect#shared` and `difference#shared`. With one
+  shared `b`, `membership` does not care which row an id sits in, so the
+  level's ids go in as ONE array (not sorted as a whole: only `b` has to
+  be) and the mask comes out; the host keeps or drops by it and cuts the
+  rows back at their offsets. No `compact` on the device.
 """
 
 from __future__ import annotations

@@ -34,6 +34,7 @@ import jax.numpy as jnp
 from dgraph_tpu.codec import uidpack
 from dgraph_tpu.codec.uidpack import join_segments, split_segments
 from dgraph_tpu.ops import packed_setops, setops
+from dgraph_tpu.query import ragged
 from dgraph_tpu.utils.observe import METRICS, TRACER
 from dgraph_tpu.x import config, device
 
@@ -85,6 +86,25 @@ def _planner_enabled() -> bool:
 
 def _pow2(n: int) -> int:
     return max(_MIN_PAD, 1 << (max(1, n) - 1).bit_length())
+
+
+def _pow4(n: int) -> int:
+    """The bucket of a level's flat ids: the next power of FOUR. On a
+    v5e the flat program costs 18.6 ns a padded element (4.9 ms at
+    262,144) and 12.5-18.6 s to compile, whatever its size (PERF.md,
+    PR 30): one compile buys the padding of thousands of requests, so
+    the buckets are twice as far apart as `_pow2`'s. At most 4x the
+    ids, 2.2x on average where `_pow2` gives 1.4x."""
+    p = _pow2(n)
+    return p if p.bit_length() % 2 else p * 2
+
+
+def _cut_rows(op: str, flat, offs, member) -> List[np.ndarray]:
+    """The rows of a ragged (flat, offs) level after intersect or
+    difference with a set, given each id's membership in it: views of
+    ONE kept array, cut at the new offsets."""
+    keep = ~member if op == "difference" else member
+    return ragged.row_views(*ragged.apply_mask(flat, offs, keep))
 
 
 def _np_op(op: str, a: np.ndarray, b: np.ndarray) -> np.ndarray:
@@ -431,10 +451,14 @@ class SetOpDispatcher:
             return _DEVICE_MIN_TOTAL
         return _HOST_ONLY if platform == "cpu" else _ACCEL_MIN_TOTAL
 
-    def _run_device(self, family: str, fetch, operands, keep=None) -> tuple:
+    def _run_device(
+        self, family: str, fetch, operands, ids: int, padded: int, keep=None
+    ) -> tuple:
         """What every device call shares after its operands are padded
-        (`setop.pad`, at the call site) and before its result is cut
-        back into rows (`setop.split`, there too): `setop.upload` of
+        (`setop.pad`, at the call site, which also says how many real
+        `ids` it pads to how many `padded` elements: counted here) and
+        before its result is cut back into rows (`setop.split`, there
+        too): `setop.upload` of
         the operands still on the host — a numpy array, or a `_Sharded`
         one for the mesh; a device array is a DeviceCache hit — and,
         through `keep(operands as the device holds them)`, the
@@ -481,6 +505,8 @@ class SetOpDispatcher:
             f'device_dispatch_total{{family="{family}"}}': 1,
             "device_upload_bytes_total": nbytes,
             "device_download_bytes_total": down,
+            "device_real_ids_total": ids,
+            "device_padded_ids_total": padded,
         })
         return host
 
@@ -505,7 +531,17 @@ class SetOpDispatcher:
         invalidates the key (no re-upload of unchanged packs).
 
         Falls back to host ops below the device threshold. u64 inputs with
-        multiple hi-32 segments fall back to the generic pair path."""
+        multiple hi-32 segments fall back to the generic pair path.
+
+        On the device, intersect and difference are FLAT: with one
+        shared operand an id's membership is the same whichever row it
+        sits in, so the rows' ids go up as one array padded to a power
+        of four of their total (`_pow4`), one `setops.membership`
+        answers them all, and the mask comes back for the host to keep
+        or drop by and cut into rows at the offsets. The program is
+        given at most four times the real ids. Only `union#shared`,
+        which needs each row's own output width, and the pair buckets
+        (`_run_bucket`) pad rows into a stack and vmap over it."""
         rows = list(rows)
         if not rows:
             return []
@@ -519,24 +555,17 @@ class SetOpDispatcher:
                 # concatenated rows beats per-row native calls (ctypes
                 # marshaling dominates at small sizes)
                 b64 = np.asarray(b, np.uint64)
-                cat = np.concatenate(
+                flat, offs = ragged.pack_rows(
                     [np.asarray(r, np.uint64) for r in rows]
                 )
-                if len(b64) and len(cat):
-                    idx = np.searchsorted(b64, cat)
-                    idx_c = np.minimum(idx, len(b64) - 1)
-                    mask = b64[idx_c] == cat
+                if len(b64) and len(flat):
+                    idx = np.minimum(
+                        np.searchsorted(b64, flat), len(b64) - 1
+                    )
+                    mask = b64[idx] == flat
                 else:
-                    mask = np.zeros(len(cat), bool)
-                if op == "difference":
-                    mask = ~mask
-                out = []
-                off = 0
-                for r in rows:
-                    n = len(r)
-                    out.append(cat[off : off + n][mask[off : off + n]])
-                    off += n
-                return out
+                    mask = np.zeros(len(flat), bool)
+                return _cut_rows(op, flat, offs, mask)
             return [_np_op(op, r, b) for r in rows]
         if (
             op in ("intersect", "difference")
@@ -546,7 +575,79 @@ class SetOpDispatcher:
             got = self._run_rows_sharded(op, rows, b, b_token)
             if got is not None:
                 return got
+        if op == "union":
+            return self._union_rows_stacked(rows, b, row_tokens, b_token)
         family = op + "#shared"
+        with TRACER.span(
+            "setop.pad", cpu=True, fine=True, family=family
+        ) as sp:
+            b64 = np.asarray(b, np.uint64)
+            flat, offs = ragged.pack_rows(
+                [np.asarray(r, np.uint64) for r in rows]
+            )
+            # one hi-32 test over the level's ids, not one split a row
+            his = [x >> np.uint64(32) for x in (flat, b64) if x.size]
+            hi = int(his[0][0]) if his else 0
+            one_segment = all((h == hi).all() for h in his)
+            if one_segment:
+                n, total = len(rows), int(flat.size)
+                B, b_key, pb = self._shared_operand(b64, hi, b_token)
+                pflat = _pow4(total)
+                flat_tok = A = None
+                if row_tokens is not None and len(row_tokens) == n and all(
+                    t is not None for t in row_tokens
+                ):
+                    flat_tok = ("stack", hi, pflat, tuple(row_tokens))
+                    cached = self.device_cache.get(flat_tok)
+                    if cached is not None:
+                        A = cached[0]
+                if A is None:
+                    A = setops.pad_sorted(flat.astype(np.uint32), pflat)
+                ids, padded = total + len(b64), pflat + pb
+                sp.attrs.update(
+                    rows=n, pa=pflat, pb=pb, ids=ids, padded=padded
+                )
+        if not one_segment:
+            return self.run_pairs(op, [(r, b) for r in rows])
+
+        def keep(dev):
+            Ad, _, Bd, _ = dev
+            if b_key is not None and Bd is not B:
+                self.device_cache.put(b_key, [b_token[0]], (Bd,), pb * 4)
+            if flat_tok is not None and Ad is not A:
+                self.device_cache.put(
+                    flat_tok, [t[0] for t in row_tokens], (Ad,), pflat * 4
+                )
+
+        (mask,) = self._run_device(
+            family,
+            lambda: self._get_jitted_shared(op, pflat, pb),
+            [A, np.int32(total), B, np.int32(len(b64))],
+            ids,
+            padded,
+            keep,
+        )
+        with TRACER.span("setop.split", cpu=True, fine=True):
+            return _cut_rows(op, flat, offs, mask[:total])
+
+    def _shared_operand(self, b64: np.ndarray, hi: int, b_token):
+        """The shared operand's low 32 bits as the device takes them:
+        (padded array or its DeviceCache copy, cache key or None, pb)."""
+        pb = _pow2(len(b64))
+        b_key = None
+        if b_token is not None:
+            b_key = ("b", b_token, hi, pb)
+            cached = self.device_cache.get(b_key)
+            if cached is not None:
+                return cached[0], b_key, pb
+        return setops.pad_sorted(b64.astype(np.uint32), pb), b_key, pb
+
+    def _union_rows_stacked(self, rows, b, row_tokens, b_token):
+        """`union#shared`: a row's union needs the row's own output
+        width, so the rows stay apart, padded into one (nb, pa) stack
+        that the program is vmapped over with `b` unbatched (it then
+        sorts a copy of `b` with every row: `padded`)."""
+        family = "union#shared"
         with TRACER.span(
             "setop.pad", cpu=True, fine=True, family=family
         ) as sp:
@@ -563,16 +664,7 @@ class SetOpDispatcher:
             if one_segment:
                 hi = next(iter(his)) if his else 0
                 b32 = bseg.get(hi, np.zeros((0,), np.uint32))
-                pb = _pow2(len(b32))
-                b_key = stack_tok = None
-                B = A = None
-                if b_token is not None:
-                    b_key = ("b", b_token, hi, pb)
-                    cached = self.device_cache.get(b_key)
-                    if cached is not None:
-                        B = cached[0]
-                if B is None:
-                    B = setops.pad_sorted(b32, pb)
+                B, b_key, pb = self._shared_operand(b32, hi, b_token)
                 LB = np.int32(len(b32))
 
                 pa = _pow2(
@@ -580,6 +672,7 @@ class SetOpDispatcher:
                 )
                 n = len(rows)
                 nb = _pow2(n)
+                stack_tok = A = None
                 if row_tokens is not None and len(row_tokens) == n and all(
                     t is not None for t in row_tokens
                 ):
@@ -594,9 +687,13 @@ class SetOpDispatcher:
                         r32 = rs.get(hi, np.zeros((0,), np.uint32))
                         A[i, : len(r32)] = r32
                         LA[i] = len(r32)
-                sp.attrs.update(rows=n, pa=pa, pb=pb)
+                ids = sum(len(r) for r in rows) + len(b32)
+                padded = nb * (pa + pb)
+                sp.attrs.update(
+                    rows=n, pa=pa, pb=pb, ids=ids, padded=padded
+                )
         if not one_segment:
-            return self.run_pairs(op, [(r, b) for r in rows])
+            return self.run_pairs("union", [(r, b) for r in rows])
 
         def keep(dev):
             Ad, LAd, Bd, _ = dev
@@ -612,15 +709,16 @@ class SetOpDispatcher:
 
         out, cnt = self._run_device(
             family,
-            lambda: self._get_jitted_shared(op, pa, pb),
+            lambda: self._get_jitted_shared("union", pa, pb),
             [A, LA, B, LB],
+            ids,
+            padded,
             keep,
         )
         with TRACER.span("setop.split", cpu=True, fine=True):
-            res = []
-            for i in range(n):
-                res.append(join_segments({hi: out[i, : cnt[i]]}))
-        return res
+            return [
+                join_segments({hi: out[i, : cnt[i]]}) for i in range(n)
+            ]
 
     def run_rows_vs_one_ragged(
         self,
@@ -639,9 +737,8 @@ class SetOpDispatcher:
         The host path is fully vectorized — one searchsorted over the
         whole flat buffer plus one cumsum to rebuild offsets — which is
         the CPU-backend fast path for every traversal level. The device
-        path reuses the padded-matrix upload via zero-copy row views."""
-        from dgraph_tpu.query import ragged
-
+        path goes through `run_rows_vs_one` on zero-copy row views, and
+        gets views of one kept array back."""
         n = len(offs) - 1
         b64 = np.asarray(b, np.uint64)
         if n == 0:
@@ -663,18 +760,14 @@ class SetOpDispatcher:
             if op == "difference":
                 mask = ~mask
             return ragged.apply_mask(flat, offs, mask)
-        rows = [flat[offs[i] : offs[i + 1]] for i in range(n)]
         res = self.run_rows_vs_one(
-            op, rows, b64, row_tokens=row_tokens, b_token=b_token
+            op,
+            ragged.row_views(flat, offs),
+            b64,
+            row_tokens=row_tokens,
+            b_token=b_token,
         )
-        out_offs = np.zeros((n + 1,), np.int64)
-        np.cumsum([len(r) for r in res], out=out_offs[1:])
-        if not out_offs[-1]:
-            return np.zeros((0,), np.uint64), out_offs
-        return (
-            np.concatenate(res).astype(np.uint64, copy=False),
-            out_offs,
-        )
+        return ragged.pack_rows(res)
 
     def run_chain(self, op: str, parts: Sequence[np.ndarray]) -> np.ndarray:
         """Combine k sorted u64 sets with one associative op (AND/OR filter
@@ -735,14 +828,21 @@ class SetOpDispatcher:
                 for i, a in enumerate(arrs):
                     M[i, : len(a)] = a
                     L[i] = len(a)
-                sp.attrs.update(rows=k, pa=pad, pb=0)
+                ids, padded = sum(len(a) for a in arrs), k * pad
+                sp.attrs.update(
+                    rows=k, pa=pad, pb=0, ids=ids, padded=padded
+                )
         if len(his) > 1:
             out = parts[0]
             for p in parts[1:]:
                 out = self.run_pairs(op, [(out, p)])[0]
             return out
         out, cnt = self._run_device(
-            family, lambda: self._get_jitted_chain(op, k, pad), [M, L]
+            family,
+            lambda: self._get_jitted_chain(op, k, pad),
+            [M, L],
+            ids,
+            padded,
         )
         with TRACER.span("setop.split", cpu=True, fine=True):
             return join_segments({hi: out[: int(cnt)]})
@@ -847,7 +947,9 @@ class SetOpDispatcher:
                 r32 = rs.get(hi, np.zeros((0,), np.uint32))
                 A[i, : len(r32)] = r32
                 LA[i] = len(r32)
-            sp.attrs.update(rows=n, pa=pa, pb=pb)
+            # every row is searched in every device's tile of b
+            ids, padded = int(LA.sum()) + len(b32), n * (pa + pb)
+            sp.attrs.update(rows=n, pa=pa, pb=pb, ids=ids, padded=padded)
         # LA rides along as a numpy array: sharded_rows_membership
         # converts it itself, as it always did
         def keep(dev):
@@ -862,6 +964,8 @@ class SetOpDispatcher:
                 mesh, Ad, LA, Bd, len(b32)
             ),
             [A, B],
+            ids,
+            padded,
             keep,
         )
         with TRACER.span("setop.split", cpu=True, fine=True):
@@ -874,23 +978,26 @@ class SetOpDispatcher:
         return out
 
     def _get_jitted_shared(self, op: str, pa: int, pb: int):
+        """intersect / difference: ONE membership of the level's ids,
+        flat (`pa` of them, padded), in `b`; the host keeps or drops by
+        the mask. union: `setops.union` vmapped over a stack of rows
+        `pa` wide, `b` unbatched."""
         key = (op + "#shared", pa, pb)
         fn = self._jit_cache.get(key)
         if fn is None:
             with self._jit_lock:
                 fn = self._jit_cache.get(key)
                 if fn is None:
-                    base = {
-                        "intersect": setops.intersect,
-                        "difference": setops.difference,
-                        "union": setops.union,
-                    }[op]
-                    fn = self._jit_cache[key] = jax.jit(
-                        jax.vmap(
-                            setops.scoped(f"setop.{op}.shared", base),
+                    if op == "union":
+                        fn = jax.vmap(
+                            setops.scoped("setop.union.shared", setops.union),
                             in_axes=(0, 0, None, None),
                         )
-                    )
+                    else:
+                        fn = setops.scoped(
+                            f"setop.{op}.shared", setops.membership
+                        )
+                    fn = self._jit_cache[key] = jax.jit(fn)
         return fn
 
     # -- public API ---------------------------------------------------------
@@ -1019,7 +1126,7 @@ class SetOpDispatcher:
         with TRACER.span(
             "setop.pad", cpu=True, fine=True, family=op, rows=len(jobs),
             pa=pa, pb=pb,
-        ):
+        ) as sp:
             n = len(jobs)
             nb = _pow2(n)
             A = np.full((nb, pa), setops.UINT32_MAX, np.uint32)
@@ -1031,8 +1138,14 @@ class SetOpDispatcher:
                 B[i, : len(b)] = b
                 LA[i] = len(a)
                 LB[i] = len(b)
+            ids, padded = int(LA.sum() + LB.sum()), nb * (pa + pb)
+            sp.attrs.update(ids=ids, padded=padded)
         out, cnt = self._run_device(
-            op, lambda: self._get_jitted(op, pa, pb), [A, LA, B, LB]
+            op,
+            lambda: self._get_jitted(op, pa, pb),
+            [A, LA, B, LB],
+            ids,
+            padded,
         )
         with TRACER.span("setop.split", cpu=True, fine=True):
             return [out[i, : cnt[i]] for i in range(n)]

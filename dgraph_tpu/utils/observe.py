@@ -2642,7 +2642,7 @@ declare_metric(
     "counter", "device_cache_misses_total",
     "DeviceCache lookups that found nothing: the operand is padded on "
     "the host, uploaded, and inserted (one lookup per cached operand: "
-    "a level's stacked rows, a shared filter list).",
+    "a level's rows, flat or stacked, a shared filter list).",
 )
 declare_metric(
     "counter", "device_dispatch_total",
@@ -2672,6 +2672,20 @@ declare_metric(
     "(query/dispatch.py _min_total); they open no span. Against the "
     "set-op families of device_dispatch_total: the share of set-op "
     "traffic the threshold keeps off the device.",
+)
+declare_metric(
+    "counter", "device_padded_ids_total",
+    "Elements the set-op programs were given after padding, a stack "
+    "of rows counted with its per-row copy of the second operand (the "
+    "`padded` of the setop.pad spans). Over device_real_ids_total: the "
+    "padding the device works through per real id (at most 4 for the "
+    "flat intersect#shared / difference#shared, whose bucket is a "
+    "power of four; rows x widths for a stack).",
+)
+declare_metric(
+    "counter", "device_real_ids_total",
+    "Real ids, both operands, that the set-op dispatcher handed to "
+    "the device (the `ids` of the setop.pad spans, query/dispatch.py).",
 )
 declare_metric(
     "counter", "device_upload_bytes_total",

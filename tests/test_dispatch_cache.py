@@ -68,15 +68,27 @@ def test_device_cache_hit_and_invalidate(monkeypatch):
     r1 = d.run_rows_vs_one("intersect", rows, b, row_tokens=toks, b_token=(b"big", 3))
     h0 = d.device_cache.hits
     r2 = d.run_rows_vs_one("intersect", rows, b, row_tokens=toks, b_token=(b"big", 3))
-    assert d.device_cache.hits >= h0 + 2  # stacked rows + b both reused
+    assert d.device_cache.hits == h0 + 2  # the rows' flat ids + b both reused
     for x, y in zip(r1, r2):
         np.testing.assert_array_equal(x, y)
-    # commit invalidation by key drops entries referencing it
+    # the entry is the flat array: 4 bytes a padded id, one array, under
+    # every row's key; b's beside it
+    total = sum(len(r) for r in rows)
+    stats = d.device_cache.stats()
+    assert stats["entries"] == 2 and stats["keys"] == len(rows) + 1
+    assert stats["bytes"] == 4 * (dispatch._pow4(total) + dispatch._pow2(len(b)))
+    # commit invalidation by ONE row's key drops the flat entry, not b's
     d.device_cache.invalidate([b"k3"])
-    n_before = d.device_cache.stats()["entries"]
+    stats = d.device_cache.stats()
+    assert stats["entries"] == 1 and stats["keys"] == 1
+    m0 = d.device_cache.misses
     r3 = d.run_rows_vs_one("intersect", rows, b, row_tokens=toks, b_token=(b"big", 3))
+    assert d.device_cache.misses == m0 + 1 and d.device_cache.hits == h0 + 3
+    assert d.device_cache.stats()["entries"] == 2
     for x, y in zip(r1, r3):
         np.testing.assert_array_equal(x, y)
+    for x, r in zip(r1, rows):
+        np.testing.assert_array_equal(x, np.intersect1d(r, b))
 
 
 def test_device_cache_lru_bound():

@@ -281,9 +281,11 @@ def test_device_cache_inserts_ride_in_the_upload_span(device_path,
     outside a span (its time would read as the executor's): both
     inserts happen under `setop.upload`, before the launch, and a
     second call with the same tokens uploads nothing. The span counts
-    arrays, the DeviceCache's counters entries (the stack is one entry
-    of two arrays): `device_cache_misses_total` rises by the entries
-    whose arrays the span reports as misses."""
+    arrays, the DeviceCache's counters entries, and in the flat form an
+    entry is one array (the level's ids; the shared operand): the
+    lengths are scalars, never uploaded as arrays nor cached, so
+    `device_cache_misses_total` rises by the arrays the span reports
+    as misses."""
     d = dispatch.SetOpDispatcher()
     rng = np.random.default_rng(5)
     rows = [np.unique(rng.integers(1, 4000, 300)).astype(np.uint64)
@@ -319,8 +321,14 @@ def test_device_cache_inserts_ride_in_the_upload_span(device_path,
         assert np.array_equal(got, want)
     uploads = [sp["attrs"] for sp in TRACER.trace_spans(root.trace_id)
                if sp["name"] == "setop.upload"]
-    assert [u["cache_misses"] for u in uploads] == [3, 0]
-    assert uploads[1]["cache_hits"] == 3 and uploads[1]["bytes"] == 0
+    assert [u["cache_misses"] for u in uploads] == [2, 0]
+    assert uploads[1]["cache_hits"] == 2 and uploads[1]["bytes"] == 0
+    # the flat array of the rows' ids, padded to a power of four of their
+    # total, and b: 4 bytes an element, no stack of rows x widest row
+    total = sum(len(r) for r in rows)
+    assert uploads[0]["bytes"] == 4 * (
+        dispatch._pow4(total) + dispatch._pow2(len(b)))
+    assert inserted == [1, 1]
     assert sum(inserted) == uploads[0]["cache_misses"]
     assert cold == [len(inserted), 0, 0] and warm == [0, len(inserted), 0]
     assert d.device_cache.stats()["misses"] == len(inserted) == 2
@@ -703,10 +711,15 @@ def test_named_scopes_reach_the_lowered_programs():
     A = np.zeros((2, 8), np.uint32)
     L = np.zeros((2,), np.int32)
     B = np.zeros((16,), np.uint32)
+    # flat: the level's ids as one array, no batch axis
     shared = d._get_jitted_shared("intersect", 8, 16).lower(
-        A, L, B, np.int32(3)).as_text(debug_info=True)
+        A[0], np.int32(5), B, np.int32(3)).as_text(debug_info=True)
     assert "setop.intersect.shared" in shared
-    assert "module @jit_intersect" in shared  # the program's name stays
+    assert "module @jit_membership" in shared  # the program's name stays
+    stacked = d._get_jitted_shared("union", 8, 16).lower(
+        A, L, B, np.int32(3)).as_text(debug_info=True)
+    assert "setop.union.shared" in stacked
+    assert "module @jit_union" in stacked
     pairs = d._get_jitted("union", 8, 8).lower(A, L, A, L).as_text(
         debug_info=True)
     assert "setop.union.pairs" in pairs
