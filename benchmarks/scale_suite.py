@@ -1,35 +1,19 @@
-"""Scale conformance + latency suite over the movie corpus.
+"""Scale conformance suite over the movie corpus: a reference for
+tests/test_scale_conformance.py, not a benchmark.
 
-The reference validates at scale with the 1million/21million suites and
-per-query latency budgets (systest/1million/1million_test.go,
-systest/ldbc/test_cases.yaml). This harness:
-
-  1. generates an N-edge corpus (benchmarks/movie_corpus.py),
-  2. bulk-loads it,
-  3. runs a ported query set (genre membership, 2-hop director-by-genre,
-     reverse expansion, year index, term search, ordered pagination,
-     count aggregation),
-  4. checks every result against goldens DERIVED from the generator's
-     plain-Python model, and
-  5. reports per-query latency + traversal edges/sec.
-
-Usage: python benchmarks/scale_suite.py [--edges 1000000] [--json out]
+The reference validates at scale with the 1million/21million suites
+(systest/1million/1million_test.go, systest/ldbc/test_cases.yaml).
+`load` generates an N-edge corpus (benchmarks/movie_corpus.py) and
+bulk-loads it; `run_suite` runs a ported query set (genre membership,
+2-hop director-by-genre, reverse expansion, year index, term search,
+ordered pagination, count aggregation) and checks every result against
+goldens DERIVED from the generator's plain-Python model. The latencies
+it returns beside each `ok` come from whatever host ran it and are no
+record of speed: PERF.md and PERF_LEDGER.jsonl are.
 """
 
 from __future__ import annotations
 
-import os as _os
-import sys as _sys
-
-_REPO = _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__)))
-if _REPO not in _sys.path:  # `python benchmarks/x.py` puts only benchmarks/ there
-    _sys.path.insert(0, _REPO)
-
-import dgraph_tpu  # noqa: E402,F401 — places the compile cache before jax loads
-
-import argparse
-import json
-import sys
 import time
 
 
@@ -248,32 +232,3 @@ def run_suite(corpus, server, repeat: int = 3) -> dict:
     }
 
     return results
-
-
-def main():
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--edges", type=int, default=1_000_000)
-    ap.add_argument("--json", default=None)
-    ap.add_argument("--storage", choices=("mem", "lsm"), default="mem")
-    args = ap.parse_args()
-
-    corpus, server, load_s = load(args.edges, storage=args.storage)
-    res = run_suite(corpus, server)
-    out = {
-        "edges": corpus.n_edges,
-        "storage": args.storage,
-        "load_seconds": round(load_s, 2),
-        "load_edges_per_sec": int(corpus.n_edges / load_s),
-        "queries": res,
-        "all_ok": all(r["ok"] for r in res.values()),
-    }
-    text = json.dumps(out, indent=1)
-    print(text)
-    if args.json:
-        with open(args.json, "w") as f:
-            f.write(text)
-    sys.exit(0 if out["all_ok"] else 1)
-
-
-if __name__ == "__main__":
-    main()

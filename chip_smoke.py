@@ -16,7 +16,7 @@ errors"):
            3-hop with a string filter, an OR filter — against the
            corpus model.
   vector   BASELINE.json config 4: 1,000,000 x 768 float32, top-10, the
-           mixture-of-gaussians corpus bench.py builds. Served DQL
+           mixture-of-gaussians corpus below. Served DQL
            `similar_to`, single and 64 concurrent; exact float32/64
            numpy on the same rows is the reference.
   write    after the first device query: a committed mutation (knows
@@ -24,8 +24,7 @@ errors"):
            then visible to FoF and `similar_to`. The first commit forks
            the apply-shard workers from the process that holds the chip.
   kernels  every jitted set-op family called through `SetOpDispatcher`
-           above the device threshold, and the Pallas sweep compiled at
-           256 x <=128 vs 2^20 — each against numpy.
+           above the device threshold — each against numpy.
 
 It fails (exit code != 0, no "ok" line) when jax finds no accelerator,
 when any check fails, or when no graph query or not every vector query
@@ -213,8 +212,7 @@ class Graph:
 
     def fof(self, roots):
         """(query, model ids) for friends-of-friends of `roots` in ONE
-        block — the shape benchmarks/ldbc_bench.py builds: fof = (union
-        of friends' knows) - roots - friends."""
+        block: fof = (union of friends' knows) - roots - friends."""
         q = (
             f"{{ me as var(func: eq(fqid, [{self._fqids(roots)}])) "
             "{ f as knows } "
@@ -277,10 +275,9 @@ def run_graph_query(name, client, q, want, fetches, clock, checks, report):
 
 
 def mixture_of_gaussians(n, d, nq, seed, n_clusters=256):
-    """The corpus bench.py's vector capture builds: cluster centers at 4
-    sigma, unit noise (real embedding sets cluster). The noise is drawn
-    in row chunks: the same stream as one (n, d) draw without its 6 GB
-    float64 copy."""
+    """Cluster centers at 4 sigma, unit noise (real embedding sets
+    cluster). The noise is drawn in row chunks: the same stream as one
+    (n, d) draw without its 6 GB float64 copy."""
     rng = np.random.default_rng(seed)
     centers = rng.standard_normal((n_clusters, d)).astype(np.float32) * 4.0
     V = centers[rng.integers(0, n_clusters, n)]
@@ -461,48 +458,6 @@ def kernel_families(checks, fetches, clock, report, seed, on_tpu):
                               np.unique(np.concatenate(parts)))
 
     family("union#chain", "union#chain", chain_union)
-
-
-def pallas_sweep(checks, report, seed, on_tpu):
-    """The Pallas compare-all sweep at the headline shape, compiled by
-    Mosaic (never interpreted) on a TPU; a small shape through the
-    interpreter elsewhere."""
-    import jax
-    import jax.numpy as jnp
-
-    from dgraph_tpu.ops import pallas_setops, setops
-
-    rng = np.random.default_rng(seed + 4)
-    n, pa, pb = (256, 128, 1 << 20) if on_tpu else (4, 128, 2048)
-    nb = pb - pb // 16
-    b0 = np.unique(rng.integers(1, 1 << 31, nb + nb // 4,
-                                dtype=np.uint64))[:nb].astype(np.uint32)
-    A = np.full((n, pa), setops.UINT32_MAX, np.uint32)
-    B = np.full((n, pb), setops.UINT32_MAX, np.uint32)
-    LA, LB = np.zeros((n,), np.int32), np.zeros((n,), np.int32)
-    for i in range(n):
-        b = b0[: nb - 17 * i]
-        la = 10 + i % 118
-        a = np.unique(np.concatenate(
-            [rng.choice(b, la // 2), rng.integers(1, 1 << 31, la, dtype=np.uint32)]
-        ))[:la]
-        A[i, : len(a)], LA[i] = a, len(a)
-        B[i, : len(b)], LB[i] = b, len(b)
-    interpret = pallas_setops._default_interpret()
-    t0 = time.perf_counter()
-    out, cnt = jax.jit(pallas_setops.intersect_batch)(
-        jnp.asarray(A), jnp.asarray(LA), jnp.asarray(B), jnp.asarray(LB)
-    )
-    out, cnt = np.asarray(out), np.asarray(cnt)
-    report["pallas"] = {"shape": [n, pa, pb], "interpret": interpret,
-                        "compile_and_run_s": round(time.perf_counter() - t0, 2)}
-    ok = all(
-        np.array_equal(out[i, : cnt[i]],
-                       np.intersect1d(A[i, : LA[i]], B[i, : LB[i]]))
-        for i in range(n)
-    )
-    checks.add("kernel:pallas_sweep", ok and not (on_tpu and interpret),
-               f"{n} x <={pa} vs {pb}, interpret={interpret}")
 
 
 # ---------------------------------------------------------------------------
@@ -807,7 +762,7 @@ def serve(args, stack, checks, clock, fetches, report, on_tpu) -> None:
                f"{sorted({r['tier'] for r in served})}")
 
     # at this size the IVF probe wins every solo query, so the brute
-    # tier is driven the way bench.py drives it: one 64-wide search_batch
+    # tier is driven by one 64-wide search_batch
     mark, tq = fetches.mark(), time.perf_counter()
     got_b = idx.search_batch(Q[:64], TOPK)
     brute_s = time.perf_counter() - tq
@@ -873,10 +828,9 @@ def serve(args, stack, checks, clock, fetches, report, on_tpu) -> None:
     )
     del V, ref
 
-    # -- kernel families + pallas ------------------------------------------------
+    # -- kernel families ---------------------------------------------------------
     c0, t0 = clock.snap(), time.perf_counter()
     kernel_families(checks, fetches, clock, report, args.seed, on_tpu)
-    pallas_sweep(checks, report, args.seed, on_tpu)
     legs["kernels"] = dict(CompileClock.delta(c0, clock.snap()),
                            seconds=round(time.perf_counter() - t0, 1))
 

@@ -57,8 +57,8 @@ MAX_FRAME = int(config.get("MAX_FRAME_BYTES"))
 _BLOB_MIN = 256  # bytes values at least this long leave the JSON
 _ZLIB_LEVEL = 1
 # Compression default OFF: raw blobs already beat the old JSON+b64 path
-# 10x on encode+decode CPU and 1.33x on bytes (benchmarks/
-# bench_framing.py, one CPU core), and
+# 10x on encode+decode CPU and 1.33x on bytes (a predicate-move
+# chunk of 200 records x 256 KiB framed both ways, one CPU core), and
 # zlib-1 (~100MB/s) is SLOWER than LAN/ICI-class links — the reference
 # affords always-on compression only because snappy is ~free, which the
 # Python stdlib cannot match. Set DGRAPH_TPU_WIRE_COMPRESS=1 for

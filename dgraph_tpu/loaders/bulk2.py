@@ -711,9 +711,8 @@ class ParallelBulkLoader:
     def _ingest_stats(self, path: str):
         """Feed StatsHolder from the native reduce's index-selectivity
         sidecar ([u16 klen][key][u64 uid_count] per index key) at load
-        finish — closes the NOTES_NEXT_ROUND §2 gap where the C++ fast
-        path skipped selectivity stats and eq plans fell back to defaults
-        until the first commits."""
+        finish: the C++ fast path once skipped selectivity stats, and eq
+        plans fell back to defaults until the first commits."""
         stats = getattr(self.server, "stats", None)
         if stats is None or not os.path.exists(path):
             return

@@ -1022,8 +1022,8 @@ i64 bulk_run_path(void* h, i64 i, char* out, i64 cap) {
 //        layout, unencrypted) with version `ts` and seqs from seq_base+1.
 // out_stats (may be null/empty): index-key selectivity records
 // [u16 klen][key][u64 uid_count], one per index key — the StatsHolder
-// feed the Python slow path emits inline but the native path previously
-// skipped (NOTES_NEXT_ROUND §2 known gap).
+// feed the Python slow path emits inline; without it eq plans fall back
+// to default selectivities until the first commits.
 i64 bulk_reduce(void* h, const char* paths_joined, i64 plen,
                 u64 max_part_uids, const char* out_main,
                 const char* out_counts, const char* out_stats, u64 ns,

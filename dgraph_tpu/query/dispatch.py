@@ -41,10 +41,11 @@ from dgraph_tpu.x import config, device
 # Below this much total work, host kernels win (dispatch overhead
 # dominates). Unset, the threshold follows the platform the process
 # serves from (x/device.py): on a CPU backend — only ever by request —
-# XLA dispatch never beats the native host kernels at any size
-# (benchmarks/tune_thresholds.py found no crossover), so everything
-# stays on host; on an accelerator it is 1<<15, a value no chip sweep
-# has tuned yet.
+# XLA dispatch never beat the native host kernels (32 rows of 16
+# against a shared operand of 1<<10 to 1<<22 ids, host path against
+# the jitted one on XLA-CPU, one CPU core: no crossover at any size),
+# so everything stays on host; on an accelerator it is 1<<15, a value
+# no chip sweep has tuned yet.
 # env semantics kept from earlier rounds: setting 0 means "always use
 # the device" (total < 0 was never true); unset means platform-aware auto
 _env_min_total = config.get("DEVICE_MIN_TOTAL")
@@ -58,22 +59,23 @@ _HOST_ONLY = 1 << 62
 _SHARD_MIN_B = int(config.get("SHARD_MIN_B"))
 # Packed-vs-decode crossover: an array x pack pair takes the
 # compressed-domain path (ops/packed_setops.py) when |big| >= ratio *
-# |small|. With the native adaptive block engine (bitmap/packed hybrid
-# containers, codec.cpp pack_pair_setop/pack_stream_setop) the tuned
-# crossover is 8 (TUNE_PACKED_CPU.json rows, down from the pre-engine
-# 256); pack x pack pairs bypass the gate entirely — the pair engine
-# streams BOTH operands compressed and holds break-even-or-better at
-# every ratio (pair_rows: 1.5x over decode-both even at ratio 1, with
-# ZERO decoded bytes), the per-BLOCK kernel pick inside it replacing
-# the old whole-operand cliff. Without the engine the packed path
-# decodes candidate blocks in Python, which only pays when selective:
-# packed_min_ratio() re-applies the old cliff (256) there unless the
-# env pins a value.
+# |small|. This is a HOST-side crossover of the native adaptive block
+# engine (bitmap/packed hybrid containers, codec.cpp pack_pair_setop/
+# pack_stream_setop), measured on one CPU core against a 1M-uid pack:
+# from ratio 8 up the packed intersect matches or beats full decode +
+# dense intersect (3.42 ms against 3.45 at 8, 1.01 against 1.80 at 64;
+# below it loses, 5.78 against 4.95 at 4), down from the pre-engine
+# 256. No chip run has read it. pack x pack pairs bypass the gate
+# entirely: the pair engine streams BOTH operands compressed and on
+# the same host stayed within 5% of decode-both or better at every
+# ratio (2.70 ms against 4.06 at ratio 1, with ZERO decoded bytes),
+# the per-BLOCK kernel pick inside it replacing the old whole-operand
+# cliff. Without the engine the packed path decodes candidate blocks
+# in Python, which only pays when selective: packed_min_ratio()
+# re-applies the old cliff (256) there unless the env pins a value.
 _PACKED_MIN_RATIO = int(config.get("PACKED_MIN_RATIO"))
 _PACKED_FALLBACK_RATIO = 256
 _FORCE_DEVICE = bool(config.get("FORCE_DEVICE"))
-# opt-in Pallas compare-all sweep for small-side intersect buckets
-_USE_PALLAS = bool(config.get("PALLAS"))
 _MIN_PAD = 8
 
 
@@ -382,8 +384,8 @@ class SetOpDispatcher:
         r = self.packed_min_ratio()
         # both sides compressed: the pair engine skips BOTH decodes —
         # break-even-or-better at every ratio with zero decoded bytes
-        # (TUNE_PACKED_CPU.json pair_rows: 1.5x over decode-both even at
-        # ratio 1) — so no ratio gate when it's available
+        # (_PACKED_MIN_RATIO's comment has the host measurement) — so
+        # no ratio gate when it's available
         both = isinstance(a, PackedOperand) and isinstance(b, PackedOperand)
         if op in ("intersect", "difference") and isinstance(b, PackedOperand):
             if (
@@ -1103,22 +1105,9 @@ class SetOpDispatcher:
                         "difference": setops.difference,
                         "union": setops.union,
                     }[op]
-                    if _USE_PALLAS and op == "intersect" and pa <= 128:
-                        from dgraph_tpu.ops import pallas_setops
-
-                        # batch-aware pallas entry point — do NOT vmap a
-                        # single-example pallas kernel (TPU lowering
-                        # rejects the Squeezed SMEM blocks vmap produces)
-                        fn = jax.jit(
-                            setops.scoped(
-                                f"setop.{op}.pairs",
-                                pallas_setops.intersect_batch,
-                            )
-                        )
-                    else:
-                        fn = jax.jit(
-                            jax.vmap(setops.scoped(f"setop.{op}.pairs", base))
-                        )
+                    fn = jax.jit(
+                        jax.vmap(setops.scoped(f"setop.{op}.pairs", base))
+                    )
                     self._jit_cache[key] = fn
         return fn
 

@@ -2310,6 +2310,15 @@ class Executor:
 
         orders = gq.order
         ordered = [int(u) for u in uids]
+        # one batched read ahead of the per-uid loop: read one key at a
+        # time, every request thread takes the memory layer's lock once
+        # per uid, and under the GIL the threads fall into a lock convoy
+        # that halves a served cell's rate for seconds at a time
+        self.cache.prefetch([
+            keys.DataKey(o.attr, u, self.ns)
+            for o in orders if not o.val_var
+            for u in ordered
+        ])
         vals_per_key = [
             {u: key_of(o, u) for u in ordered} for o in orders
         ]

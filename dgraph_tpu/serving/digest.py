@@ -18,8 +18,9 @@ with a real shape — real shapes contain braces and spaces), so
 per-namespace totals stay exact under shape churn.
 
 Accounting is observation-only: `record()` mutates only this store, so
-query results are byte-identical with the store on or off (the A/B
-gate `bench.py --obs-sanity` enforces it). The hot path pays one
+query results are byte-identical with the store on or off
+(tests/test_flight_recorder.py::test_recorder_on_off_byte_identity
+holds it over the golden smoke subset). The hot path pays one
 enabled-check plus one dict update under a short lock; METRICS is
 never called while the store's lock is held (lock-order discipline).
 
@@ -190,9 +191,8 @@ class DigestStore:
         return rows
 
     def totals(self) -> Dict[str, float]:
-        """Store-wide aggregates — what qps_loadgen stamps into BENCH
-        rows: total calls/errors/latency plus the top shape's latency
-        share (0 when empty)."""
+        """Store-wide aggregates: total calls/errors/latency plus the
+        top shape's latency share (0 when empty)."""
         rows = self.snapshot()
         calls = sum(r["calls"] for r in rows)
         lat = sum(r["lat_sum"] for r in rows)

@@ -180,8 +180,9 @@ class Metrics:
 
     def snapshot(self, prefix: str = "") -> Dict[str, float]:
         """Counters+gauges whose names start with `prefix` — used by the
-        chaos suite and bench.py to diff fault/retry/circuit counters
-        around a workload without parsing the exposition text."""
+        chaos suite (tests/test_chaos.py) to diff fault/retry/circuit
+        counters around a workload without parsing the exposition
+        text."""
         with self._lock:
             out = {
                 k: v for k, v in self._counters.items()
@@ -2539,9 +2540,8 @@ declare_metric(
     "counter", "apply_shard_ipc_seconds",
     "Wall seconds group-commit leaders spent shipping columns into "
     "the shared-memory rings and waiting on apply-shard worker "
-    "responses — the shard-IPC cost qps_loadgen stamps into "
-    "BENCH_QPS rows (compare against commit_propose_ns_total for the "
-    "IPC share of the propose phase).",
+    "responses — the shard-IPC cost (compare against "
+    "commit_propose_ns_total for the IPC share of the propose phase).",
 )
 declare_metric(
     "counter", "backup_bytes_total",
@@ -2881,20 +2881,20 @@ declare_metric(
 declare_metric(
     "counter", "commit_oracle_ns_total",
     "Wall time (ns) group-commit leaders spent in the oracle verdict "
-    "exchange (fence check + zero.commit_batch) — the commit-phase "
-    "split qps_loadgen stamps into BENCH_QPS rows.",
+    "exchange (fence check + zero.commit_batch) — one of the three "
+    "commit phases (worker/groupcommit.py commit_phase_ns).",
 )
 declare_metric(
     "counter", "commit_propose_ns_total",
     "Wall time (ns) group-commit leaders spent encoding deltas and "
-    "dispatching write proposals (or the direct put_batch) — the "
-    "commit-phase split qps_loadgen stamps into BENCH_QPS rows.",
+    "dispatching write proposals (or the direct put_batch) — one of "
+    "the three commit phases (worker/groupcommit.py commit_phase_ns).",
 )
 declare_metric(
     "counter", "commit_apply_ns_total",
     "Wall time (ns) group-commit leaders spent in the apply barrier "
-    "(group applies + watermark advance + zero.applied) — the "
-    "commit-phase split qps_loadgen stamps into BENCH_QPS rows.",
+    "(group applies + watermark advance + zero.applied) — one of the "
+    "three commit phases (worker/groupcommit.py commit_phase_ns).",
 )
 declare_metric(
     "counter", "num_commits",

@@ -20,10 +20,9 @@ Two triggers:
   watching.
 
 Sampling is observation-only: frames are read, never mutated, and no
-query-path code changes behavior based on an active capture — response
-bytes are identical with a capture running (the --obs-sanity A/B gate's
-profiler-armed leg). METRICS is never called while a profiler lock is
-held (lock-order discipline).
+query-path code changes behavior based on an active capture. METRICS
+is never called while a profiler lock is held (lock-order
+discipline).
 """
 
 from __future__ import annotations

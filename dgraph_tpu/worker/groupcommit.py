@@ -98,9 +98,8 @@ def columnar_writes(committed):
 def commit_phase_ns(oracle: int = 0, propose: int = 0, apply: int = 0):
     """Commit-phase wall-time split (ns): where a group-commit batch
     spent its time — the oracle verdict exchange, the encode+propose
-    (or put_batch) phase, and the apply barrier. qps_loadgen stamps
-    the deltas of these counters into every BENCH_QPS row so the
-    residual write-path bound is visible in-capture."""
+    (or put_batch) phase, and the apply barrier: the deltas of these
+    counters around a write workload show which phase bounds it."""
     if oracle:
         METRICS.inc("commit_oracle_ns_total", oracle)
     if propose:

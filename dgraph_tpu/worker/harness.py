@@ -1025,11 +1025,11 @@ class ProcCluster:
                 t_done = time.perf_counter()
             METRICS.inc("num_queries")
             ext = out.setdefault("extensions", {})
-            # encoding_ns is the wire-bytes production time (the A/B
-            # quantity for BENCH_ENCODE.json); processing absorbs the
-            # rest of the post-ts work — including the dict-API compat
-            # parse-back, itemized as profile.encode.parse_ns — so the
-            # parts still sum to total_ns with no unattributed gap
+            # encoding_ns is the wire-bytes production time; processing
+            # absorbs the rest of the post-ts work — including the
+            # dict-API compat parse-back, itemized as
+            # profile.encode.parse_ns — so the parts still sum to
+            # total_ns with no unattributed gap
             enc_ns = int(prof.encode.get("encode_ns", 0))
             total_ns = int((t_done - t_start) * 1e9)
             ext["server_latency"] = {

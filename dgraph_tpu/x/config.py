@@ -424,16 +424,11 @@ _define(
     "PACKED_MIN_RATIO", "int", 8,
     "Packed-vs-decode crossover for array x pack pairs: the op takes "
     "the compressed-domain path when |big| >= ratio * |small| (query/"
-    "dispatch.py; tuned via TUNE_PACKED_CPU.json — 8 with the native "
-    "adaptive block engine, down from the pre-engine 256). Pack x pack "
-    "pairs bypass the gate entirely (the pair engine holds break-even-"
-    "or-better at every ratio with zero decode); without the native "
-    "engine an unset knob falls back to the pre-engine cliff of 256.",
-)
-_define(
-    "PALLAS", "bool", False,
-    "Opt-in Pallas compare-all sweep for small-side intersect buckets "
-    "(query/dispatch.py, ops/pallas_setops.py).",
+    "dispatch.py; a host-side crossover measured on one CPU core — 8 "
+    "with the native adaptive block engine, down from the pre-engine "
+    "256). Pack x pack pairs bypass the gate entirely (the pair engine "
+    "runs them with zero decode); without the native engine an unset "
+    "knob falls back to the pre-engine cliff of 256.",
 )
 _define(
     "PLAN_CACHE_SIZE", "int", 512,
@@ -633,7 +628,7 @@ _define(
     "of hex-uid and count-object arrays — byte-identical to the dict "
     "encoder by contract. 0 is the escape hatch back to the "
     "ExecNode->dict->json.dumps path (query/outputjson.py) for A/B "
-    "benchmarking (BENCH_ENCODE.json) and triage.",
+    "comparison (tests/test_stream_encoder.py) and triage.",
 )
 _define(
     "TABLET_TRAFFIC", "bool", True,
@@ -641,8 +636,7 @@ _define(
     "every level read and committed mutation records into a sharded "
     "(namespace, predicate) accumulator served at /debug/tablets and "
     "consumed by the traffic-driven rebalancer. Always-on by design "
-    "(its cost is unresolved: BENCH_OBS.json's CPU arms differ by less "
-    "than their spread); 0 is the A/B escape hatch.",
+    "(its cost has not been measured); 0 is the A/B escape hatch.",
 )
 _define(
     "TRACE", "bool", True,
@@ -695,7 +689,7 @@ _define(
     "(codec.cpp vec_qi8_topk*) with a float32 rerank of the surviving "
     "pool (models/vector.py). Applies on CPU-backend hosts above the "
     "small-corpus cutoff; 0 is the A/B escape hatch back to the jitted "
-    "float32 paths (BENCH_VECTOR.json).",
+    "float32 paths (tests/test_vector_quant.py).",
 )
 _define(
     "VEC_REBUILD_IMBALANCE", "float", 4.0,
@@ -726,7 +720,7 @@ _define(
     "WIRE_COMPRESS", "bool", False,
     "zlib-compress bulk wire blobs; default OFF because zlib-1 is "
     "slower than LAN/ICI-class links — enable for DCN-class links "
-    "(conn/frame.py; measured by benchmarks/bench_framing.py).",
+    "(conn/frame.py has the host measurement).",
 )
 
 

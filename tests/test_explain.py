@@ -226,9 +226,8 @@ def test_explain_counter_ticks(small_server):
 
 
 def test_render_plan_snapshot(small_server):
-    """The rendered plan is a stable contract (the --explain-sanity
-    gate snapshots it too): one header, the decision lines, one
-    indented line per node."""
+    """The rendered plan is a stable contract: one header, the
+    decision lines, one indented line per node."""
     from dgraph_tpu.cli import render_plan
 
     res = small_server.query(

@@ -143,34 +143,49 @@ def test_slow_query_log_records_digest_shape(tmp_path, monkeypatch):
     assert rec.get("ns") is not None
 
 
-def test_recorder_on_off_byte_identity():
-    """Spot check of the --obs-sanity gate's property: the recorder
-    never changes response bytes."""
+HERE = os.path.join(os.path.dirname(os.path.abspath(__file__)), "ref_golden")
+SMOKE_CASES = json.load(open(os.path.join(HERE, "cases.json")))[::9]
+
+
+@pytest.fixture(scope="module")
+def golden_server():
     from dgraph_tpu.api.server import Server
-    from dgraph_tpu.x import config
 
     s = Server()
-    s.alter("biname: string @index(exact) .")
-    s.new_txn().mutate_rdf(
-        set_rdf='<0x1> <biname> "A" .', commit_now=True
-    )
-    q = '{ q(func: eq(biname, "A")) { biname } }'
+    s.alter(open(os.path.join(HERE, "schema.txt")).read())
+    for rdf in ("triples.rdf", "triples_facets.rdf"):
+        s.new_txn().mutate_rdf(
+            set_rdf=open(os.path.join(HERE, rdf)).read(), commit_now=True
+        )
+    return s
+
+
+@pytest.mark.parametrize(
+    "case", SMOKE_CASES, ids=[c["id"] for c in SMOKE_CASES]
+)
+def test_recorder_on_off_byte_identity(golden_server, case, monkeypatch):
+    """The recorder never changes response bytes (or the error a query
+    fails with), and the digest store records on the on arm only."""
 
     def run():
-        d = s.query(q, want="raw")["data"]
+        try:
+            d = golden_server.query(case["query"], want="raw")["data"]
+        except Exception as exc:
+            return f"{type(exc).__name__}: {exc}"
         raw = getattr(d, "raw", None)
         return bytes(raw) if raw is not None else json.dumps(
             d, sort_keys=True
         ).encode()
 
-    config.set_env("DIGEST", 0)
-    config.set_env("HISTORY", 0)
-    try:
-        off = run()
-    finally:
-        config.unset_env("DIGEST")
-        config.unset_env("HISTORY")
+    calls = DIGESTS.totals()["calls"]
+    monkeypatch.setenv("DGRAPH_TPU_DIGEST", "0")
+    monkeypatch.setenv("DGRAPH_TPU_HISTORY", "0")
+    off = run()
+    assert DIGESTS.totals()["calls"] == calls
+    monkeypatch.setenv("DGRAPH_TPU_DIGEST", "1")
+    monkeypatch.setenv("DGRAPH_TPU_HISTORY", "1")
     assert run() == off
+    assert DIGESTS.totals()["calls"] == calls + 1
 
 
 # ---------------------------------------------------------------------------

@@ -311,33 +311,70 @@ def _mk_server():
     return s
 
 
-def test_concurrent_committers_coalesce_and_commit():
-    s = _mk_server()
-    base_batches = METRICS.value("group_commit_total")
-    base_txns = METRICS.value("group_commit_txns_total")
-    errs = []
+@pytest.mark.parametrize("procs", [0, 2])
+def test_concurrent_committers_coalesce_and_commit(procs):
+    """32 committers beside two readers, the native columnar apply in
+    the serving process (APPLY_PROCS=0) and behind the apply-shard
+    worker processes (=2): nobody errs, every write is read back, and
+    each arm is seen to take its path (its counters move)."""
+    from dgraph_tpu.worker import applyshard
 
-    def w(i):
-        try:
-            t = s.new_txn()
-            t.mutate_json(
-                set_obj={"uid": "_:x", "name": f"gc{i}",
-                         "knows": [{"uid": "0x1"}]},
-                commit_now=True,
-            )
-        except Exception as e:  # pragma: no cover - failure detail
-            errs.append(e)
+    config.set_env("BATCH_APPLY", 1)
+    config.set_env("APPLY_PROCS", procs)
+    before = dict(METRICS.snapshot())
+    try:
+        s = _mk_server()
+        # a bypassed commit that lets the GIL go: the committers that
+        # arrive meanwhile find the coalescer busy and must queue
+        serial = s._gc_serial
+        s._gc_serial = lambda txn: (time.sleep(0.005), serial(txn))[1]
+        errs = []
+        done = threading.Event()
 
-    ths = [threading.Thread(target=w, args=(i,)) for i in range(32)]
-    for t in ths:
-        t.start()
-    for t in ths:
-        t.join()
+        def w(i):
+            try:
+                t = s.new_txn()
+                t.mutate_json(
+                    set_obj={"uid": "_:x", "name": f"gc{i}",
+                             "knows": [{"uid": "0x1"}]},
+                    commit_now=True,
+                )
+            except Exception as e:  # pragma: no cover - failure detail
+                errs.append(e)
+
+        def r():
+            try:
+                while not done.is_set():
+                    s.query('{ q(func: has(name)) { name knows { uid } } }')
+            except Exception as e:  # pragma: no cover - failure detail
+                errs.append(e)
+
+        readers = [threading.Thread(target=r) for _ in range(2)]
+        ths = [threading.Thread(target=w, args=(i,)) for i in range(32)]
+        for t in readers + ths:
+            t.start()
+        for t in ths:
+            t.join()
+        done.set()
+        for t in readers:
+            t.join()
+    finally:
+        config.unset_env("BATCH_APPLY")
+        config.unset_env("APPLY_PROCS")
+        applyshard.shutdown()
     assert not errs
     out = s.query('{ q(func: has(name)) { name } }')
     assert len(out["data"]["q"]) == 32
-    assert METRICS.value("group_commit_txns_total") - base_txns >= 32
-    assert METRICS.value("group_commit_total") - base_batches >= 1
+    after = dict(METRICS.snapshot())
+
+    def delta(name):
+        return after.get(name, 0) - before.get(name, 0)
+
+    assert delta("mutation_batch_apply_edges_total") > 0, "kernel never ran"
+    assert (delta("apply_shard_batches_total") > 0) == bool(procs)
+    assert delta("apply_shard_fallback_total") == 0
+    assert delta("group_commit_txns_total") >= 32
+    assert delta("group_commit_total") >= 1
     # pipeline fully drained: no outstanding barrier
     assert METRICS.value("commit_pipeline_depth") == 0
     s._group_commit.drain()  # returns immediately when drained
@@ -384,12 +421,17 @@ def test_batch_conflict_aborts_only_the_loser():
     assert out["data"]["q"] == [{"name": "safe"}]
 
 
-def test_escape_hatch_restores_serial_path_byte_for_byte(monkeypatch):
+@pytest.mark.parametrize("bypass", [0, 1])
+def test_escape_hatch_restores_serial_path_byte_for_byte(monkeypatch, bypass):
     """DGRAPH_TPU_GROUP_COMMIT=0 through the public commit API: the
     coalescer is never even constructed, and the stored KV bytes match
     a group-commit engine's byte-for-byte for the same single-threaded
-    mutation sequence."""
+    mutation sequence, whether its commits queue for the coalescer
+    (GROUP_COMMIT_BYPASS=0) or take the adaptive bypass (=1); each arm
+    must be seen to take its path."""
     import dgraph_tpu.worker.groupcommit as gcmod
+
+    monkeypatch.setenv("DGRAPH_TPU_GROUP_COMMIT_BYPASS", str(bypass))
 
     def run(mode):
         config.set_env("GROUP_COMMIT", mode)
@@ -418,7 +460,13 @@ def test_escape_hatch_restores_serial_path_byte_for_byte(monkeypatch):
         finally:
             config.unset_env("GROUP_COMMIT")
 
+    bypassed = METRICS.value("group_commit_bypass_total")
+    batches = METRICS.value("group_commit_total")
     on = run(1)
+    bypassed = METRICS.value("group_commit_bypass_total") - bypassed
+    batches = METRICS.value("group_commit_total") - batches
+    assert (bypassed > 0) == bool(bypass), (bypassed, batches)
+    assert batches > 0 or bypass, "no commit went through the coalescer"
 
     def _boom(*a, **k):  # the serial path must never touch the coalescer
         raise AssertionError("GroupCommit constructed with hatch off")

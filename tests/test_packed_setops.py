@@ -270,7 +270,8 @@ def test_posting_list_block_cache_and_packed_view():
 
 def test_native_bulk_load_feeds_stats(tmp_path):
     """The C++ bulk path must emit index selectivity records and the
-    loader must ingest them at load finish (NOTES_NEXT_ROUND §2 gap)."""
+    loader must ingest them at load finish, or eq plans run on default
+    selectivities until the first commits."""
     from dgraph_tpu import native
 
     if not native.NATIVE_AVAILABLE:

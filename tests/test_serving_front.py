@@ -89,9 +89,19 @@ def test_window_zero_is_a_true_off_switch(golden_server, monkeypatch):
     golden_server.query(q)  # must not touch the batcher
 
 
+@pytest.mark.parametrize("admission", [0, 1])
 def test_concurrent_queries_coalesce_and_stay_byte_identical(
-    golden_server, monkeypatch
+    golden_server, monkeypatch, admission
 ):
+    """Four closed-loop clients behind the batch window, with and
+    without the admission gate (a shed query is retried, as a client
+    of the front does): every answer arrives and is the solo answer."""
+    from dgraph_tpu.conn.retry import Deadline, RetryPolicy, retrying_call
+    from dgraph_tpu.serving import TooManyRequestsError
+
+    monkeypatch.setenv("DGRAPH_TPU_ADMISSION", str(admission))
+    monkeypatch.setenv("DGRAPH_TPU_MAX_INFLIGHT", "2")
+    shed = METRICS.value("admission_shed_total")
     q = """{ me(func: eq(name, "Michonne")) {
         name
         friend { name friend { name } }
@@ -116,19 +126,25 @@ def test_concurrent_queries_coalesce_and_stay_byte_identical(
     def worker():
         barrier.wait()
         for _ in range(20):
-            got = json.dumps(
-                golden_server.query(q)["data"], sort_keys=False
+            res = retrying_call(
+                lambda: golden_server.query(q),
+                policy=RetryPolicy(base=0.002, cap=0.05),
+                deadline=Deadline.after(60.0),
+                retryable=(TooManyRequestsError,),
             )
             with lock:
-                results.append(got)
+                results.append(json.dumps(res["data"], sort_keys=False))
 
     ths = [threading.Thread(target=worker) for _ in range(4)]
     for th in ths:
         th.start()
     for th in ths:
         th.join()
-    assert all(r == base for r in results)
-    assert METRICS.value("batch_coalesced_total") > before, (
+    assert len(results) == 80 and all(r == base for r in results)
+    shed = METRICS.value("admission_shed_total") - shed
+    assert (shed > 0) == bool(admission), shed
+    # a budget of two leaves nothing in flight to coalesce with
+    assert admission or METRICS.value("batch_coalesced_total") > before, (
         "no cross-query coalescing happened under 4-way concurrency"
     )
 

@@ -127,43 +127,3 @@ def test_batched_vmap_matches_scalar():
     for i, (a, b) in enumerate(pairs):
         want = np.intersect1d(a, b, assume_unique=True)
         np.testing.assert_array_equal(np.asarray(out[i])[: int(n[i])], want)
-
-
-def test_pallas_membership_interpret():
-    # semantics-equal to the XLA membership path (interpret mode on CPU)
-    from dgraph_tpu.ops import pallas_setops
-
-    rng = np.random.default_rng(3)
-    b = _mk(rng, 5000)
-    a = np.concatenate([b[::97][:40], _mk(rng, 30, hi=1 << 29)])
-    a = np.unique(a)[:100]
-    A = jnp.asarray(setops.pad_sorted(a, 128))
-    B = jnp.asarray(setops.pad_sorted(b, 8192))
-    got = np.asarray(
-        pallas_setops.membership(A, len(a), B, len(b), interpret=True)
-    )
-    want = np.isin(a, b)
-    np.testing.assert_array_equal(got[: len(a)], want)
-    assert not got[len(a) :].any()
-    # sentinel uid 0xFFFFFFFF is a valid value (validity by length)
-    a2 = np.array([1, 0xFFFFFFFF], np.uint32)
-    b2 = np.array([0xFFFFFFFF], np.uint32)
-    got = np.asarray(
-        pallas_setops.membership(
-            jnp.asarray(setops.pad_sorted(a2, 8)), 2,
-            jnp.asarray(setops.pad_sorted(b2, 8)), 1,
-            interpret=True,
-        )
-    )
-    np.testing.assert_array_equal(got[:2], [False, True])
-    # zero-valued uid in b padding must not create false hits
-    a3 = np.array([0], np.uint32)
-    b3 = np.array([5], np.uint32)
-    got = np.asarray(
-        pallas_setops.membership(
-            jnp.asarray(setops.pad_sorted(a3, 8)), 1,
-            jnp.asarray(setops.pad_sorted(b3, 8)), 1,
-            interpret=True,
-        )
-    )
-    assert not got[0]

@@ -68,6 +68,42 @@ def test_lossy_float_bucket_inner_sort(server):
     assert scores == sorted(scores)
 
 
+def test_multi_key_order_reads_its_keys_in_one_batch(server, monkeypatch):
+    """The generic (multi-key) sort reads every uid's sort values through
+    ONE batched read, not one memory-layer read a uid: per-uid reads
+    take the layer's lock once each, and concurrent requests then fall
+    into a lock convoy."""
+    single, batched, in_batch = [], [], []
+    real_read, real_many = server.mem.read, server.mem.read_many
+
+    def read(kv, key, read_ts):
+        # (a backend with no batch API serves read_many key by key)
+        if not in_batch:
+            single.append(key)
+        return real_read(kv, key, read_ts)
+
+    def read_many(kv, keys_list, read_ts):
+        keys_list = list(keys_list)
+        batched.extend(keys_list)
+        in_batch.append(1)
+        try:
+            return real_many(kv, keys_list, read_ts)
+        finally:
+            in_batch.pop()
+
+    monkeypatch.setattr(server.mem, "read", read)
+    monkeypatch.setattr(server.mem, "read_many", read_many)
+    q = "{ q(func: has(name), orderdesc: age, orderasc: name) { name age } }"
+    rows = server.query(q)["data"]["q"]
+    ages = [x["age"] for x in rows if "age" in x]
+    assert ages == sorted(ages, reverse=True) and len(ages) == 60
+    assert len(rows) == 61 and rows[-1]["name"] == "ageless"
+    # the root read every `name` list already; the 61 `age` lists are
+    # what the sort still has to read, and it reads them in the batch
+    assert sum(b"age" in k for k in batched) == 61
+    assert not any(b"age" in k for k in single), len(single)
+
+
 def test_device_topk_val_var_first():
     s = Server()
     s.alter("name: string @index(exact) .\nrank: int @index(int) .")

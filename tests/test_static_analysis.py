@@ -121,8 +121,8 @@ def test_config_checker_catches_raw_env_read(tmp_path):
         os.environ["DGRAPH_TPU_STORAGE"] = "lsm"
         D = environ.get("SOME_OTHER_VAR")
         E = dict(os.environ)
-        F = environ["DGRAPH_TPU_PALLAS"]      # from-import bypass
-        G = getenv("DGRAPH_TPU_PALLAS")       # bare getenv bypass
+        F = environ["DGRAPH_TPU_SHARD_MIN_B"] # from-import bypass
+        G = getenv("DGRAPH_TPU_SHARD_MIN_B")  # bare getenv bypass
         """,
         ["config-registry"],
     )
@@ -836,6 +836,58 @@ def test_metrics_md_in_sync():
         "METRICS.md is stale — regenerate with "
         "`python -m dgraph_tpu.cli metrics-ref -o METRICS.md`"
     )
+
+
+# a name a document gives in backticks that is a file the program
+# writes at run time, not a path of the tree
+_RUNTIME_FILES = {"MANIFEST.json"}  # a debug bundle's member
+
+
+@pytest.mark.parametrize(
+    "doc",
+    [
+        "tools/check.sh",
+        "README.md",
+        "ARCHITECTURE.md",
+        "benchmarks/README.md",
+        "CONFIG.md",
+    ],
+)
+def test_documents_name_only_files_that_exist(doc):
+    """Every script tools/check.sh runs, and every .py/.json/.md/.sh
+    path a document names in backticks, is in the tree: a document
+    that sends its reader to a deleted harness or capture fails here."""
+    import re
+
+    with open(os.path.join(REPO, doc)) as f:
+        text = f.read()
+    path = r"[\w./-]+\.(?:py|json|md|sh)"
+    if doc.endswith(".sh"):
+        named = set(re.findall(path, text))
+    else:
+        named = {
+            m.group(0)
+            for tok in re.findall(r"`([^`\n]+)`", text)
+            for word in tok.split()
+            for m in [re.match(path + r"(?=$|:)", word)]
+            if m
+        }
+    # a path is given from the document's directory, the repo root, the
+    # package or the tests; a bare name may be any file of those trees
+    roots = [os.path.dirname(doc), "", "dgraph_tpu", "tests"]
+    trees = ["dgraph_tpu", "tests", "tools", "benchmarks", "chipbench"]
+    basenames = set(os.listdir(REPO)) | _RUNTIME_FILES
+    for tree in trees:
+        for _, _, files in os.walk(os.path.join(REPO, tree)):
+            basenames.update(files)
+    missing = sorted(
+        p for p in named
+        if not any(
+            os.path.isfile(os.path.join(REPO, root, p)) for root in roots
+        )
+        and ("/" in p or p not in basenames)
+    )
+    assert named and not missing, missing
 
 
 def test_metric_declarations_are_documented():

@@ -29,8 +29,7 @@ recovery and finishes in seconds — tier-1 and `tools/check.sh
 --read-chaos-sanity` run exactly that slice.
 
     python tools/chaos_soak.py --sanity          # fixed-seed CI slice
-    python tools/chaos_soak.py --long            # full schedule,
-                                                 # stamps BENCH_CHAOS.json
+    python tools/chaos_soak.py --long            # full schedule
 
 Every per-phase row carries the follower-read / breaker / retry-budget
 counters, so a regression in routing shows up as a counter delta even
@@ -472,9 +471,8 @@ def main():
     ap.add_argument("--sanity", action="store_true",
                     help="short fixed-seed slice (tier-1 / check.sh)")
     ap.add_argument("--long", action="store_true",
-                    help="full schedule, stamps BENCH_CHAOS.json")
+                    help="full schedule")
     ap.add_argument("--seed", type=int, default=1234)
-    ap.add_argument("--out", default=os.path.join(REPO, "BENCH_CHAOS.json"))
     args = ap.parse_args()
     if not (args.sanity or args.long):
         args.sanity = True
@@ -490,18 +488,6 @@ def main():
     print(json.dumps(
         {k: v for k, v in result.items() if k != "phases"}, indent=2
     ))
-    if args.long:
-        from benchmarks import stamp
-
-        try:
-            existing = json.load(open(args.out))
-            existing.pop("provenance", None)
-        except Exception:
-            existing = {}
-        existing["soak"] = result
-        wrote = stamp.guarded_write(args.out, existing, "cpu")
-        print(f"chaos_soak: stamped {wrote}")
-
     if result["failures"]:
         print("chaos_soak: FAILURES:", file=sys.stderr)
         for f in result["failures"]:

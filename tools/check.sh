@@ -8,16 +8,6 @@
 #                           the ~5s ops-plane gate alone: backup/restore
 #                           crash-consistency + CDC ordering/replay
 #                           (tests/test_ops_plane.py)
-#   tools/check.sh --plan-sanity
-#                           the ~5s planner/result-reuse gate alone:
-#                           planner on/off + result-cache off/miss/hit
-#                           byte-equality over the golden smoke subset
-#                           (bench.py --plan-sanity)
-#   tools/check.sh --obs-sanity
-#                           the ~5s flight-recorder gate alone: digest +
-#                           history on/off byte-equality over the golden
-#                           smoke subset, digest store and history ring
-#                           asserted live (bench.py --obs-sanity)
 #   tools/check.sh --read-chaos-sanity
 #                           the read-plane chaos gate alone: fixed-seed
 #                           chaos soak slice — leader SIGKILL under the
@@ -56,20 +46,6 @@ if [[ "${1:-}" == "--ops-sanity" ]]; then
     exit 0
 fi
 
-if [[ "${1:-}" == "--plan-sanity" ]]; then
-    echo "== planner/result-reuse sanity (~5s): A/B byte-equality =="
-    python bench.py --plan-sanity
-    echo "check.sh: plan-sanity passed"
-    exit 0
-fi
-
-if [[ "${1:-}" == "--obs-sanity" ]]; then
-    echo "== flight-recorder sanity (~5s): digest/history A/B byte-equality =="
-    python bench.py --obs-sanity
-    echo "check.sh: obs-sanity passed"
-    exit 0
-fi
-
 if [[ "${1:-}" == "--read-chaos-sanity" ]]; then
     echo "== read-plane chaos sanity: leader kill + byte-identity replay =="
     python tools/chaos_soak.py --sanity
@@ -98,7 +74,7 @@ fi
 
 # analyzers FIRST: a registry violation (undeclared metric/config, new
 # allowlist entry) must fail in seconds, before lint and long before the
-# smoke subset or the ~5s sanity gates get a chance to run
+# smoke subset gets a chance to run
 echo "== analyzer + config-registry self-tests =="
 python -m pytest tests/test_static_analysis.py -q -p no:cacheprovider
 
@@ -129,6 +105,7 @@ else
         tests/test_planner.py \
         tests/test_ops_plane.py \
         tests/test_follower_reads.py \
+        tests/test_flight_recorder.py \
         -q -p no:cacheprovider
 
     echo "== proc-shard chaos smoke: worker SIGKILL + respawn, ledger exact =="
@@ -137,27 +114,6 @@ else
 
     echo "== read-plane chaos sanity: leader kill + byte-identity replay =="
     python tools/chaos_soak.py --sanity
-
-    echo "== explain sanity (~5s) =="
-    python bench.py --explain-sanity
-
-    echo "== planner/result-reuse sanity (~5s) =="
-    python bench.py --plan-sanity
-
-    echo "== flight-recorder sanity (~5s) =="
-    python bench.py --obs-sanity
-
-    echo "== qps loadgen sanity (~5s) =="
-    python benchmarks/qps_loadgen.py --sanity
-
-    echo "== qps loadgen write sanity (~5s) =="
-    python benchmarks/qps_loadgen.py --write-sanity
-
-    echo "== encode microbench sanity (~5s) =="
-    python bench.py --encode-sanity
-
-    echo "== vector engine sanity (~5s) =="
-    python bench.py --vector-sanity
 fi
 
 echo "check.sh: all stages passed"
