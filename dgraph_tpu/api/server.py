@@ -1618,8 +1618,12 @@ class Server:
             deadline=deadline,
             batcher=batcher,
         )
-        with observe.TRACER.span("process", cpu=True, fine=True):
+        with observe.TRACER.span("process", cpu=True, fine=True) as sp:
             nodes = ex.process(blocks)
+            if ex.order_tally:
+                sp.attrs["order_cands"] = ex.order_tally[
+                    "order_candidates_total"]
+                sp.attrs["order_kept"] = ex.order_tally["order_kept_total"]
         with observe.TRACER.span("encode", cpu=True, fine=True) as sp:
             data, enc_stats = encode_response_data(
                 nodes, val_vars=ex.val_vars, schema=self.schema, want=want

@@ -14,7 +14,10 @@ Execution order of blocks follows variable dependencies
 
 from __future__ import annotations
 
+import collections
 import contextvars
+import heapq
+import itertools
 import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
@@ -126,6 +129,25 @@ def _expand_pool(workers: int) -> ThreadPoolExecutor:
         return pool
 
 
+# Candidate ids a multi-key `order .. first: N` must bring for every index
+# bucket its window walk may read (Executor._narrow_to_window): the walk
+# gives up after len(uids) // 8 buckets and the block sorts every
+# candidate, as it did before the walk existed. Data whose leading key has
+# about as many distinct values as there are candidates (SF1 proper's
+# thousands of last names) then pays the old cost plus a bounded extra.
+# The reading that chose 8 (PR 32; this sandbox's CPU, one thread, the
+# chipbench SNB store on LSM at 9,892 persons, memory layer warm): the
+# comparator's path costs 7.2-7.8 us a candidate over (int, string) keys
+# and 17.6-22.3 us over IC1's (string, int); a walk costs ~55 us to open
+# the store's iterator and then 17 us a bucket, listed, read and
+# intersected (1,232 one-id buckets against 9,892 candidates), so a walk
+# that spends its whole budget adds 10-30% to the block. The 17 us hold
+# because native.intersect gallops through candidates sorted once a walk;
+# np.intersect1d sorts them again for every bucket (0.16 ms at 8,000
+# candidates, 1.7 ms at 80,000) and no budget in buckets would bound that.
+_ORDER_WALK_IDS_PER_BUCKET = 8
+
+
 @dataclass
 class ExecNode:
     """Executed form of one GraphQuery node (ref query.SubGraph)."""
@@ -195,6 +217,11 @@ class Executor:
         self.val_vars: Dict[str, Dict[int, Val]] = {}
         # where each value var is keyed (for per-parent aggregation)
         self.var_def_node: Dict[str, ExecNode] = {}
+        # multi-key window walks (_order_uids_window): what the request's
+        # ordered blocks and rows came to, flushed to METRICS by process()
+        # (sibling levels expand on pool threads, hence the lock)
+        self._order_mu = threading.Lock()
+        self.order_tally: Dict[str, int] = collections.Counter()
         # cost-based planner (query/planner.py): whole-query evaluation
         # ordering + intersect-vs-filter strategy, observation-
         # equivalent by construction; None = declaration-order
@@ -238,6 +265,14 @@ class Executor:
                 raise QueryBudgetError("query exceeded its time budget")
 
     def process(self, blocks: List[GraphQuery]) -> List[ExecNode]:
+        self.order_tally = collections.Counter()
+        try:
+            return self._process(blocks)
+        finally:
+            if self.order_tally:
+                METRICS.inc_many(self.order_tally)
+
+    def _process(self, blocks: List[GraphQuery]) -> List[ExecNode]:
         pending = list(blocks)
         executed: List[ExecNode] = [None] * len(blocks)  # type: ignore
         idx = {id(b): i for i, b in enumerate(blocks)}
@@ -2266,7 +2301,11 @@ class Executor:
         """full=True keeps EVERY uid ordered (no first/offset-bounded
         top-k / index early stop) — required when pruning happens after
         ordering, e.g. @cascade (ref TestCascadeWithSort)."""
-        if not len(uids) or not gq.order:
+        if not gq.order:
+            return uids
+        if len(gq.order) > 1 and gq.first is not None:
+            return self._order_uids_window(gq, uids, full)
+        if not len(uids):
             return uids
         if any(o.lang and o.lang != "." for o in gq.order):
             # lang-tagged sorts need collation — only the generic path
@@ -2281,6 +2320,111 @@ class Executor:
             if got is not None:
                 return got
         return self._order_uids_generic(gq, uids)
+
+    def _order_uids_window(
+        self, gq: GraphQuery, uids: np.ndarray, full: bool
+    ) -> np.ndarray:
+        """Two or more order keys under `first`: hand the comparator only
+        the ids that can reach the window (ref worker/sort.go: the
+        leading key through its sortable index, the later keys read for
+        what the first kept). Engages on what the query and the schema
+        show: the whole window is wanted from the front (`first >= 0`, no
+        `after`, not `full`), the candidates do not fit it, and the
+        leading key is an untagged predicate whose index holds each uid
+        in ONE bucket of a sortable tokenizer (an @lang or list predicate
+        holds a uid in several, and the comparator reads one value) whose
+        tokens are monotone in the value as the comparator sees it. The
+        date tokenizers are not: they encode the fields of the datetime
+        as written, offset and all (10:30+05:00 sits in hour bucket 10),
+        and the comparator orders by instant, so a datetime leading key
+        stays with the comparator. Everything else, and a walk that ran
+        out of budget or of buckets, sorts every candidate as before."""
+        path, kept, read = "generic", uids, 0
+        o = gq.order[0]
+        need = max(gq.offset or 0, 0) + gq.first
+        if (
+            not full
+            and gq.first >= 0
+            and gq.after is None
+            and len(uids) > need
+            and not o.val_var
+            and not o.lang
+        ):
+            su = self.st.get(o.attr)
+            tk = None
+            if su is not None and not su.lang and not su.is_list:
+                tk = next(
+                    (
+                        t for t in su.tokenizer_objs()
+                        if t.is_sortable and t.type_id != TypeID.DATETIME
+                    ),
+                    None,
+                )
+            if tk is not None:
+                path, kept, read = self._narrow_to_window(o, tk, uids, need)
+        with self._order_mu:
+            self.order_tally.update({
+                f'order_window_total{{path="{path}"}}': 1,
+                "order_candidates_total": len(uids),
+                "order_kept_total": len(kept),
+                "order_buckets_total": read,
+            })
+        return self._order_uids_generic(gq, kept)
+
+    def _narrow_to_window(
+        self, o: Order, tk, uids: np.ndarray, need: int
+    ) -> Tuple[str, np.ndarray, int]:
+        """(path, ids for the comparator, buckets read). Walk the leading
+        key's buckets in its direction and keep WHOLE buckets until they
+        hold `need` candidates. The tokens are monotone in the value, the
+        lossy ones too (the int of a float), so every id of a later
+        bucket sorts strictly after every kept one whatever the later
+        keys say, and inside the kept buckets the comparator orders by
+        the real values of all keys. Ids with no leading value sort after
+        every valued one: they count only when the buckets run out first
+        ("refilled"). The candidates come in any order (a path var's, a
+        facet order's) and leave uid-ascending."""
+        from dgraph_tpu import native
+
+        budget = len(uids) // _ORDER_WALK_IDS_PER_BUCKET
+        if not budget:
+            return "over_budget", uids, 0
+        cands = np.unique(uids)  # native.intersect takes sorted, unique ids
+        walk = self._index_bucket_stream(o.attr, tk)
+        if o.desc:
+            # the stores iterate forwards only: a descending walk lists
+            # the keys it may read first, and an attr that has more of
+            # them than the budget is not walked
+            listed = list(itertools.islice(walk, budget + 1))
+            if len(listed) > budget:
+                return "over_budget", uids, 0
+            walk = reversed(listed)
+        kept: List[np.ndarray] = []
+        have = read = 0
+        for bk in walk:
+            if read >= budget:
+                return "over_budget", uids, read
+            read += 1
+            sel = native.intersect(self.cache.uids(bk), cands)
+            if len(sel):
+                kept.append(sel)
+                have += len(sel)
+                if have >= need:
+                    return "narrowed", np.unique(np.concatenate(kept)), read
+        return "refilled", uids, read
+
+    def _index_bucket_stream(self, attr: str, tk):
+        """Keys of `attr`'s index buckets under one tokenizer, ascending:
+        the store's at this read timestamp, and the buckets only this
+        transaction's uncommitted writes have made."""
+        prefix = keys.IndexKey(attr, bytes([tk.identifier]), self.ns)
+        stored = (
+            k for k, _, _ in self.cache.kv.iterate(prefix, self.cache.read_ts)
+        )
+        own = sorted(k for k in self.cache.deltas if k.startswith(prefix))
+        if not own:
+            return stored
+        return (k for k, _ in itertools.groupby(heapq.merge(stored, own)))
 
     def _order_uids_generic(self, gq: GraphQuery, uids: np.ndarray) -> np.ndarray:
         if not len(uids) or not gq.order:

@@ -2696,6 +2696,38 @@ declare_metric(
     "that stopped hitting.",
 )
 declare_metric(
+    "counter", "order_window_total{path=\"*\"}",
+    "Ordered blocks, and rows of an ordered child level, that carry two "
+    "or more order keys and `first`, by what the executor's window walk "
+    "did with them (query/subgraph.py _order_uids_window): `narrowed` "
+    "(only the ids of the leading key's first index buckets went to the "
+    "comparator), `generic` (the walk does not apply: the set fits the "
+    "window, `after`, a negative `first`, @cascade above, or a leading "
+    "key that is a val(..), language-tagged, @lang, a list, a datetime "
+    "or without a sortable index), `refilled` (the buckets ran out "
+    "before the window was full, so ids without a leading value count) "
+    "and `over_budget` (the walk read len(ids)/8 buckets without "
+    "filling the window, or a descending walk found more buckets than "
+    "that to list); the last three sort every candidate.",
+)
+declare_metric(
+    "counter", "order_candidates_total",
+    "Ids that the blocks and rows counted in order_window_total were "
+    "asked to order.",
+)
+declare_metric(
+    "counter", "order_kept_total",
+    "Ids those blocks and rows handed to the multi-key comparator "
+    "(one value read per id and key). Over order_candidates_total: "
+    "the share of the sort's work the window walk left.",
+)
+declare_metric(
+    "counter", "order_buckets_total",
+    "Index buckets the window walk read and intersected with the "
+    "candidates, the wasted reads of `over_budget` and `refilled` "
+    "walks included.",
+)
+declare_metric(
     "counter", "digest_evicted_total",
     "Digest-store rows evicted past DGRAPH_TPU_DIGEST_SHAPES and "
     "folded into the sticky per-namespace `other` bucket "
