@@ -1,7 +1,8 @@
 """The vector corpus: a mixture of 256 gaussians (cluster centres at 4
-sigma, unit noise — the corpus bench.py and chip_smoke.py build, since
-real embedding sets cluster and there is no network to fetch one), with
-its plain reference, exact nearest neighbours in float64.
+sigma, unit noise: real embedding sets cluster and there is no network
+to fetch one), with its plain reference, exact nearest neighbours in
+float64. It is the benchmark's own generator; chip_smoke.py builds a
+corpus of the same kind for its smoke run and shares no code with it.
 
 The rows are drawn in chunks of 65,536, each from a generator of its own
 keyed by (seed, chunk), in float32 and in threads: the same seed gives

@@ -1,7 +1,9 @@
 """LDBC-SNB data at scale factor 1's person and `knows` counts, with its
-plain model. A copy of benchmarks/ldbc_corpus.py (schema, attribute
-widths, creationDate facets, uid and id numbering unchanged) with two
-changes: the `knows` degree draw and the counts.
+plain model: the benchmark's own generator (schema, attribute widths,
+creationDate facets, uid and id numbering as upstream dgraph's
+systest/ldbc has them; it began as a copy of benchmarks/ldbc_corpus.py,
+which tier-1's tests still use, and has since its own degree draw, its
+own counts and the model's message side).
 
 Degrees: SF1 has 9,892 persons and 180,623 friendship pairs (mean degree
 36.5) with a heavy tail. The `knows` graph here has ONE structure for
@@ -12,7 +14,11 @@ configuration model over popularity ranks from a fixed generator
 rank r, so every seed serves the same graph under other names — the same
 sizes in another order — and a run's work does not depend on the seed
 beyond which requests its clients happen to draw. Everything else
-(attributes, facet dates, messages, forums) is drawn from the seed.
+(attributes, facet dates, messages, forums) is drawn from the seed, by
+ONE generator in ONE order: `person_columns`, then `message_draws`. The
+n-quads writer and the model both read those two, so what the store
+holds and what the model says cannot drift apart; the n-quads of a
+(configuration, seed) are pinned byte for byte by a test.
 
 Nothing of the program is imported here: `Model` is the plain reference.
 """
@@ -75,11 +81,38 @@ def person_sid(index):
     return 933 + index * 7
 
 
+def message_uid(n: int, index):
+    """uid of message `index` among `n` persons: the posts follow the
+    persons, the comments the posts (a message index counts posts
+    first, then comments in the order written)."""
+    return person_uid(n) + index
+
+
+def post_sid(j):
+    return 3 + j * 11
+
+
+def comment_sid(i):
+    """`i` counts comments alone (message index less the posts)."""
+    return 1099511627777 + i * 3
+
+
+def forum_sid(f):
+    return f
+
+
 def _dt(ms_epoch: int) -> str:
     """RFC3339 with millis, the SNB creationDate shape."""
     d = datetime.datetime.fromtimestamp(ms_epoch / 1000.0,
                                         datetime.timezone.utc)
     return d.strftime("%Y-%m-%dT%H:%M:%S.") + f"{ms_epoch % 1000:03d}Z"
+
+
+def epoch_ms(text: str) -> int:
+    """An RFC3339 time as epoch milliseconds, `_dt`'s inverse: the served
+    text and the n-quads' differ in how many digits the fraction has."""
+    t = datetime.datetime.fromisoformat(text.replace("Z", "+00:00"))
+    return round(t.timestamp() * 1000)
 
 
 def degree_sequence(n: int, pairs: int, sigma: float, cap: int) -> np.ndarray:
@@ -136,13 +169,134 @@ def person_row(i: int, cols) -> dict:
             "browserUsed": _BROWSERS[br], "place": pl}
 
 
-class Model:
-    """The plain reference: adjacency over person indices."""
+def message_draws(rng, n: int, n_pairs: int, sizes: dict) -> dict:
+    """Everything the seed draws after the persons' attributes, in the
+    one order the generator is read in (`rng` is `person_columns`'s,
+    handed on): friendship dates; the posts' creator, topic, has-content
+    (0 of 4: none), has-no-image (0 of 3: an image) and creation offset;
+    the comments' parent draw in [0, 1), THEN their creator, subject and
+    gap after the parent; the forums' moderators. A change of order or
+    of a bound here changes every store: the pinned n-quads say so."""
 
-    def __init__(self, n: int, pairs: np.ndarray, seed: int):
-        self.n, self.seed = n, seed
+    def ints(hi, count):
+        return rng.integers(0, hi, count)
+
+    n_posts, n_comments = sizes["posts"], sizes["comments"]
+    return {
+        "knows_ms": BASE_MS + ints(60_000_000_000, n_pairs),
+        "post_creator": ints(n, n_posts),
+        "post_topic": ints(500, n_posts),
+        "post_has_content": ints(4, n_posts),
+        "post_no_image": ints(3, n_posts),
+        "post_ms": BASE_MS + ints(70_000_000_000, n_posts),
+        "comment_u01": rng.random(n_comments),
+        "comment_creator": ints(n, n_comments),
+        "comment_about": ints(100, n_comments),
+        "comment_gap": ints(5_000_000_000, n_comments),
+        "moderator": ints(n, sizes["forums"]),
+    }
+
+
+def _csr(owner: np.ndarray, owners: int):
+    """(members sorted by owner then index, starts): owner o's members
+    are members[starts[o]:starts[o + 1]], ascending."""
+    order = np.argsort(owner, kind="stable")
+    return order, np.searchsorted(owner[order], np.arange(owners + 1))
+
+
+class Messages:
+    """The message side of the plain model: numpy columns over message
+    index (posts first, then comments in the order written) and over
+    forum index, as the n-quads carry them."""
+
+    def __init__(self, n: int, draws: dict):
+        self.n = n
+        self.n_posts = len(draws["post_creator"])
+        self.n_comments = len(draws["comment_creator"])
+        self.n_forums = len(draws["moderator"])
+        self.creator = np.concatenate(
+            [draws["post_creator"], draws["comment_creator"]])
+        # a comment replies to a post or to an EARLIER comment, drawn
+        # uniformly over the messages written before it, and is created
+        # 1 s plus its gap after that message
+        count = self.n_posts + np.arange(self.n_comments)
+        reply_to = (draws["comment_u01"] * count).astype(np.int64)
+        ms = draws["post_ms"].tolist()
+        for t, gap in zip(reply_to.tolist(), draws["comment_gap"].tolist()):
+            ms.append(ms[t] + 1000 + gap)
+        self.ms = np.array(ms, np.int64)  # creationDate, epoch ms
+        self.parent = np.concatenate(
+            [np.full(self.n_posts, -1, np.int64), reply_to])
+        self.moderator = draws["moderator"]
+        self._draws = draws
+        self._by_creator = _csr(self.creator, n)
+        self._replies = _csr(self.parent + 1, len(self.creator) + 1)
+
+    def __len__(self) -> int:
+        return len(self.creator)
+
+    def is_post(self, i: int) -> bool:
+        return i < self.n_posts
+
+    def uid(self, i):
+        return message_uid(self.n, i)
+
+    def sid(self, i: int) -> int:
+        """The message's `id`; its `fqid` is `post_<id>` or
+        `comment_<id>`."""
+        return (post_sid(i) if i < self.n_posts
+                else comment_sid(i - self.n_posts))
+
+    def fqid(self, i: int) -> str:
+        return f"{'post' if i < self.n_posts else 'comment'}_{self.sid(i)}"
+
+    def text(self, i: int) -> dict:
+        """The message's text predicates, those it has: a post's
+        `content` (3 of 4) and `imageFile` (1 of 3), a comment's
+        `content`."""
+        if i >= self.n_posts:
+            c = i - self.n_posts
+            return {"content":
+                    f"reply {c} about {self._draws['comment_about'][c]}"}
+        out = {}
+        if self._draws["post_has_content"][i]:
+            out["content"] = (f"About topic {self._draws['post_topic'][i]}, "
+                              f"opinion {i}")
+        if not self._draws["post_no_image"][i]:
+            out["imageFile"] = f"photo{post_sid(i)}.jpg"
+        return out
+
+    def by_creator(self, p: int) -> np.ndarray:
+        """Message indices person `p` created, ascending."""
+        order, starts = self._by_creator
+        return order[starts[p]:starts[p + 1]]
+
+    def replies(self, i: int) -> np.ndarray:
+        """Indices of the comments that reply to message `i`, ascending."""
+        order, starts = self._replies
+        return order[starts[i + 1]:starts[i + 2]]
+
+    def forum_uid(self, f):
+        return message_uid(self.n, len(self.creator) + f)
+
+    def forum_title(self, f: int) -> str:
+        return f"Wall of person_{person_sid(int(self.moderator[f]))}"
+
+    def forum_of_post(self, j):
+        """The forum whose `containerOf` holds post `j`."""
+        return j % self.n_forums
+
+
+class Model:
+    """The plain reference: adjacency over person indices, and behind
+    `messages()` the posts, comments and forums. `sizes` are those in
+    force (a rehearsal's, where one runs); a model made without them
+    (a control's cut-down copy) has no message side."""
+
+    def __init__(self, n: int, pairs: np.ndarray, seed: int, sizes=None):
+        self.n, self.seed, self.sizes = n, seed, sizes
         self.pairs = pairs  # (m, 2) person indices, a < b, unique
-        self._cols = None
+        self._cols = self._draws = self._messages = None
         both = np.concatenate([pairs, pairs[:, ::-1]])
         order = np.lexsort((both[:, 1], both[:, 0]))
         both = both[order]
@@ -159,6 +313,28 @@ class Model:
         if self._cols is None:
             self._cols = person_columns(self.n, self.seed)[1]
         return self._cols
+
+    def _drawn(self) -> dict:
+        if self._draws is None:
+            if self.sizes is None:
+                raise ValueError("a model made without sizes has no "
+                                 "friendship dates and no messages")
+            rng, self._cols = person_columns(self.n, self.seed)
+            self._draws = message_draws(rng, self.n, len(self.pairs),
+                                        self.sizes)
+        return self._draws
+
+    @property
+    def knows_ms(self) -> np.ndarray:
+        """The `knows|creationDate` facet of each of `pairs`, epoch ms."""
+        return self._drawn()["knows_ms"]
+
+    def messages(self) -> Messages:
+        """Built on the first call and kept: a cell that never asks
+        pays nothing for it."""
+        if self._messages is None:
+            self._messages = Messages(self.n, self._drawn())
+        return self._messages
 
     def person(self, i: int) -> dict:
         return person_row(int(i), self.columns())
@@ -198,22 +374,16 @@ def make(config: dict, seed: int, rdf_path=None) -> Model:
     sizes, assumed = config["sizes"], config["assumed"]
     n = sizes["persons"]
     pairs = knows_pairs(n, sizes, assumed, seed)
-    model = Model(n, pairs, seed)
+    model = Model(n, pairs, seed, sizes)
     if rdf_path is not None:
-        model.nquads = _write_rdf(rdf_path, model, sizes, seed)
+        model.nquads = _write_rdf(rdf_path, model)
     return model
 
 
-def _write_rdf(path: str, model: Model, sizes: dict, seed: int) -> int:
-    rng, cols = person_columns(model.n, seed)
+def _write_rdf(path: str, model: Model) -> int:
     n = model.n
+    msgs = model.messages()
     out = []
-    uid = UID0
-
-    def nu() -> int:
-        nonlocal uid
-        uid += 1
-        return uid
 
     def emit(s, p, o, facet=None):
         out.append(f"<0x{s:x}> <{p}> {o} " + (f"({facet}) ." if facet else "."))
@@ -222,20 +392,29 @@ def _write_rdf(path: str, model: Model, sizes: dict, seed: int) -> int:
         e = v.replace("\\", "\\\\").replace('"', '\\"')
         return f'"{e}"'
 
-    def ints(hi, count):
-        return rng.integers(0, hi, count).tolist()
+    ms, creator = msgs.ms.tolist(), msgs.creator.tolist()
 
-    place_uids = []
+    def message(i, kind):
+        """What a post and a comment share, in the order written."""
+        mu = msgs.uid(i)
+        emit(mu, "fqid", lit(msgs.fqid(i)))
+        emit(mu, "id", f'"{msgs.sid(i)}"^^<xs:int>')
+        for attr, text in msgs.text(i).items():
+            emit(mu, attr, lit(text))
+        emit(mu, "creationDate", f'"{_dt(ms[i])}"^^<xs:dateTime>')
+        emit(mu, "dgraph.type", lit(kind))
+        emit(mu, "hasCreator", f"<0x{person_uid(creator[i]):x}>")
+        return mu
+
+    place_uids = [UID0 + 1 + i for i in range(N_PLACES)]
     for i, name in enumerate(_PLACES):
-        pu = nu()
-        place_uids.append(pu)
-        emit(pu, "name", lit(name))
-        emit(pu, "id", f'"{200 + i}"^^<xs:int>')
-        emit(pu, "dgraph.type", lit("place"))
+        emit(place_uids[i], "name", lit(name))
+        emit(place_uids[i], "id", f'"{200 + i}"^^<xs:int>')
+        emit(place_uids[i], "dgraph.type", lit("place"))
 
     for i in range(n):
-        pu = nu()
-        row = person_row(i, cols)
+        pu = person_uid(i)
+        row = model.person(i)
         emit(pu, "fqid", lit(f"person_{row['id']}"))
         emit(pu, "id", f'"{row["id"]}"^^<xs:int>')
         for attr in ("firstName", "lastName", "gender"):
@@ -246,65 +425,29 @@ def _write_rdf(path: str, model: Model, sizes: dict, seed: int) -> int:
             emit(pu, attr, lit(row[attr]))
         emit(pu, "dgraph.type", lit("person"))
         emit(pu, "isLocatedIn", f"<0x{place_uids[row['place']]:x}>")
-    assert uid == person_uid(n - 1)
 
-    for (a, b), ms in zip(model.pairs.tolist(),
-                          ints(60_000_000_000, len(model.pairs))):
-        facet = f'creationDate="{_dt(BASE_MS + ms)}"^^<xs:dateTime>'
+    for (a, b), at in zip(model.pairs.tolist(), model.knows_ms.tolist()):
+        facet = f'creationDate="{_dt(at)}"^^<xs:dateTime>'
         ua, ub = person_uid(a), person_uid(b)
         emit(ua, "knows", f"<0x{ub:x}>", facet)
         emit(ub, "knows", f"<0x{ua:x}>", facet)
 
-    n_posts, n_comments = sizes["posts"], sizes["comments"]
-    post_uids, post_ms = [], []
-    for i, (cr, topic, has_c, has_i, ms) in enumerate(zip(
-            ints(n, n_posts), ints(500, n_posts), ints(4, n_posts),
-            ints(3, n_posts), ints(70_000_000_000, n_posts))):
-        mu = nu()
-        sid = 3 + i * 11
-        post_uids.append(mu)
-        post_ms.append(BASE_MS + ms)
-        emit(mu, "fqid", lit(f"post_{sid}"))
-        emit(mu, "id", f'"{sid}"^^<xs:int>')
-        if has_c:
-            emit(mu, "content", lit(f"About topic {topic}, opinion {i}"))
-        if not has_i:
-            emit(mu, "imageFile", lit(f"photo{sid}.jpg"))
-        emit(mu, "creationDate", f'"{_dt(post_ms[-1])}"^^<xs:dateTime>')
-        emit(mu, "dgraph.type", lit("post"))
-        emit(mu, "hasCreator", f"<0x{person_uid(cr):x}>")
+    for i in range(msgs.n_posts):
+        message(i, "post")
+    for i, t in enumerate(msgs.parent[msgs.n_posts:].tolist(), msgs.n_posts):
+        mu = message(i, "comment")
+        emit(mu, "replyOf", f"<0x{msgs.uid(t):x}>")
 
-    msg_uids, msg_ms = list(post_uids), list(post_ms)
-    u01 = rng.random(n_comments).tolist()
-    for i, (cr, about, gap) in enumerate(zip(
-            ints(n, n_comments), ints(100, n_comments),
-            ints(5_000_000_000, n_comments))):
-        mu = nu()
-        sid = 1099511627777 + i * 3
-        t = int(u01[i] * len(msg_uids))  # a post or an earlier comment
-        ms = msg_ms[t] + 1000 + gap
-        emit(mu, "fqid", lit(f"comment_{sid}"))
-        emit(mu, "id", f'"{sid}"^^<xs:int>')
-        emit(mu, "content", lit(f"reply {i} about {about}"))
-        emit(mu, "creationDate", f'"{_dt(ms)}"^^<xs:dateTime>')
-        emit(mu, "dgraph.type", lit("comment"))
-        emit(mu, "hasCreator", f"<0x{person_uid(cr):x}>")
-        emit(mu, "replyOf", f"<0x{msg_uids[t]:x}>")
-        msg_uids.append(mu)
-        msg_ms.append(ms)
-
-    n_forums = sizes["forums"]
-    forum_uids = []
-    for i, mod in enumerate(ints(n, n_forums)):
-        fu = nu()
-        forum_uids.append(fu)
-        emit(fu, "fqid", lit(f"forum_{i}"))
-        emit(fu, "id", f'"{i}"^^<xs:int>')
-        emit(fu, "title", lit(f"Wall of person_{person_sid(mod)}"))
+    for f, mod in enumerate(msgs.moderator.tolist()):
+        fu = msgs.forum_uid(f)
+        emit(fu, "fqid", lit(f"forum_{forum_sid(f)}"))
+        emit(fu, "id", f'"{forum_sid(f)}"^^<xs:int>')
+        emit(fu, "title", lit(msgs.forum_title(f)))
         emit(fu, "dgraph.type", lit("forum"))
         emit(fu, "hasModerator", f"<0x{person_uid(mod):x}>")
-    for j, mu in enumerate(post_uids):
-        emit(forum_uids[j % n_forums], "containerOf", f"<0x{mu:x}>")
+    for j in range(msgs.n_posts):
+        emit(msgs.forum_uid(msgs.forum_of_post(j)), "containerOf",
+             f"<0x{msgs.uid(j):x}>")
 
     with open(path, "w") as f:
         f.write("\n".join(out))

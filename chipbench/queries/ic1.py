@@ -15,8 +15,6 @@ among them, and the first name uniformly among the generator's."""
 
 from __future__ import annotations
 
-import datetime
-
 import numpy as np
 
 from chipbench.data import snb
@@ -27,15 +25,8 @@ DATES = ("birthday", "creationDate")
 PROFILE = "{ " + " ".join(FIELDS) + " isLocatedIn { name } }"
 
 
-def _ms(text: str) -> int:
-    """An RFC3339 time as epoch milliseconds: the served text and the
-    n-quads' differ in how many digits the fraction has."""
-    t = datetime.datetime.fromisoformat(text.replace("Z", "+00:00"))
-    return round(t.timestamp() * 1000)
-
-
 def _row(d: int, person: dict, place: str) -> tuple:
-    return (d, *(_ms(person[f]) if f in DATES else person[f]
+    return (d, *(snb.epoch_ms(person[f]) if f in DATES else person[f]
                  for f in FIELDS), place)
 
 

@@ -9,14 +9,19 @@ ends when the window's first request is sent. After the window closes
 the device's peak memory is read, the alpha is stopped, and the answers
 the window produced are compared with the plain reference.
 
-Everything that belongs to one configuration, mix, query kind or layer
-metric is a file of its own, found by the name in BENCHMARK.json:
-configs/<config>.json, data/<maker>.py, mixes/<traffic>.json,
-queries/<kind>.py, layer_metrics/<metric>.py.
+Everything that belongs to one configuration, mix, query kind, layer
+metric or planted fault is a file of its own, found by the name in
+BENCHMARK.json or in the file that names it: configs/<config>.json,
+data/<maker>.py, mixes/<traffic>.json, queries/<kind>.py,
+layer_metrics/<metric>.py, faults/<fault>.py. A key of a configuration's
+or a mix's file that nothing here reads (a mix's `fault`, which the
+tests read) is passed over.
 
 Without a TPU it exits non-zero and prints no result. `--rehearsal` runs
-the same code at the configuration's tiny `rehearsal` sizes on whatever
-platform jax has; its output says so and is never a result.
+the same code at the configuration's tiny `rehearsal` sizes, under its
+`rehearsal_env` (what the CPU needs to take the jitted paths; a variable
+the caller has set stands), on whatever platform jax has; its output
+says so and is never a result.
 """
 
 from __future__ import annotations
@@ -66,6 +71,24 @@ def find_cell(bench: dict, name: str):
     config = load_json(os.path.join(ROOT, entry["file"]))
     mix = load_json(os.path.join(HERE, "mixes", cell["traffic"] + ".json"))
     return cell, config, mix
+
+
+def load_cell(args):
+    """(BENCHMARK.json, cell, configuration, mix) of `args.workload`;
+    under `--rehearsal` the configuration is the rehearsed one."""
+    bench = load_json(os.path.join(ROOT, "BENCHMARK.json"))
+    cell, config, mix = find_cell(bench, args.workload)
+    return bench, cell, (rehearsed(config) if args.rehearsal
+                         else config), mix
+
+
+def rehearsed(config: dict) -> dict:
+    """The configuration at its rehearsal sizes, with its rehearsal
+    environment put into this process's (the program reads its knobs
+    live, and the load generator inherits them)."""
+    for name, value in config.get("rehearsal_env", {}).items():
+        os.environ.setdefault(name, value)
+    return dict(config, sizes=dict(config["sizes"], **config["rehearsal"]))
 
 
 def metrics_of(bench: dict, group: str, cell: str) -> list:
@@ -253,7 +276,7 @@ def traced(child: Child, seconds: float, mix: dict, out_path: str):
     return reply, {"dir": tdir, "start": t_a, "stop": t_b}
 
 
-def main(argv=None) -> int:
+def parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, required=True)
@@ -262,9 +285,11 @@ def main(argv=None) -> int:
     ap.add_argument("--rehearsal", action="store_true",
                     help="tiny sizes on whatever platform jax has; never a "
                     "result")
-    args = ap.parse_args(argv)
-    result = run(args)
-    print(json.dumps(result))
+    return ap
+
+
+def main(argv=None) -> int:
+    print(json.dumps(run(parser().parse_args(argv))))
     return 0
 
 
@@ -273,11 +298,7 @@ def run(args, after=None) -> dict:
     what the comparison had in hand — model, config, mix, sample and the
     program's numbers — and what it returns goes into the result under
     "after"."""
-    bench = load_json(os.path.join(ROOT, "BENCHMARK.json"))
-    cell, config, mix = find_cell(bench, args.workload)
-    if args.rehearsal:
-        config = dict(config, sizes=dict(config["sizes"],
-                                         **config["rehearsal"]))
+    bench, cell, config, mix = load_cell(args)
 
     # the device first, before any data is built. dgraph_tpu before jax:
     # the package places the persistent compile cache in the checkout
@@ -286,7 +307,7 @@ def run(args, after=None) -> dict:
     import jax
 
     from chipbench import alpha as alpha_mod
-    from chipbench import trace_reduce
+    from chipbench import span_reduce, trace_reduce
 
     devs = jax.devices()
     device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
@@ -364,7 +385,10 @@ def run(args, after=None) -> dict:
     metrics = {}
     ctx = None
     if args.trace:
-        reduced = trace_reduce.reduce_dir(trace["dir"])
+        planes = span_reduce.read_planes(trace["dir"])
+        reduced = trace_reduce.reduce_planes(span_reduce.bare(planes))
+        by_span = span_reduce.reduce_planes(planes)["by_span"]
+        del planes
         say(f"trace: busy {reduced['busy_s']:.4f}s on "
             f"{reduced['device_planes']} device plane(s); planes "
             f"{[p for p in reduced['planes'] if 'device' in p[0]]}")
@@ -415,9 +439,11 @@ def run(args, after=None) -> dict:
                   checks.get("wrong_answers", {"value": 0})["value"]),
               "metrics": metrics, "device": device}
     if ctx is not None:
+        # the idle gaps by the span the launching thread was in
+        # (`span_reduce`), largest first: the only trace the ledger keeps
         result["breakdown"] = {
             "device_ops": ctx["trace"]["top_ops"][:10],
-            "idle_gaps": ctx["trace"]["top_gaps"][:10],
+            "idle_gaps": [[n, s] for n, s in by_span.items()][:10],
         }
     result["rehearsal"] = bool(args.rehearsal)
     result["setup"] = {"install": install, "warm": warm,

@@ -2,25 +2,33 @@
 
 The program's spans are events on the host plane of the profiler's trace
 (`dgraph_tpu.utils.observe.Tracer.span` opens a `TraceAnnotation` beside
-each span), on the clock of the device plane's "XLA Ops" line. For every
-idle gap on a device plane (the time between two merged busy intervals,
-as `trace_reduce` takes them) this reduction finds the host thread whose
-`*.launch` span enqueued the program that ended the gap (the launch
-span, on any thread, that started last before the device did), and
-splits the gap's time over that thread's innermost program spans by
-overlap: `encode`, `http.reply`, `http.read`, `parse`, `level_task`,
-`setop.pad`, ...; what no span covers goes under `no_span` (between two
-requests of a connection: the client's own time, the request line and
-the headers), a gap no launch span precedes under `no_launch`, and a
-gap between two operations of one program under `within_program`.
+each span), nominally on the clock of the device plane's "XLA Ops" line.
+For every idle gap on a device plane (the time between two merged busy
+intervals, as `trace_reduce` takes them) this reduction finds the host
+thread whose `*.launch` span enqueued the program that ended the gap
+(the k-th launch enqueued the k-th program, `order_offset`; where a
+trace shows no such order, the launch span, on any thread, that started
+last before the device did), and splits the gap's time over that
+thread's innermost program spans by overlap: `encode`, `http.reply`,
+`http.read`, `parse`, `level_task`, `setop.pad`, ... A span is whatever the program emits as one: every
+`Tracer.span` event carries its trace id, so the span names of a trace
+are the names of its host events that carry an id, and a span a later PR
+adds is named here with no edit (`SPANS` is only the fallback for a
+recorded trace that kept no ids). What no span covers goes under
+`no_span` (between two requests of a connection: the client's own time,
+the request line and the headers), a gap no launch span precedes under
+`no_launch`, and a gap between two operations of one program under
+`within_program`.
 
   python3 -m chipbench.span_reduce --workload <cell> --seed <n> --seconds <s>
 
 takes one short traced window of a cell with `run.py`'s own pieces,
 keeps the trace until both reductions have read it, and prints both,
-with the program's request records of the traced stretch, its `device_*`
-counters over the window per request, and the seconds of its set-up
-phases beside them. It is a builder's tool: `run.py` does not call it.
+with the program's request records of the traced stretch, every counter
+and gauge of the program that moved over the window, per request, and
+the seconds of its set-up phases beside them. It is a builder's tool;
+`run.py` takes `reduce_planes`'s `by_span` for its `breakdown.idle_gaps`
+and no metric from here.
 
   python3 -m chipbench.span_reduce <dir, .xplane.pb or planes .json>
 
@@ -49,7 +57,8 @@ import time
 
 from chipbench import trace_reduce
 
-# the spans `utils/observe.py`'s call sites open on the served paths
+# the spans `utils/observe.py`'s call sites opened on the served paths at
+# PR 32: read only where a trace carries no trace id (a recorded file)
 SPANS = frozenset((
     "http.request", "http.read", "http.reply", "query", "parse", "admit",
     "process", "encode", "level_task", "commit",
@@ -111,15 +120,74 @@ def overlaps(segments, starts, lo, hi):
         i += 1
 
 
+def bare(planes) -> list:
+    """The planes in `trace_reduce`'s form: every event without its id."""
+    return [(p, [(ln, [ev[:3] for ev in evs]) for ln, evs in lines])
+            for p, lines in planes]
+
+
+def span_names(planes) -> frozenset:
+    """The names of the host events that carry a trace id; `SPANS`
+    where none does."""
+    return frozenset(
+        ev[0] for pname, lines in planes
+        if not trace_reduce.is_device_plane(pname)
+        for _, events in lines for ev in events
+        if len(ev) > 3 and ev[3]) or SPANS
+
+
+ORDER_REACH, ORDER_LEAST = 32, 16  # offsets tried; pairs an offset needs
+
+
+def order_offset(launch_starts, module_starts):
+    """(k, early_ns): the device's program i + k is the one launch i
+    enqueued, and the device's clock reads at least `early_ns` before
+    the host's; (None, 0) where the trace shows no such k. One stream
+    runs its programs in the order they were launched, so the two sorted
+    lists are one sequence seen twice, shifted by the programs whose
+    launch the trace did not catch; at the right shift the distance from
+    launch to program is the dispatch latency, all but constant, and at
+    a wrong one it swings with the time between requests. The shift
+    whose distances spread least (quartiles) is taken where it wins
+    clearly, by half. It needs no common clock: the host's and the
+    device's timestamps have been seen a millisecond apart in one trace
+    and not in the next (PERF.md), more than a short program's dispatch
+    takes. No program starts before its launch began: where the lowest
+    tenth of the distances is negative, the clocks differ by that much
+    or more."""
+    spreads = []
+    for k in range(-ORDER_REACH, ORDER_REACH + 1):
+        lo = max(0, -k)
+        hi = min(len(launch_starts), len(module_starts) - k)
+        if hi - lo < ORDER_LEAST:
+            continue
+        q = statistics.quantiles(
+            [module_starts[i + k] - launch_starts[i] for i in range(lo, hi)],
+            n=4)
+        spreads.append((q[2] - q[0], k))
+    spreads.sort()
+    if not spreads or (len(spreads) > 1
+                       and 2 * spreads[0][0] > spreads[1][0]):
+        return None, 0
+    k = spreads[0][1]
+    lowest = statistics.quantiles(
+        [module_starts[i + k] - launch_starts[i]
+         for i in range(max(0, -k),
+                        min(len(launch_starts), len(module_starts) - k))],
+        n=10)[0]
+    return k, max(0, -lowest)
+
+
 def reduce_planes(planes) -> dict:
     threads = {}  # (plane, line) -> [(name, start, end)]
     launches = []  # (start, thread)
     with_id = 0
+    names = span_names(planes)
     for pname, lines in planes:
         if trace_reduce.is_device_plane(pname):
             continue
         for li, (lname, events) in enumerate(lines):
-            mine = [ev for ev in events if ev[0] in SPANS]
+            mine = [ev for ev in events if ev[0] in names]
             if not mine:
                 continue
             key = (pname, li, lname)  # thread names can repeat
@@ -155,6 +223,7 @@ def reduce_planes(planes) -> dict:
         modules = sorted((ev[1], ev[1] + ev[2])
                          for ev in by_line.get(trace_reduce.MODULES_LINE, []))
         module_starts = [m[0] for m in modules]
+        shift, early = order_offset(launch_starts, module_starts)
         for (_, e0), (s1, _) in zip(merged, merged[1:]):
             gaps += 1
             m = bisect.bisect_right(module_starts, s1) - 1
@@ -162,14 +231,20 @@ def reduce_planes(planes) -> dict:
                 # between two ops of one program: not the host's doing
                 by_span["within_program"] += (s1 - e0) / 1e9
                 continue
-            i = bisect.bisect_right(launch_starts, s1) - 1
+            # the launch that enqueued the program: by order where the
+            # trace shows one, else the last that began before the device
+            if shift is not None and m >= 0 and s1 < modules[m][1]:
+                i = m - shift if m - shift < len(launches) else -1
+            else:
+                i = bisect.bisect_right(launch_starts, s1) - 1
             if i < 0:
                 by_span["no_launch"] += (s1 - e0) / 1e9
                 continue
             start, key = launches[i]
-            lags.append(s1 - start)
+            lags.append(s1 + early - start)
             left = s1 - e0
-            for name, ns in overlaps(flat[key], flat_starts[key], e0, s1):
+            for name, ns in overlaps(flat[key], flat_starts[key],
+                                     e0 + early, s1 + early):
                 by_span[name] += ns / 1e9
                 left -= ns
             by_span["no_span"] += left / 1e9
@@ -192,16 +267,18 @@ def reduce_planes(planes) -> dict:
 def read_xplane(path: str) -> list:
     from jax.profiler import ProfileData
 
-    def event(ev):
-        if ev.name not in SPANS:
-            return (ev.name, ev.start_ns, ev.duration_ns)
-        # the converter reads an all-digit hex id as a number
-        tid = dict(ev.stats).get("trace_id", "")
-        return (ev.name, ev.start_ns, ev.duration_ns, str(tid))
+    def bare_event(ev):
+        return (ev.name, ev.start_ns, ev.duration_ns)
 
-    return [(p.name, [(ln.name, [event(ev) for ev in ln.events])
-                      for ln in p.lines])
-            for p in ProfileData.from_file(path).planes]
+    def host_event(ev):
+        # the converter reads an all-digit hex id as a number
+        tid = dict(ev.stats).get("trace_id")
+        return bare_event(ev) if tid is None else (*bare_event(ev), str(tid))
+
+    return [(p.name, [(ln.name, [
+        (bare_event if trace_reduce.is_device_plane(p.name)
+         else host_event)(ev) for ev in ln.events]) for ln in p.lines])
+        for p in ProfileData.from_file(path).planes]
 
 
 def read_planes(path: str) -> list:
@@ -257,11 +334,7 @@ def window(args) -> dict:
     trace kept until both reductions have read it."""
     from chipbench import run
 
-    bench = run.load_json(os.path.join(run.ROOT, "BENCHMARK.json"))
-    _, config, mix = run.find_cell(bench, args.workload)
-    if args.rehearsal:
-        config = dict(config, sizes=dict(config["sizes"],
-                                         **config["rehearsal"]))
+    _, _, config, mix = run.load_cell(args)
     import dgraph_tpu  # noqa: F401  (places the compile cache, before jax)
     import jax
 
@@ -288,12 +361,12 @@ def window(args) -> dict:
         from dgraph_tpu.utils.observe import METRICS
 
         c0 = clock.compiles
-        m0 = METRICS.snapshot("device_")
+        m0 = METRICS.snapshot("")
         cpu0 = time.process_time()
         reply, trace = run.traced(child, args.seconds, mix,
                                   os.path.join(tmp, "records.pkl"))
         alpha_cpu_s = time.process_time() - cpu0
-        m1 = METRICS.snapshot("device_")
+        m1 = METRICS.snapshot("")
         planes = read_planes(trace["dir"])
         out = {
             "rehearsal": bool(args.rehearsal),
@@ -303,17 +376,16 @@ def window(args) -> dict:
             "alpha_cpu_s": alpha_cpu_s,
             "compiles_in_window": clock.compiles - c0,
             "slow_queries_total": METRICS.value("slow_queries_total"),
-            # the program's own counters over the whole window, exact
-            # where the fine spans ride in one tree per 50 ms
+            # every counter and gauge of the program that moved over the
+            # whole window, exact where the fine spans ride in one tree
+            # per 50 ms
             "counters_per_req": {
                 k: (v - m0.get(k, 0)) / max(1, reply["requests"])
                 for k, v in sorted(m1.items()) if v != m0.get(k, 0)},
             "setup_phases_s": {
                 n: spans.phase_seconds(n) for n in sorted(SPANS)
                 if n.startswith("ivf.")},
-            "trace_reduce": trace_reduce.reduce_planes(
-                [(p, [(ln, [ev[:3] for ev in evs]) for ln, evs in lines])
-                 for p, lines in planes]),
+            "trace_reduce": trace_reduce.reduce_planes(bare(planes)),
             "span_reduce": reduce_planes(planes),
             "records": records_summary({"requests": reply["requests"]}),
         }

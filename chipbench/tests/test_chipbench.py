@@ -29,11 +29,15 @@ with open(os.path.join(ROOT, "BENCHMARK.json")) as _f:
 CELLS = [w["name"] for w in BENCH["workloads"]]
 NAME = re.compile(r"[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}")
 UNIT = re.compile(r"[A-Za-z0-9_/%.\-]{1,16}")
-# what each configuration needs on the CPU to take the jitted paths
-CPU_ENV = {"snb-sf1": {"DGRAPH_TPU_DEVICE_MIN_TOTAL": "32768"},
-           "vec-1m-768": {"DGRAPH_TPU_VEC_QUANT": "0"}}
-FAULT = {"snb-sf1": "setops", "vec-1m-768": "vector_search"}
 BIG_SEED = 2**31 + 11
+# sha256 of the n-quads `snb.make` writes for snb-sf1 at its rehearsal
+# sizes, by seed (the parent of PR 33 wrote the same bytes; at full sizes
+# seed 2**31 + 11 gives 1dc8cd9a41f2...: CHANGES.md, PR 33)
+NQUADS_SHA256 = {
+    5: "8bb02bb81ce8d973239c6b707e8424348f16ce21ea5e1b7e7edc37413a0170ca",
+    BIG_SEED:
+        "02b845933caae1e3b7da4fab9b10f3461a97c908834b8e58ceab251b8f2da287",
+}
 
 
 def cell(name):
@@ -52,12 +56,12 @@ def mix_of(name):
         return json.load(f)
 
 
-def drive(module, name, *args, cwd=ROOT, extra_path=()):
-    """Run `python -m <module> --workload <name> ...` on the CPU."""
+def drive(module, *args, cwd=ROOT, extra_path=()):
+    """Run `python -m <module> ...` on the CPU. What a configuration's
+    rehearsal needs in its environment is in its own file
+    (`rehearsal_env`), and `--rehearsal` puts it there."""
     env = dict(os.environ, JAX_PLATFORMS="cpu",
-               PYTHONPATH=os.pathsep.join([cwd, *extra_path]),
-               **CPU_ENV.get(cell(name)["config"], {})
-               if name in CELLS else {})
+               PYTHONPATH=os.pathsep.join([cwd, *extra_path]))
     env.pop("BENCH_RUN", None)
     return subprocess.run(
         [sys.executable, "-m", module, *args], cwd=cwd, env=env,
@@ -130,6 +134,10 @@ def test_every_piece_of_a_cell_is_found_by_name(name):
     config, mix = config_of(name), mix_of(name)
     maker = importlib.import_module(f"chipbench.data.{config['data']}")
     assert all(hasattr(maker, f) for f in ("make", "install", "catalog"))
+    assert all(isinstance(v, str) for v in config["rehearsal_env"].values())
+    assert set(config["rehearsal"]) <= set(config["sizes"])
+    fault = importlib.import_module(f"chipbench.faults.{mix['fault']}")
+    assert callable(fault.plant)
     for k in mix["kinds"]:
         kind = importlib.import_module(f"chipbench.queries.{k['kind']}")
         assert all(hasattr(kind, f)
@@ -147,7 +155,7 @@ def test_every_piece_of_a_cell_is_found_by_name(name):
 @pytest.mark.parametrize("name", CELLS)
 @pytest.mark.parametrize("trace", [0, 1])
 def test_rehearsal_runs_a_cell_end_to_end(name, trace):
-    out = last_json(drive("chipbench.run", name, "--workload", name,
+    out = last_json(drive("chipbench.run", "--workload", name,
                           "--seed", str(BIG_SEED), "--seconds", "3",
                           "--trace", str(trace), "--rehearsal"))
     assert {"correct", "attempted", "failed", "metrics", "device"} <= set(out)
@@ -163,6 +171,10 @@ def test_rehearsal_runs_a_cell_end_to_end(name, trace):
     if trace:
         assert {"busy_s", "window_s"} <= set(out["device"])
         assert "compiles_in_window" in out["metrics"]
+        # the configuration's `rehearsal_env` reached the program: the
+        # requests took the jitted paths
+        assert out["metrics"]["device_ops_per_req"]["value"] > 0
+        assert out["breakdown"]["idle_gaps"] == []  # no device plane here
         # no device plane on the CPU: the device readers return nothing
         assert "device_idle_share" not in out["metrics"]
     else:
@@ -171,7 +183,7 @@ def test_rehearsal_runs_a_cell_end_to_end(name, trace):
 
 
 def test_without_a_tpu_there_is_no_result():
-    proc = drive("chipbench.run", CELLS[0], "--workload", CELLS[0], "--seed",
+    proc = drive("chipbench.run", "--workload", CELLS[0], "--seed",
                  "1", "--seconds", "1", "--trace", "0")
     assert proc.returncode != 0 and proc.stdout.strip() == ""
 
@@ -180,7 +192,7 @@ def test_outside_the_repo_there_is_no_result(tmp_path):
     shutil.copytree(CHIPBENCH, tmp_path / "chipbench",
                     ignore=shutil.ignore_patterns(".store", "__pycache__"))
     shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), tmp_path)
-    proc = drive("chipbench.run", CELLS[0], "--workload", CELLS[0], "--seed",
+    proc = drive("chipbench.run", "--workload", CELLS[0], "--seed",
                  "1", "--seconds", "1", "--trace", "0", "--rehearsal",
                  cwd=str(tmp_path))
     assert proc.returncode != 0 and proc.stdout.strip() == ""
@@ -192,8 +204,7 @@ def test_outside_the_repo_there_is_no_result(tmp_path):
 @pytest.mark.parametrize("name", CELLS)
 def test_an_answer_altered_where_it_is_produced_is_not_correct(name):
     out = last_json(drive(
-        "chipbench.tests.faults", name, FAULT[cell(name)["config"]],
-        "--workload", name, "--seed", str(BIG_SEED), "--seconds", "3",
+        "chipbench.tests.faults", mix_of(name)["fault"], "--workload", name, "--seed", str(BIG_SEED), "--seconds", "3",
         "--trace", "0"))
     assert out["correct"] is False
     assert any(not _ok(c) for c in out["checks"].values())
@@ -204,9 +215,18 @@ def _ok(c):
     return value <= limit if op == "<=" else value >= limit
 
 
+def test_a_fault_that_is_no_file_fails_loudly():
+    """A mix names its fault; a name with no `chipbench/faults/<name>.py`
+    ends the run with no result and says which file is missing."""
+    proc = drive("chipbench.tests.faults", "no_such_fault", "--workload",
+                 CELLS[0], "--seed", "1", "--seconds", "1", "--trace", "0")
+    assert proc.returncode != 0 and proc.stdout.strip() == ""
+    assert "chipbench/faults/no_such_fault.py" in proc.stderr
+
+
 @pytest.mark.parametrize("name", CELLS)
 def test_the_control_comes_out_not_correct(name):
-    out = last_json(drive("chipbench.control", name, "--workload", name,
+    out = last_json(drive("chipbench.control", "--workload", name,
                           "--seed", str(BIG_SEED), "--seconds", "3",
                           "--rehearsal"))
     assert out["program"]["correct"] is True
@@ -424,6 +444,209 @@ def test_draws_and_data_are_functions_of_the_seed():
     assert not np.array_equal(mog.corpus(vconf, BIG_SEED), mog.corpus(vconf, 5))
 
 
+# -- the plain model's message side against the n-quads the store is loaded from --
+
+
+def _rehearsal_config(name="snb.ic1"):
+    config = config_of(name)
+    return dict(config, sizes=dict(config["sizes"], **config["rehearsal"]))
+
+
+def _sha256(path):
+    import hashlib
+
+    with open(path, "rb") as f:
+        return hashlib.sha256(f.read()).hexdigest()
+
+
+@pytest.mark.parametrize("seed", sorted(NQUADS_SHA256))
+def test_the_nquads_of_a_config_and_seed_are_pinned(seed, tmp_path):
+    """The bytes the bulk loader reads are what they were before the
+    model got its message side: a change to a draw's order or bound
+    would serve another graph under `snb.ic1`."""
+    from chipbench.data import snb
+
+    path = str(tmp_path / "snb.rdf")
+    model = snb.make(_rehearsal_config(), seed, path)
+    assert _sha256(path) == NQUADS_SHA256[seed]
+    with open(path) as f:
+        assert model.nquads == sum(1 for _ in f)
+
+
+def _epoch_ms(literal):
+    """'"2011-..Z"^^<xs:dateTime>' as epoch ms, read without the maker."""
+    import datetime
+
+    text = literal.split('"')[1]
+    assert literal == f'"{text}"^^<xs:dateTime>'
+    t = datetime.datetime.fromisoformat(text.replace("Z", "+00:00"))
+    return round(t.timestamp() * 1000)
+
+
+@pytest.fixture(scope="module")
+def written(tmp_path_factory):
+    """(model, {predicate: {subject uid: [object text]}}, {(subject,
+    object) of a `knows`: facet text}) of one rehearsal-size file."""
+    from chipbench.data import snb
+
+    path = str(tmp_path_factory.mktemp("snb") / "snb.rdf")
+    model = snb.make(_rehearsal_config(), BIG_SEED, path)
+    triples, facets = {}, {}
+    with open(path) as f:
+        for line in f.read().split("\n"):
+            body = line[: -len(" .")]
+            assert line == body + " ."
+            subject, pred, obj = body.split(" ", 2)
+            s = int(subject[len("<0x"):-1], 16)
+            if obj.endswith(")"):
+                obj, facet = obj[:-1].split(" (", 1)
+                facets[s, int(obj[len("<0x"):-1], 16)] = facet
+            triples.setdefault(pred[1:-1], {}).setdefault(s, []).append(obj)
+    return model, triples, facets
+
+
+def _ref(uid):
+    return f"<0x{int(uid):x}>"
+
+
+def _lit(text):
+    return '"' + text + '"'
+
+
+def _expected(model, pred):
+    """{subject uid: [object text]} of EVERY `pred` triple, from the
+    model's columns alone."""
+    from chipbench.data import snb
+
+    msgs = model.messages()
+    every = range(len(msgs))
+    forums = range(msgs.n_forums)
+    if pred == "hasCreator":
+        return {msgs.uid(i): [_ref(snb.person_uid(msgs.creator[i]))]
+                for i in every}
+    if pred == "replyOf":
+        return {msgs.uid(i): [_ref(msgs.uid(msgs.parent[i]))]
+                for i in every if msgs.parent[i] >= 0}
+    if pred in ("content", "imageFile"):
+        return {msgs.uid(i): [_lit(msgs.text(i)[pred])]
+                for i in every if pred in msgs.text(i)}
+    if pred == "fqid":
+        return {msgs.uid(i): [_lit(msgs.fqid(i))] for i in every}
+    if pred == "containerOf":
+        out = {}
+        for j in range(msgs.n_posts):
+            out.setdefault(msgs.forum_uid(msgs.forum_of_post(j)),
+                           []).append(_ref(msgs.uid(j)))
+        return out
+    if pred == "hasModerator":
+        return {msgs.forum_uid(f): [_ref(snb.person_uid(msgs.moderator[f]))]
+                for f in forums}
+    if pred == "title":
+        return {msgs.forum_uid(f): [_lit(msgs.forum_title(f))]
+                for f in forums}
+    raise KeyError(pred)
+
+
+@pytest.mark.parametrize("pred", ["hasCreator", "replyOf", "content",
+                                  "imageFile", "fqid", "containerOf",
+                                  "hasModerator", "title"])
+def test_the_model_says_every_triple_the_file_holds(written, pred):
+    from chipbench.data import snb
+
+    model, triples, _ = written
+    got = triples[pred]
+    if pred == "fqid":  # the persons' and forums' are not the messages'
+        got = {s: o for s, o in got.items()
+               if not o[0].startswith(('"person_', '"forum_'))}
+    assert got == _expected(model, pred)
+    msgs = model.messages()
+    assert (len(msgs), msgs.n_posts, msgs.n_forums) == (900, 300, 30)
+    if pred == "replyOf":  # a comment answers an EARLIER message, later
+        replies = np.flatnonzero(msgs.parent >= 0)
+        assert np.array_equal(replies, np.arange(300, 900))
+        assert (msgs.parent[replies] < replies).all()
+        assert (msgs.ms[replies] > msgs.ms[msgs.parent[replies]]).all()
+        assert (msgs.parent[replies] >= 300).any()  # some to a comment
+
+
+def test_the_model_says_every_date_the_file_holds(written):
+    """Every `creationDate` triple (persons and messages) and every
+    `knows|creationDate` facet, as epoch milliseconds."""
+    from chipbench.data import snb
+
+    model, triples, facets = written
+    msgs = model.messages()
+    got = {s: [_epoch_ms(o) for o in objs]
+           for s, objs in triples["creationDate"].items()}
+    want = {msgs.uid(i): [int(msgs.ms[i])] for i in range(len(msgs))}
+    want.update({snb.person_uid(i): [_epoch_ms(
+        f'"{model.person(i)["creationDate"]}"^^<xs:dateTime>')]
+        for i in range(model.n)})
+    assert got == want
+    assert len(model.knows_ms) == len(model.pairs)
+    want = {}
+    for (a, b), at in zip(model.pairs.tolist(), model.knows_ms.tolist()):
+        ua, ub = snb.person_uid(a), snb.person_uid(b)
+        want[ua, ub] = want[ub, ua] = at
+    assert {k: _epoch_ms(v[len("creationDate="):])
+            for k, v in facets.items()} == want
+    assert all(v.startswith("creationDate=") for v in facets.values())
+    assert sorted(facets) == sorted(
+        (s, int(o[len("<0x"):-1], 16))
+        for s, objs in triples["knows"].items() for o in objs)
+
+
+def test_the_models_views_agree_with_its_columns(written):
+    model, _, _ = written
+    msgs = model.messages()
+    assert msgs is model.messages()  # built once, kept
+    mine = [msgs.by_creator(p) for p in range(model.n)]
+    assert sum(map(len, mine)) == len(msgs)
+    assert all((msgs.creator[m] == p).all() and (np.diff(m) > 0).all()
+               for p, m in enumerate(mine))
+    answered = [msgs.replies(i) for i in range(len(msgs))]
+    assert sum(map(len, answered)) == msgs.n_comments
+    assert all((msgs.parent[r] == i).all() and (np.diff(r) > 0).all()
+               for i, r in enumerate(answered))
+    assert msgs.is_post(299) and not msgs.is_post(300)
+    assert (msgs.sid(0), msgs.sid(299), msgs.sid(300)) == (
+        3, 3 + 299 * 11, 1099511627777)
+    # uids: places, persons, posts, comments, forums, with no hole
+    from chipbench.data import snb
+
+    assert msgs.uid(0) == snb.person_uid(model.n - 1) + 1
+    assert msgs.forum_uid(0) == msgs.uid(len(msgs) - 1) + 1
+
+
+def test_messages_are_a_function_of_config_and_seed():
+    """The same (configuration, seed) gives the same message side with
+    no file written, another seed another, and the sizes in force are
+    the model's: a query kind reads them there, not from a file."""
+    from chipbench.data import snb
+    from chipbench.queries import ic1
+
+    config = _rehearsal_config()
+    a, b, c = (snb.make(config, s) for s in (BIG_SEED, BIG_SEED, 5))
+    assert a.sizes == config["sizes"] and a._messages is None
+    # what `snb.ic1` asks of the model never builds the message side
+    params = mix_of("snb.ic1")["kinds"][0]["params"]
+    ic1.reference(a, params, [np.array([7, 3])])
+    ic1.control(a, params, [np.array([7, 3])])
+    assert a._messages is None and a._draws is None
+    for col in ("creator", "ms", "parent", "moderator"):
+        assert np.array_equal(getattr(a.messages(), col),
+                              getattr(b.messages(), col))
+        assert not np.array_equal(getattr(a.messages(), col),
+                                  getattr(c.messages(), col))
+    assert np.array_equal(a.knows_ms, b.knows_ms)
+    assert [a.messages().text(i) for i in (0, 1, 2, 300, 899)] == [
+        b.messages().text(i) for i in (0, 1, 2, 300, 899)]
+    full = snb.make(config_of("snb.ic1"), 5)
+    assert full.sizes["posts"] == 100360 and full._messages is None
+    with pytest.raises(ValueError):  # a control's cut-down copy
+        snb.Model(a.n, a.pairs[:10], a.seed).messages()
+
+
 def test_exact_topk_reference_is_exact():
     from chipbench.data import mog
 
@@ -440,26 +663,104 @@ def test_exact_topk_reference_is_exact():
 
 # -- a later PR adds files and entries, and edits nothing ---------------------------
 
+NEWEST_KIND = '''"""Query kind of a test: the newest messages of a person, newest first
+(LDBC's short read 2 without its reply chain), from the plain model's
+message side."""
+from chipbench.data import snb
+
+
+def request(catalog, params, rng):
+    msgs = catalog["model"].messages()
+    person = int(msgs.creator[rng.integers(len(msgs))])  # one who has written
+    return person, (
+        '{ q(func: eq(fqid, "person_%d")) { ~hasCreator(orderdesc: '
+        "creationDate, first: %d) { id creationDate content imageFile } } }"
+        % (snb.person_sid(person), params["first"]))
+
+
+def parse(body):
+    if "errors" in body:
+        raise ValueError(str(body["errors"])[:200])
+    return [(r["id"], snb.epoch_ms(r["creationDate"]), r.get("content"),
+             r.get("imageFile")) for r in body["data"]["q"][0]["~hasCreator"]]
+
+
+def reference(model, params, keys, stale=0):
+    msgs = model.messages()
+    out = []
+    for person in keys:
+        mine = sorted(msgs.by_creator(int(person)).tolist(),
+                      key=lambda i: (-msgs.ms[i], i))
+        out.append([(msgs.sid(i), int(msgs.ms[i]), msgs.text(i).get("content"),
+                     msgs.text(i).get("imageFile"))
+                    for i in mine[stale:][:params["first"]]])
+    return out
+
+
+def control(model, params, keys):
+    """A store that served before its last write was synced: each
+    person's newest message is missing."""
+    return reference(model, params, keys, stale=1), None
+
+
+def check(model, params, keys, answers, captured=None):
+    want = reference(model, params, keys)
+    return {"wrong_answers": [0.0 if list(a) == w else 1.0
+                              for a, w in zip(answers, want)],
+            "answers_compared": [1.0] * len(answers),
+            "newest_compared": [float(len(a)) for a in answers]}
+'''
+
+ORDER_FAULT = '''"""Fault of a test: every ordered block loses its first row."""
+
+
+def plant():
+    from dgraph_tpu.query.subgraph import Executor
+
+    orig = Executor._order_uids
+
+    def broken(self, gq, uids, full=False):
+        out = orig(self, gq, uids, full)
+        return out[1:] if gq.order else out
+
+    Executor._order_uids = broken
+'''
+
 
 def test_new_pieces_need_only_new_files(tmp_path):
-    """A configuration, a mix, a query kind and a layer reader added as
-    new files plus BENCHMARK.json entries, in a copy of the benchmark."""
-    shutil.copytree(CHIPBENCH, tmp_path / "chipbench",
-                    ignore=shutil.ignore_patterns(".store", "__pycache__"))
+    """In a copy of the benchmark, with no file of the copy edited: a
+    configuration with its own rehearsal sizes and `rehearsal_env`, a
+    fault file and a mix that names it, a query kind whose reference
+    reads the plain model's message side, a second kind in the same
+    mix and a layer reader, as new files plus BENCHMARK.json entries.
+    On that new cell: the rehearsal, the fault run (`correct` false)
+    and the control (`correct` false)."""
+    ignore = shutil.ignore_patterns(".store", "__pycache__")
+    shutil.copytree(CHIPBENCH, tmp_path / "chipbench", ignore=ignore)
     cb = tmp_path / "chipbench"
+    before = {p: p.read_bytes() for p in cb.rglob("*") if p.is_file()}
     config = config_of("snb.ic1")
-    config.update(name="snb-small", rehearsal={"persons": 1500,
-                                                "knows_pairs": 12000,
-                                                "posts": 50, "comments": 50,
-                                                "forums": 5})
+    config.update(
+        name="snb-small",
+        rehearsal={"persons": 1500, "knows_pairs": 12000, "posts": 3000,
+                   "comments": 6000, "forums": 40},
+        # lower than snb-sf1's: at 1,500 persons only this takes IC1's
+        # third level to the jitted set ops, and nothing else sets it
+        rehearsal_env={"DGRAPH_TPU_DEVICE_MIN_TOTAL": "1024"})
+    config["checks"]["newest_compared"] = {"agg": "sum", "op": ">=",
+                                           "limit": 1}
     (cb / "configs" / "snb-small.json").write_text(json.dumps(config))
     (cb / "queries" / "ic1_again.py").write_text(
         "from chipbench.queries.ic1 import *  # noqa: F401,F403\n")
+    (cb / "queries" / "newest.py").write_text(NEWEST_KIND)
+    (cb / "faults" / "order_drops_first.py").write_text(ORDER_FAULT)
     mix = mix_of("snb.ic1")
-    mix.update(name="ic1wide", clients=2,
+    mix.update(name="walls", clients=2, fault="order_drops_first",
                kinds=[{"kind": "ic1_again", "weight": 1,
-                       "params": {"limit": 5, "band": [0.3, 0.7]}}])
-    (cb / "mixes" / "ic1wide.json").write_text(json.dumps(mix))
+                       "params": {"limit": 5, "band": [0.3, 0.7]}},
+                      {"kind": "newest", "weight": 3,
+                       "params": {"first": 10}}])
+    (cb / "mixes" / "walls.json").write_text(json.dumps(mix))
     (cb / "layer_metrics" / "requests_answered.py").write_text(
         "def read(ctx):\n    return float(ctx['requests'])\n")
     bench = json.loads(json.dumps(BENCH))
@@ -467,25 +768,44 @@ def test_new_pieces_need_only_new_files(tmp_path):
         "name": "snb-small", "source": "test",
         "file": "chipbench/configs/snb-small.json",
         "reduced": config["reduced"], "why": "test"})
-    bench["workloads"].append({"name": "small.ic1wide", "config": "snb-small",
-                               "traffic": "ic1wide", "chips": 1, "why": "test"})
+    bench["workloads"].append({"name": "small.walls", "config": "snb-small",
+                               "traffic": "walls", "chips": 1, "why": "test"})
     bench["per_layer"].append({
         "name": "requests_answered", "unit": "count", "better": "higher",
         "source": "program_counter", "layer": "wire", "moves": "qps",
-        "workloads": ["small.ic1wide"]})
+        "workloads": ["small.walls"]})
     for m in bench["per_layer"]:
         if m["name"] == "store_open_s":
-            m["workloads"].append("small.ic1wide")
+            m["workloads"].append("small.walls")
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
-               PYTHONPATH=os.pathsep.join([str(tmp_path), ROOT]),
-               DGRAPH_TPU_DEVICE_MIN_TOTAL="32768")
-    proc = subprocess.run(
-        [sys.executable, "-m", "chipbench.run", "--workload", "small.ic1wide",
-         "--seed", "9", "--seconds", "2", "--trace", "1", "--rehearsal"],
-        cwd=str(tmp_path), env=env, capture_output=True, text=True,
-        timeout=600)
-    out = last_json(proc)
-    assert out["correct"] is True
+    assert "DGRAPH_TPU_DEVICE_MIN_TOTAL" not in os.environ
+
+    def run_it(module, *args):
+        return last_json(drive(
+            module, *args, "--workload", "small.walls", "--seed", "9",
+            "--seconds", "3", cwd=str(tmp_path), extra_path=[ROOT]))
+
+    out = run_it("chipbench.run", "--trace", "1", "--rehearsal")
+    assert out["correct"] is True and out["failed"] == 0
     assert out["metrics"]["requests_answered"]["value"] > 0
     assert "store_open_s" in out["metrics"]
+    assert out["metrics"]["device_ops_per_req"]["value"] > 0
+    # both kinds were sent and compared: rows of `newest`, answers of both
+    compared = out["checks"]["answers_compared"][0]
+    assert compared == out["attempted"]
+    assert out["checks"]["newest_compared"][0] > compared / 2
+
+    out = run_it("chipbench.tests.faults", mix["fault"], "--trace", "0")
+    assert out["correct"] is False and out["checks"]["wrong_answers"][0] > 0
+
+    out = run_it("chipbench.control", "--rehearsal")
+    assert out["program"]["correct"] is True
+    assert out["control"]["correct"] is False
+
+    after = {p: p.read_bytes() for p in cb.rglob("*") if p.is_file()
+             and ".store" not in p.parts and "__pycache__" not in p.parts}
+    added = {str(p.relative_to(cb)) for p in set(after) - set(before)}
+    assert added == {"configs/snb-small.json", "queries/ic1_again.py",
+                     "queries/newest.py", "faults/order_drops_first.py",
+                     "mixes/walls.json", "layer_metrics/requests_answered.py"}
+    assert all(after[p] == data for p, data in before.items())

@@ -198,6 +198,92 @@ def test_span_reduce_on_the_recorded_planes():
     assert r["device_scope_s"] == {"vec.ivf": pytest.approx(1400e-9)}
 
 
+def test_a_span_is_what_carries_a_trace_id_and_SPANS_is_the_fallback():
+    """With its ids the recorded trace names its own spans; without
+    them the frozen list does, and both give the same attribution. A
+    span the list has never heard of is named as soon as it carries an
+    id, and a host event that carries none stays what it was."""
+    path = os.path.join(HERE, "recorded_spans.json")
+    planes = span_reduce.read_planes(path)
+    names = span_reduce.span_names(planes)
+    assert names < span_reduce.SPANS and "vec.wait" in names
+    assert "PjitFunction(run)" not in names and "TpuExecute" not in names
+    stripped = span_reduce.bare(planes)
+    assert span_reduce.span_names(stripped) == span_reduce.SPANS
+    with_ids = span_reduce.reduce_planes(planes)
+    without = span_reduce.reduce_planes(stripped)
+    assert with_ids["by_span"] == without["by_span"]
+    assert list(with_ids["by_span"])[:2] == ["no_span", "parse"]
+    assert (with_ids["span_events"], without["span_events"]) == (36, 36)
+    assert without["span_events_with_trace_id"] == 0
+
+    # `parse` renamed to a span no list knows: found by its id, lost
+    # without one (its time then falls to the span around it)
+    renamed = [(p, [(ln, [["order.walk", *ev[1:]] if ev[0] == "parse"
+                          else ev for ev in evs]) for ln, evs in lines])
+               for p, lines in planes]
+    found = span_reduce.reduce_planes(renamed)["by_span"]
+    assert found["order.walk"] == with_ids["by_span"]["parse"]
+    assert {k: v for k, v in found.items() if k != "order.walk"} == {
+        k: v for k, v in with_ids["by_span"].items() if k != "parse"}
+    lost = span_reduce.reduce_planes(span_reduce.bare(renamed))["by_span"]
+    assert "order.walk" not in lost and "parse" not in lost
+    assert lost["query"] == pytest.approx(
+        with_ids["by_span"]["query"] + with_ids["by_span"]["parse"])
+
+
+def _two_threads(skew, requests=40):
+    """Planes of two handler threads taking turns: a request parses for
+    2,000 ns, launches for 600 and waits 2,400; its program starts 300
+    after the launch began and runs 1,000, on a device clock that reads
+    `skew` early; requests begin 4,000-9,000 apart."""
+    import random
+
+    rng = random.Random(3)
+    host, programs, t = ([], []), [], 10_000
+    for i in range(requests):
+        t += rng.randrange(4000, 9000)
+        tid = f"{i + 1:032x}"
+        host[i % 2].extend([["parse", t, 2000, tid],
+                            ["vec.launch", t + 2000, 600, tid],
+                            ["vec.wait", t + 2600, 2400, tid]])
+        programs.append(["jit_run(1)", t + 2300 - skew, 1000])
+    return [["/device:TPU:0", [["XLA Modules", programs],
+                               ["XLA Ops", programs]]],
+            ["/host:CPU", [["handler", host[0]], ["handler", host[1]]]]]
+
+
+def test_the_launcher_is_found_by_order_where_the_clocks_differ(monkeypatch):
+    """The device's clock a microsecond early puts a program's start
+    before its own launch: the last launch before it is then the OTHER
+    thread's, which waits. Matched by order, the gap goes to what the
+    launching thread did (it parsed), and the clamp takes out all of
+    the skew but the dispatch latency itself."""
+    true = span_reduce.reduce_planes(_two_threads(0))
+    assert span_reduce.order_offset([1, 2], [1, 2]) == (None, 0)  # too few
+    assert true["launch_to_device_us_p50"] == pytest.approx(0.3)
+    assert true["by_span"]["parse"] > 10 * true["by_span"].get("vec.wait", 0)
+    skewed = span_reduce.reduce_planes(_two_threads(1000))
+    assert skewed["by_span"]["parse"] > 10 * skewed["by_span"].get(
+        "vec.wait", 0)
+    assert skewed["by_span"] == span_reduce.reduce_planes(
+        _two_threads(400))["by_span"]  # what is left is the 300 of latency
+    assert skewed["launch_to_device_us_p50"] == pytest.approx(0.0)
+    # programs whose launch the trace did not catch shift the order
+    late = _two_threads(0)
+    late[1][1][0][1] = late[1][1][0][1][6:]  # thread 0 traced from its 3rd
+    late[1][1][1][1] = late[1][1][1][1][6:]  # request on, thread 1 too
+    assert span_reduce.reduce_planes(late)["launch_to_device_us_p50"] == (
+        pytest.approx(0.3))
+    # the rule before PR 33, the last launch that began before the device
+    # did, on the same skewed trace: the waiting thread gets the gap
+    monkeypatch.setattr(span_reduce, "ORDER_LEAST", 10 ** 6)
+    by_time = span_reduce.reduce_planes(_two_threads(1000))["by_span"]
+    assert by_time["vec.wait"] > by_time.get("parse", 0)
+    assert span_reduce.reduce_planes(_two_threads(0))["by_span"] == (
+        true["by_span"])  # with one clock the two rules agree
+
+
 def test_span_reduce_names_a_gap_no_launch_precedes():
     planes = [
         ["/device:TPU:0", [
