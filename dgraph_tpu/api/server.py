@@ -1624,6 +1624,8 @@ class Server:
                 sp.attrs["order_cands"] = ex.order_tally[
                     "order_candidates_total"]
                 sp.attrs["order_kept"] = ex.order_tally["order_kept_total"]
+                sp.attrs["order_buckets"] = ex.order_tally[
+                    "order_buckets_total"]
         with observe.TRACER.span("encode", cpu=True, fine=True) as sp:
             data, enc_stats = encode_response_data(
                 nodes, val_vars=ex.val_vars, schema=self.schema, want=want

@@ -129,8 +129,9 @@ def _expand_pool(workers: int) -> ThreadPoolExecutor:
         return pool
 
 
-# Candidate ids a multi-key `order .. first: N` must bring for every index
-# bucket its window walk may read (Executor._narrow_to_window): the walk
+# Candidate ids an order must bring for every index bucket its walk may
+# read (Executor._narrow_to_window for two or more keys under `first`,
+# _order_uids_indexed for one key): the walk
 # gives up after len(uids) // 8 buckets and the block sorts every
 # candidate, as it did before the walk existed. Data whose leading key has
 # about as many distinct values as there are candidates (SF1 proper's
@@ -217,8 +218,9 @@ class Executor:
         self.val_vars: Dict[str, Dict[int, Val]] = {}
         # where each value var is keyed (for per-parent aggregation)
         self.var_def_node: Dict[str, ExecNode] = {}
-        # multi-key window walks (_order_uids_window): what the request's
-        # ordered blocks and rows came to, flushed to METRICS by process()
+        # index walks under an order (_order_uids_window, _order_uids):
+        # what the request's ordered blocks and rows came to, flushed to
+        # METRICS by process()
         # (sibling levels expand on pool threads, hence the lock)
         self._order_mu = threading.Lock()
         self.order_tally: Dict[str, int] = collections.Counter()
@@ -2186,13 +2188,47 @@ class Executor:
 
     def _order_uids_indexed(
         self, gq: GraphQuery, o: Order, uids: np.ndarray
-    ) -> Optional[np.ndarray]:
-        """Index-walk ordering (ref worker/sort.go:189 sortWithIndex): walk
-        the attr's sortable index buckets in token order — token bytes are
-        order-preserving for exact/int/datetime tokenizers — intersecting
-        each bucket with the candidates, early-stopping at offset+first.
-        One KV read per DISTINCT value instead of one per uid. Returns
-        None when no sortable index applies (caller falls back)."""
+    ) -> Optional[Tuple[str, np.ndarray, int, int]]:
+        """One order key over an attr with a sortable index: (path, the
+        ids in order, ids handed to the comparator, buckets listed or
+        read), or None when no sortable index applies (the caller sorts
+        by value). Upstream races the two ways to order (ref
+        worker/sort.go sortWithIndex against sortWithoutIndex); here the
+        number of candidates decides before anything is listed or read:
+
+        - "values": fewer than _ORDER_WALK_IDS_PER_BUCKET candidates
+          cannot pay for one bucket, so the comparator orders them by
+          their stored values behind one prefetch. A person's ten
+          messages no longer list an hour index's 19,000 keys.
+        - "walked": the attr's buckets in token order (token bytes are
+          order-preserving for the sortable tokenizers), each intersected
+          with the candidates, until offset+first are placed or every
+          candidate is; one read per DISTINCT value instead of one per
+          id. The walk is lazy and stops after len(uids) // 8 buckets,
+          the budget of _narrow_to_window; the stores iterate forwards
+          only, so a descending walk lists the keys first, and an attr
+          with more of them than the budget is not walked.
+        - "over_budget": the walk gave up and the comparator orders every
+          candidate, as "values" does.
+
+        The two ways agree on everything the walk defines: ids with no
+        value after every valued one, in uid order along the key's
+        direction; inside a lossy bucket (hour, year, the int of a
+        float) by the real value; equal values of an exact tokenizer by
+        uid ASCENDING in both directions, the order a bucket holds them
+        in, which the comparator is told to keep. They differ in one
+        case (ROADMAP D18 (b)): the date tokenizers file a value under
+        its fields as written, offset and all, so over a predicate that
+        mixes UTC offsets the walk orders by bucket and the comparator
+        by instant. The comparator is right; the walk stays wrong there
+        until the tokens change. A negative offset counts as none, as
+        _paginate has it (the walk used to add it to `first` and stop
+        short of the window).
+
+        An id of an @lang or list predicate sits in several buckets and
+        the walk places it by the first it meets (its least value
+        ascending, its greatest descending), where the comparator reads
+        one value: those are always walked, with no budget, as before."""
         if o.val_var or o.lang:
             return None
         su = self.st.get(o.attr)
@@ -2205,52 +2241,83 @@ class Executor:
             return None
         need = None
         if gq.first is not None and gq.first >= 0 and gq.after is None:
-            need = (gq.offset or 0) + gq.first
-        prefix = keys.IndexPrefix(o.attr, self.ns)
-        ident = bytes([tk.identifier])
-        bucket_keys = [
-            k
-            for k, _, _ in self.cache.kv.iterate(prefix, self.cache.read_ts)
-            if keys.parse_key(k).term.startswith(ident)
-        ]
+            need = max(gq.offset or 0, 0) + gq.first
+        budget = (
+            None if su.lang or su.is_list
+            else len(uids) // _ORDER_WALK_IDS_PER_BUCKET
+        )
+        got, kept, read = (
+            (None, 0, 0) if budget == 0
+            else self._walk_in_order(o, tk, uids, need, budget)
+        )
+        if got is not None:
+            return "walked", got, kept, read
+        got = self._order_uids_generic(
+            gq, uids, ties_desc=o.desc and tk.is_lossy
+        )
+        path = "over_budget" if budget else "values"
+        return path, got, kept + len(uids), read
+
+    def _walk_in_order(
+        self, o: Order, tk, uids: np.ndarray, need: Optional[int],
+        budget: Optional[int],
+    ) -> Tuple[Optional[np.ndarray], int, int]:
+        """(the ids in order or None where `budget` buckets did not
+        do, ids the comparator ordered inside lossy buckets, buckets
+        listed or read): _order_uids_indexed's walk. The key that shows
+        a descending listing to be over its budget is not counted."""
+        from dgraph_tpu import native
+
+        walk = self._index_bucket_stream(o.attr, tk)
+        listed = 0
         if o.desc:
-            bucket_keys.reverse()
-        out: List[int] = []
-        emitted: set = set()  # a uid with several indexed values (langs,
-        # list preds) appears in several buckets — first bucket wins
-        cand = uids
-        for bk in bucket_keys:
-            if need is not None and len(out) >= need:
-                break
-            bucket = self.cache.uids(bk)
-            if not len(bucket):
-                continue
-            sel = np.intersect1d(bucket, cand, assume_unique=True)
-            sel = np.array(
-                [u for u in sel if int(u) not in emitted], dtype=np.uint64
+            keys_ = list(
+                walk if budget is None
+                else itertools.islice(walk, budget + 1)
             )
+            if budget is not None and len(keys_) > budget:
+                return None, 0, budget
+            listed = len(keys_)
+            walk = reversed(keys_)
+        cands = np.unique(uids)  # native.intersect takes sorted, unique ids
+        out: List[np.ndarray] = []
+        placed = kept = read = 0
+        # an id with several indexed values (langs, list preds) is in
+        # several buckets: the first one wins
+        seen = np.zeros(len(cands), bool)
+        wanted = len(cands) if need is None else min(need, len(cands))
+        for bk in walk if wanted else ():
+            if budget is not None and read >= budget:
+                return None, kept, max(listed, read)
+            read += 1
+            sel = native.intersect(self.cache.uids(bk), cands)
             if not len(sel):
                 continue
-            emitted.update(int(u) for u in sel)
+            at = np.searchsorted(cands, sel)
+            sel = sel[~seen[at]]
+            if not len(sel):
+                continue
+            seen[at] = True
             if tk.is_lossy and len(sel) > 1:
                 # lossy buckets (float@int, year/...) order between buckets
                 # only: sort inside by actual value (sortWithoutIndex per
                 # bucket in the reference)
-                sub = GraphQuery(attr=gq.attr)
-                sub.order = [Order(attr=o.attr, desc=o.desc, lang=o.lang)]
+                sub = GraphQuery(attr=o.attr)
+                sub.order = [Order(attr=o.attr, desc=o.desc)]
                 sel = self._order_uids_generic(sub, sel)
-            out.extend(int(u) for u in sel)
-        # uids with no indexed value sort AFTER every valued one, uid
+                kept += len(sel)
+            out.append(sel)
+            placed += len(sel)
+            if placed >= wanted:
+                break
+        # ids with no indexed value sort AFTER every valued one, uid
         # order matching the key's direction — same tail the generic
         # comparator produces (ref TestNegativeOffset)
-        if need is None or len(out) < need:
-            out.extend(
-                sorted(
-                    (int(u) for u in uids if int(u) not in emitted),
-                    reverse=o.desc,
-                )
-            )
-        return np.array(out, dtype=np.uint64)
+        if need is None or placed < need:
+            tail = cands[~seen]
+            out.append(tail[::-1] if o.desc else tail)
+        ordered = np.concatenate(out) if out else np.zeros(0, np.uint64)
+        return ordered.astype(np.uint64, copy=False), kept, max(listed, read)
 
     def _order_uids_topk(
         self, gq: GraphQuery, o: Order, uids: np.ndarray
@@ -2307,19 +2374,31 @@ class Executor:
             return self._order_uids_window(gq, uids, full)
         if not len(uids):
             return uids
-        if any(o.lang and o.lang != "." for o in gq.order):
-            # lang-tagged sorts need collation — only the generic path
-            # applies it (index walks are byte-ordered)
+        if len(gq.order) > 1:
             return self._order_uids_generic(gq, uids)
-        if len(gq.order) == 1 and not full:
-            o = gq.order[0]
+        # one key: by device top-k, through the key's index, or by value,
+        # and a tally of which (order_single_total)
+        o = gq.order[0]
+        took = None
+        # lang-tagged sorts need collation — only the generic path
+        # applies it (index walks are byte-ordered)
+        if not full and not (o.lang and o.lang != "."):
             got = self._order_uids_topk(gq, o, uids)
-            if got is not None:
-                return got
-            got = self._order_uids_indexed(gq, o, uids)
-            if got is not None:
-                return got
-        return self._order_uids_generic(gq, uids)
+            took = (
+                ("topk", got, 0, 0) if got is not None
+                else self._order_uids_indexed(gq, o, uids)
+            )
+        if took is None:
+            took = "values", self._order_uids_generic(gq, uids), len(uids), 0
+        path, got, kept, read = took
+        with self._order_mu:
+            self.order_tally.update({
+                f'order_single_total{{path="{path}"}}': 1,
+                "order_candidates_total": len(uids),
+                "order_kept_total": kept,
+                "order_buckets_total": read,
+            })
+        return got
 
     def _order_uids_window(
         self, gq: GraphQuery, uids: np.ndarray, full: bool
@@ -2426,9 +2505,19 @@ class Executor:
             return stored
         return (k for k, _ in itertools.groupby(heapq.merge(stored, own)))
 
-    def _order_uids_generic(self, gq: GraphQuery, uids: np.ndarray) -> np.ndarray:
+    def _order_uids_generic(
+        self, gq: GraphQuery, uids: np.ndarray,
+        ties_desc: Optional[bool] = None,
+    ) -> np.ndarray:
+        """The comparator over the stored values of every candidate.
+        `ties_desc` says how ids with equal values under every predicate
+        key follow each other: by uid in the last key's direction,
+        unless the caller has an index walk's order to keep
+        (_order_uids_indexed)."""
         if not len(uids) or not gq.order:
             return uids
+        if ties_desc is None:
+            ties_desc = gq.order[-1].desc
 
         def key_of(o: Order, u):
             if o.val_var:
@@ -2481,10 +2570,12 @@ class Executor:
             return _sort_key_of(v)
 
         def cmp(a, b):
+            valued = False
             for o, vals in zip(orders, vals_per_key):
                 va, vb = vals[a], vals[b]
                 if va is None and vb is None:
                     continue
+                valued = True
                 if va is None:
                     return 1  # missing always last
                 if vb is None:
@@ -2503,7 +2594,9 @@ class Executor:
             lt = -1 if a < b else 1
             if orders[-1].val_var:
                 return lt
-            return -lt if orders[-1].desc else lt
+            # two ids with no value at all keep the direction's order
+            # whatever the caller says of equal values
+            return -lt if (ties_desc if valued else orders[-1].desc) else lt
 
         try:
             ordered.sort(key=functools.cmp_to_key(cmp))

@@ -2711,21 +2711,37 @@ declare_metric(
     "that to list); the last three sort every candidate.",
 )
 declare_metric(
+    "counter", "order_single_total{path=\"*\"}",
+    "Ordered blocks, and rows of an ordered child level, that carry ONE "
+    "order key, by how the executor ordered them (query/subgraph.py "
+    "_order_uids, _order_uids_indexed): `values` (the comparator over "
+    "every candidate's stored value: fewer than 8 candidates, no "
+    "sortable index, a val(..) or language-tagged key, @cascade above), "
+    "`walked` (the key's index buckets in token order until the window "
+    "or the candidates were placed), `over_budget` (a walk that read "
+    "len(ids)/8 buckets without placing them, or a descending walk "
+    "that found more buckets than that to list; the comparator then "
+    "ordered every candidate) and `topk` (device top-k over a numeric "
+    "value var).",
+)
+declare_metric(
     "counter", "order_candidates_total",
-    "Ids that the blocks and rows counted in order_window_total were "
-    "asked to order.",
+    "Ids that the blocks and rows counted in order_window_total and "
+    "order_single_total were asked to order.",
 )
 declare_metric(
     "counter", "order_kept_total",
-    "Ids those blocks and rows handed to the multi-key comparator "
-    "(one value read per id and key). Over order_candidates_total: "
-    "the share of the sort's work the window walk left.",
+    "Ids those blocks and rows handed to the comparator (one value "
+    "read per id and key). Over order_candidates_total: the share of "
+    "the sort's work the index walks left.",
 )
 declare_metric(
     "counter", "order_buckets_total",
-    "Index buckets the window walk read and intersected with the "
-    "candidates, the wasted reads of `over_budget` and `refilled` "
-    "walks included.",
+    "Index buckets the walks of those blocks and rows read and "
+    "intersected with the candidates, the wasted reads of "
+    "`over_budget` and `refilled` walks included; for a single-key "
+    "descending walk, the bucket keys it listed first, where those "
+    "are more.",
 )
 declare_metric(
     "counter", "digest_evicted_total",
