@@ -157,6 +157,67 @@ def encode(uids: np.ndarray) -> UidPack:
     return UidPack(bases=bases, counts=counts, offsets=offsets, num_uids=n)
 
 
+def _frozen(arr: np.ndarray) -> np.ndarray:
+    arr.setflags(write=False)
+    return arr
+
+
+# THE empty pack: one shared, immutable object for every list without uid
+# edges (half of a social graph's records are a lone value posting), in
+# place of three fresh arrays per decoded record
+EMPTY = UidPack(
+    bases=_frozen(np.zeros((0,), np.uint64)),
+    counts=_frozen(np.zeros((0,), np.int32)),
+    offsets=_frozen(np.zeros((0, BLOCK_SIZE), np.uint32)),
+    num_uids=0,
+)
+NO_UIDS = _frozen(np.zeros((0,), np.uint64))  # decode(EMPTY), shared
+_PAD_ROW = _frozen(np.full((1, BLOCK_SIZE), 0xFFFFFFFF, np.uint32))
+_HEADER = struct.Struct("<4sQI")  # magic, num_uids, nblocks
+_BLOCK_HEAD = struct.Struct("<QHB")  # base, count, width
+# a one-block pack of at most this many uids is cheaper to unpack with
+# Python ints than through four numpy calls and a native bit-unpack
+SMALL_PACK = 16
+
+
+def deserialize_small(data: bytes, pos: int, n: int):
+    """(pack, uids) for the two commonest serialized packs at
+    data[pos:pos+n], else None (the caller takes `deserialize`, the
+    general form and the reference): an EMPTY pack is the shared one; a
+    single bit-packed block of at most SMALL_PACK uids is unpacked from
+    one Python int, its decoded uids alongside. Never raises for bad
+    input: whatever it does not recognise is the general decoder's."""
+    if n < 16:
+        return None
+    magic, num_uids, nb = _HEADER.unpack_from(data, pos)
+    if magic != _MAGIC:
+        return None
+    if nb == 0:
+        return (EMPTY, NO_UIDS) if n == 16 and num_uids == 0 else None
+    if nb != 1 or n < 27:
+        return None
+    base, c, w = _BLOCK_HEAD.unpack_from(data, pos + 16)
+    if (
+        c != num_uids or not 0 < c <= SMALL_PACK or w > 32
+        or 27 + (c * w + 7) // 8 != n
+    ):
+        return None
+    bits = int.from_bytes(data[pos + 27 : pos + n], "little")
+    mask = (1 << w) - 1
+    offs = [(bits >> (i * w)) & mask for i in range(c)]
+    if base + offs[-1] >= 1 << 64:
+        return None
+    offsets = _PAD_ROW.copy()
+    offsets[0, :c] = offs
+    pack = UidPack(
+        bases=np.array((base,), np.uint64),
+        counts=np.array((c,), np.int32),
+        offsets=offsets,
+        num_uids=c,
+    )
+    return pack, np.array([base + o for o in offs], np.uint64)
+
+
 def decode(pack: UidPack) -> np.ndarray:
     """Decode a UidPack back to a sorted u64 array. Ref codec.go:444 Decode.
 

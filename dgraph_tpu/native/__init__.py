@@ -16,6 +16,8 @@ import hashlib
 import os
 import subprocess
 import tempfile
+from array import array
+from itertools import accumulate
 from typing import Optional
 
 import numpy as np
@@ -23,6 +25,16 @@ import numpy as np
 from dgraph_tpu.x import config
 
 _LIB: Optional[ctypes.CDLL] = None
+# The same kernels, called WITHOUT giving up the interpreter's lock. A
+# call through _LIB releases it, which is right for milliseconds of native
+# work and wrong for microseconds: with other threads waiting, the release
+# hands the lock over, and the caller queues for it again. On this
+# sandbox's CPU that turns a 1.4 us call into 18-29 us of wall time and
+# 23-40 us of CPU (futex, wake-up, a cold cache) at 4-16 threads, while
+# the held call stays at 1.4-1.6 us (PERF.md, PR 35). A wrapper picks
+# _HELD where the work is bounded by a few microseconds (the level
+# reads' probe of a handful of keys).
+_HELD: Optional[ctypes.PyDLL] = None
 NATIVE_AVAILABLE = False
 BUILD_ERROR: Optional[str] = None  # why the build/load failed, if it did
 
@@ -160,13 +172,9 @@ DECLS = {
         _i64,
         [_u8p, _i64, _i64, _u8p, _i64, _i64, _u64p, _u64p, _i64p, _i64p],
     ),
-    "sst_versions_multi": (
-        _i64,
-        [
-            _u8p, _i64, _i64, _u8p, _i64p, _i64p, _i64p, _i64,
-            _i64p, _u64p, _u64p, _i64p, _i64p,
-        ],
-    ),
+    # void* params by design, like batch_apply: the table's words and the
+    # thread's scratch by address, the keys as the bytes they are
+    "sst_versions_multi": (_i64, [_vp, _i64, _vp, _vp, _i64, _vp]),
     "sst_scan": (
         _i64,
         [
@@ -209,14 +217,14 @@ _SAN_FLAGS = {
 }
 
 
-def _build_and_load() -> ctypes.CDLL:
+def _build_and_load():
     here = os.path.dirname(__file__)
     srcs = [
         os.path.join(here, "codec.cpp"),
         os.path.join(here, "bulkload.cpp"),
     ]
     h = hashlib.sha256()
-    for s in srcs:
+    for s in srcs + [os.path.join(here, "sst_bloom.h")]:
         with open(s, "rb") as f:
             h.update(f.read())
     tag = h.hexdigest()[:16]
@@ -248,12 +256,15 @@ def _build_and_load() -> ctypes.CDLL:
                 subprocess.TimeoutExpired):
             subprocess.run(cmd, check=True, capture_output=True, timeout=120)
         os.replace(tmp, so_path)
-    lib = ctypes.CDLL(so_path)
-    for name, (restype, argtypes) in DECLS.items():
-        fn = getattr(lib, name)
-        fn.restype = restype
-        fn.argtypes = argtypes
-    return lib
+    # the same library twice: CDLL releases the interpreter's lock
+    # around a call, PyDLL holds it (_HELD, below)
+    libs = ctypes.CDLL(so_path), ctypes.PyDLL(so_path)
+    for lib in libs:
+        for name, (restype, argtypes) in DECLS.items():
+            fn = getattr(lib, name)
+            fn.restype = restype
+            fn.argtypes = argtypes
+    return libs
 
 
 def _ptr(arr: np.ndarray, ctype):
@@ -261,7 +272,7 @@ def _ptr(arr: np.ndarray, ctype):
 
 
 try:
-    _LIB = _build_and_load()
+    _LIB, _HELD = _build_and_load()
     NATIVE_AVAILABLE = True
 except Exception as _e:  # no compiler, failed compile, unloadable .so
     # CalledProcessError carries the compiler's stderr
@@ -324,10 +335,15 @@ def bitunpack(data: bytes, count: int, width: int) -> np.ndarray:
             dtype=np.uint32,
             count=count,
         )
-    if _LIB is not None:
+    if _HELD is not None:
+        # _HELD: one block, at most 256 lanes. The general record decoder
+        # calls this once a record, and with the level reads off the
+        # store's lock several threads decode side by side: giving the
+        # interpreter's lock away here cost a cold `knows` level on the
+        # chip's host 2.4 times its CPU (PERF.md, PR 35)
         buf = np.frombuffer(data, dtype=np.uint8)
         out = np.empty((count,), np.uint32)
-        _LIB.bitunpack(
+        _HELD.bitunpack(
             _ptr(buf, ctypes.c_uint8),
             buf.size,
             count,
@@ -1082,8 +1098,11 @@ def sst_versions(
     s = _VSCRATCH
     while True:
         s.ensure(cap)
+        # _HELD: one key's few records, under the store's lock (LsmKV.
+        # versions / get): giving the interpreter's lock away here would
+        # park the next reader on the store's
         n = int(
-            _LIB.sst_versions(
+            _HELD.sst_versions(
                 bptr, end, off, kp, len(key), s.cap, *s.ptrs
             )
         )
@@ -1092,39 +1111,67 @@ def sst_versions(
         cap = s.cap * 4
 
 
-def sst_versions_multi(
-    bptr, end: int, keys: list, starts: np.ndarray, cap: int
-):
-    """Batched version probe over SORTED distinct keys in one native call.
-    Returns (counts, tss, seqs, voffs, vlens) flattened per key order."""
+class _ProbeScratch(__import__("threading").local):
+    """A thread's reusable output buffer for sst_versions_multi."""
+
+    def __init__(self):
+        self.words = 0
+
+    def ensure(self, words: int):
+        if words > self.words:
+            self.words = words
+            self.buf = np.empty(words, np.uint64)
+            self.addr = self.buf.ctypes.data
+
+
+_PSCRATCH = _ProbeScratch()
+# a probe of at most this many keys keeps the interpreter's lock (_HELD):
+# under a microsecond a key, so at most about as long as the hand-over it
+# saves; a wider level (a cold multi-hop expansion) releases it as before
+_HELD_PROBE_KEYS = 64
+
+
+def sst_probe_table(buf, data_end, bloom_bits, index, max_key):
+    """What sst_versions_multi needs of one table, as ten words built
+    ONCE (`_SSTable` keeps the result for its lifetime): returns (address
+    of the words, everything the addresses point into)."""
+    idx_keys = np.frombuffer(b"".join(k for k, _ in index) or b"\0", np.uint8)
+    koffs = np.zeros(len(index) + 1, np.int64)
+    np.cumsum([len(k) for k, _ in index], out=koffs[1:])
+    foffs = np.array([off for _, off in index], np.int64)
+    bits = None if not bloom_bits else np.frombuffer(bloom_bits, np.uint8)
+    mk = np.frombuffer(max_key or b"\0", np.uint8)
+    words = np.array(
+        [
+            buf.ctypes.data, data_end,
+            0 if bits is None else bits.ctypes.data,
+            0 if bits is None else 8 * bits.size,
+            idx_keys.ctypes.data, koffs.ctypes.data, foffs.ctypes.data,
+            len(index), mk.ctypes.data, len(max_key),
+        ],
+        np.int64,
+    )
+    return words.ctypes.data, (words, idx_keys, koffs, foffs, bits, mk)
+
+
+def sst_versions_multi(table_addr: int, keys: list) -> list:
+    """Batched version probe over SORTED distinct keys in one native
+    call: range test, bloom test, index seek and scan all happen there.
+    Returns one flat list: len(keys) counts, then (ts, seq, value offset,
+    value length) per version in key order."""
     nk = len(keys)
+    ends = array("q", accumulate(map(len, keys)))
     blob = b"".join(keys)
-    key_lens = np.fromiter((len(k) for k in keys), np.int64, nk)
-    key_offs = np.zeros(nk, np.int64)
-    np.cumsum(key_lens[:-1], out=key_offs[1:])
-    kb = np.frombuffer(blob, np.uint8)
+    s = _PSCRATCH
+    fn = (_HELD if nk <= _HELD_PROBE_KEYS else _LIB).sst_versions_multi
+    cap = max(64, 2 * nk)
     while True:
-        counts = np.zeros(nk, np.int64)
-        tss = np.empty(cap, np.uint64)
-        seqs = np.empty(cap, np.uint64)
-        voffs = np.empty(cap, np.int64)
-        vlens = np.empty(cap, np.int64)
-        got = int(
-            _LIB.sst_versions_multi(
-                bptr, end, nk,
-                _ptr(kb, ctypes.c_uint8),
-                _ptr(key_offs, ctypes.c_int64),
-                _ptr(key_lens, ctypes.c_int64),
-                _ptr(np.ascontiguousarray(starts, np.int64), ctypes.c_int64),
-                cap,
-                _ptr(counts, ctypes.c_int64),
-                _ptr(tss, ctypes.c_uint64), _ptr(seqs, ctypes.c_uint64),
-                _ptr(voffs, ctypes.c_int64), _ptr(vlens, ctypes.c_int64),
-            )
-        )
+        s.ensure(nk + 4 * cap)
+        cap = (s.words - nk) // 4
+        got = fn(table_addr, nk, blob, ends.buffer_info()[0], cap, s.addr)
         if got >= 0:
-            return counts, tss[:got], seqs[:got], voffs[:got], vlens[:got]
-        cap = max(cap * 2, -got + 1024)
+            return s.buf[: nk + 4 * got].tolist()
+        cap = max(cap * 2, -got + 64)
 
 
 def sst_scan(buf: np.ndarray, end: int, off: int, prefix: bytes, batch: int = 1024):

@@ -26,6 +26,8 @@
 #include <unordered_map>
 #include <vector>
 
+#include "sst_bloom.h"
+
 namespace {
 
 using u8 = uint8_t;
@@ -730,46 +732,6 @@ static void map_line(Ctx* c, MapState* st, const char* p, size_t n, u64 ns) {
 // SSTable writer (storage/lsm.py _SSTable.write, unencrypted form)
 // ---------------------------------------------------------------------------
 
-static u32 crc32_tab[256];
-static bool crc32_init_done = false;
-static void crc32_init() {
-  if (crc32_init_done) return;
-  for (u32 i = 0; i < 256; ++i) {
-    u32 c = i;
-    for (int k = 0; k < 8; ++k) c = (c & 1) ? 0xEDB88320u ^ (c >> 1) : c >> 1;
-    crc32_tab[i] = c;
-  }
-  crc32_init_done = true;
-}
-static u32 crc32_of(const u8* p, size_t n) {
-  crc32_init();
-  u32 c = 0xFFFFFFFFu;
-  for (size_t i = 0; i < n; ++i) c = crc32_tab[(c ^ p[i]) & 0xFF] ^ (c >> 8);
-  return c ^ 0xFFFFFFFFu;
-}
-static u32 adler32_of(const u8* p, size_t n) {
-  u32 a = 1, b = 0;
-  for (size_t i = 0; i < n; ++i) {
-    a = (a + p[i]) % 65521;
-    b = (b + a) % 65521;
-  }
-  return (b << 16) | a;
-}
-
-// lsm.py _bloom_hashes: crc32|adler32<<32 through two splitmix64 runs
-static void bloom_hashes(const std::string& key, u64* h1, u64* h2) {
-  const u8* p = (const u8*)key.data();
-  u64 x = u64(crc32_of(p, key.size())) | (u64(adler32_of(p, key.size())) << 32);
-  u64 z = x + 0x9E3779B97F4A7C15ULL;
-  z = (z ^ (z >> 30)) * 0xBF58476D1CE4E5B9ULL;
-  z = (z ^ (z >> 27)) * 0x94D049BB133111EBULL;
-  *h1 = z ^ (z >> 31);
-  z = x + 0x3C6EF372FE94F82AULL;
-  z = (z ^ (z >> 30)) * 0xBF58476D1CE4E5B9ULL;
-  z = (z ^ (z >> 27)) * 0x94D049BB133111EBULL;
-  *h2 = (z ^ (z >> 31)) | 1;
-}
-
 struct SstWriter {
   FILE* f = nullptr;
   u64 ts = 0, seq = 0, n = 0;
@@ -787,7 +749,7 @@ struct SstWriter {
     if (n % 64 == 0) index.emplace_back(key, u64(ftello(f)));
     if (key != last_key) {
       u64 a, b;
-      bloom_hashes(key, &a, &b);
+      sst_bloom_hashes((const u8*)key.data(), key.size(), &a, &b);
       h1s.push_back(a);
       h2s.push_back(b);
       last_key = key;
@@ -817,12 +779,8 @@ struct SstWriter {
     u64 nbits = ((nk * 10 + 7) / 8) * 8;  // _BLOOM_BITS_PER_KEY=10
     std::vector<u8> bits(nbits / 8, 0);
     for (size_t i = 0; i < h1s.size(); ++i)
-      for (int k = 0; k < 3; ++k) {  // _BLOOM_HASHES=3
-        // Python evaluates (h1 + k*h2) % nbits in arbitrary precision
-        // — match it with 128-bit math, NOT 64-bit wraparound
-        unsigned __int128 probe =
-            (unsigned __int128)h1s[i] + (unsigned __int128)h2s[i] * k;
-        u64 b = u64(probe % nbits);
+      for (int k = 0; k < SST_BLOOM_HASHES; ++k) {
+        u64 b = sst_bloom_bit(h1s[i], h2s[i], k, nbits);
         bits[b >> 3] |= u8(1 << (b & 7));
       }
     fwrite(bits.data(), 1, bits.size(), f);

@@ -21,6 +21,8 @@
 #include <utility>
 #include <vector>
 
+#include "sst_bloom.h"
+
 #if defined(__AVX512VNNI__) || defined(__AVX512BW__) || defined(__AVX2__)
 #include <immintrin.h>
 #endif
@@ -957,27 +959,77 @@ int64_t sst_versions(const uint8_t* buf, int64_t end, int64_t off,
     return n;
 }
 
-// Entry headers from `off` while keys start with `prefix` (or all when
-// prefix_len == 0): writes (key_off, key_len, ts, seq, val_off, val_len);
-// returns count (callers loop with growing max_out).
-// Versions of MANY sorted distinct keys in one pass. `starts[i]` is a
-// seek hint at/before key i's first possible entry (sparse-index stride
-// head); since keys ascend, the walk position is monotone — the scan for
-// key i begins at max(current pos, starts[i]). Outputs are flattened:
-// counts[i] versions for key i, written sequentially into tss/seqs/
-// voffs/vlens. Returns total versions written, or -(needed) if max_out
-// was too small (caller re-runs with a bigger buffer).
-int64_t sst_versions_multi(const uint8_t* buf, int64_t end, int64_t nkeys,
-                           const uint8_t* keys_blob, const int64_t* key_offs,
-                           const int64_t* key_lens, const int64_t* starts,
-                           int64_t max_out, int64_t* counts, uint64_t* tss,
-                           uint64_t* seqs, int64_t* voffs, int64_t* vlens) {
+// Versions of MANY sorted distinct keys in one pass, the table's own
+// pruning included, so that no Python runs per key before the call
+// (storage/lsm.py _SSTable.versions_of_many): a key outside [first index
+// key, max key] or refused by the bloom filter gets count 0 untouched;
+// the others seek from the sparse index's stride head (the last index
+// key strictly below the key: a key's versions may begin in the stride
+// before the head that equals it). Since keys ascend, the walk position
+// is monotone.
+//
+// `table` is what does not change for a table, ten words the wrapper
+// builds once: the mapped file's address and its data end; the bloom's
+// bits (0: a table from before blooms) and their count; the sparse index
+// as its keys laid end to end, their n_idx + 1 prefix offsets, their file
+// offsets, and n_idx; the max key and its length. The probe keys are laid
+// end to end in `keys_blob`, key i ending at `key_ends[i]`. `out` is one
+// u64 buffer of nkeys + 4 * max_out words: counts[i] versions for key i,
+// then (ts, seq, value offset, value length) per version, in key order.
+// Returns total versions written, or -(needed) if max_out was too small
+// (caller re-runs with a bigger buffer). Six arguments, all plain words:
+// the marshalling of nineteen cost more than a small probe's work.
+int64_t sst_versions_multi(const void* table, int64_t nkeys,
+                           const void* keys_blob_v, const void* key_ends_v,
+                           int64_t max_out, void* out_v) {
+    const int64_t* tb = (const int64_t*)table;
+    const uint8_t* buf = (const uint8_t*)tb[0];
+    const int64_t end = tb[1];
+    const uint8_t* bloom = (const uint8_t*)tb[2];
+    const int64_t bloom_nbits = tb[3];
+    const uint8_t* idx_keys = (const uint8_t*)tb[4];
+    const int64_t* idx_koffs = (const int64_t*)tb[5];
+    const int64_t* idx_foffs = (const int64_t*)tb[6];
+    const int64_t n_idx = tb[7];
+    const uint8_t* max_key = (const uint8_t*)tb[8];
+    const int64_t max_klen = tb[9];
+    const uint8_t* keys_blob = (const uint8_t*)keys_blob_v;
+    const int64_t* key_ends = (const int64_t*)key_ends_v;
+    uint64_t* counts = (uint64_t*)out_v;
+    uint64_t* recs = counts + nkeys;
     int64_t pos = 0;
     int64_t out = 0;
     for (int64_t i = 0; i < nkeys; i++) {
-        const uint8_t* key = keys_blob + key_offs[i];
-        int64_t klen = key_lens[i];
-        if (starts[i] > pos) pos = starts[i];
+        int64_t koff = i ? key_ends[i - 1] : 0;
+        const uint8_t* key = keys_blob + koff;
+        int64_t klen = key_ends[i] - koff;
+        counts[i] = 0;
+        if (n_idx == 0 ||
+            keycmp(key, klen, idx_keys, idx_koffs[1] - idx_koffs[0]) < 0 ||
+            keycmp(key, klen, max_key, max_klen) > 0)
+            continue;
+        if (bloom != nullptr) {
+            uint64_t h1, h2;
+            sst_bloom_hashes(key, (size_t)klen, &h1, &h2);
+            bool hit = true;
+            for (int j = 0; j < SST_BLOOM_HASHES && hit; j++) {
+                uint64_t b = sst_bloom_bit(h1, h2, j, (uint64_t)bloom_nbits);
+                hit = bloom[b >> 3] & (1 << (b & 7));
+            }
+            if (!hit) continue;
+        }
+        // index keys strictly below `key`: [0, lo)
+        int64_t lo = 0, hi = n_idx;
+        while (lo < hi) {
+            int64_t mid = (lo + hi) >> 1;
+            if (keycmp(idx_keys + idx_koffs[mid],
+                       idx_koffs[mid + 1] - idx_koffs[mid], key, klen) < 0)
+                lo = mid + 1;
+            else
+                hi = mid;
+        }
+        int64_t start = idx_foffs[lo > 0 ? lo - 1 : 0];
+        if (start > pos) pos = start;
         int64_t p = sst_seek(buf, end, pos, key, klen);
         int64_t n = 0;
         while (p + 24 <= end) {
@@ -985,20 +1037,24 @@ int64_t sst_versions_multi(const uint8_t* buf, int64_t end, int64_t nkeys,
             int64_t body = ent_read(buf, p, &kl, &ts, &seq, &vl);
             if (keycmp(buf + body, kl, key, klen) != 0) break;
             if (out + n >= max_out) return -(out + n + 1);
-            tss[out + n] = ts;
-            seqs[out + n] = seq;
-            voffs[out + n] = body + kl;
-            vlens[out + n] = vl;
+            uint64_t* r = recs + 4 * (out + n);
+            r[0] = ts;
+            r[1] = seq;
+            r[2] = (uint64_t)(body + kl);
+            r[3] = vl;
             n++;
             p = body + kl + vl;
         }
-        counts[i] = n;
+        counts[i] = (uint64_t)n;
         out += n;
         pos = p;
     }
     return out;
 }
 
+// Entry headers from `off` while keys start with `prefix` (or all when
+// prefix_len == 0): writes (key_off, key_len, ts, seq, val_off, val_len);
+// returns count (callers loop with growing max_out).
 int64_t sst_scan(const uint8_t* buf, int64_t end, int64_t off,
                  const uint8_t* prefix, int64_t prefix_len, int64_t max_out,
                  int64_t* key_offs, int64_t* key_lens, uint64_t* tss,

@@ -19,7 +19,8 @@ import threading
 from collections import OrderedDict
 from typing import Dict, Iterable, Optional, Tuple
 
-from dgraph_tpu.posting.pl import PostingList
+from dgraph_tpu.posting.pl import PostingList, decode_cold
+from dgraph_tpu.utils.observe import METRICS, add_span_attr
 
 
 class MemoryLayer:
@@ -77,22 +78,37 @@ class MemoryLayer:
         cache future versions under an old ts. Complete entries skip the
         probe while the store is unchanged (_fast_state)."""
         seq, complete = self._fast_state(kv, read_ts)
+        hit = None
         with self._lock:
             got = self._cache.get(key)
             if got is not None and self._fast_hit(got, seq, read_ts):
                 self._cache.move_to_end(key)
-                self.hits += 1
-                return got[1]
-        versions = kv.versions(key, read_ts)
-        newest_ts = versions[0][0] if versions else 0
-        with self._lock:
-            got = self._cache.get(key)
-            if got is not None and got[0] == newest_ts:
-                self._cache[key] = (newest_ts, got[1], seq, read_ts, complete)
-                self._cache.move_to_end(key)
-                self.hits += 1
-                return got[1]
+                hit = got
+        if hit is None:
+            if hasattr(kv, "versions_batch"):
+                # one miss path: the store's unlocked probe, the one-pass
+                # decoder, one acquisition of the lock (read_many)
+                return self.read_many(kv, (key,), read_ts)[key]
+            versions = kv.versions(key, read_ts)
+            newest_ts = versions[0][0] if versions else 0
+            with self._lock:
+                got = self._cache.get(key)
+                if got is not None and got[0] == newest_ts:
+                    self._cache[key] = (
+                        newest_ts, got[1], seq, read_ts, complete
+                    )
+                    self._cache.move_to_end(key)
+                    hit = got
+        if hit is not None:
+            self.hits += 1
+            METRICS.inc("memlayer_hits_total")
+            return hit[1]
         self.misses += 1
+        METRICS.inc_many({
+            "memlayer_misses_total": 1,
+            'level_cold_keys_total{path="general"}': 1,
+        })
+        add_span_attr("cold", 1)
         pl = PostingList.from_versions(key, versions, kv=kv, read_ts=read_ts)
         with self._lock:
             self._cache[key] = (newest_ts, pl, seq, read_ts, complete)
@@ -105,60 +121,93 @@ class MemoryLayer:
         """Batched read-through: one kv.versions_batch for every key (the
         LSM backend probes each table monotonically instead of per-key).
         Returns {key: PostingList}. Falls back to per-key read when the
-        backend has no batch API."""
+        backend has no batch API.
+
+        The lock is taken ONCE a level, at the end, for the LRU touches
+        and the new entries, with nothing but dict operations inside it:
+        sixteen readers that each held it three times a level, loops and
+        all, queued behind one another (PERF.md, PR 35). The look-ups
+        before it are single `dict.get`s of immutable entries, each
+        atomic under the interpreter's lock, and an entry that is
+        dropped meanwhile is as valid for this reader as one dropped
+        just after a locked look-up was."""
         keys = list(dict.fromkeys(keys))  # dedupe: decode each key once
         vb = getattr(kv, "versions_batch", None)
         if vb is None:
             return {k: self.read(kv, k, read_ts) for k in keys}
         seq, complete = self._fast_state(kv, read_ts)
+        cache = self._cache
         out = {}
         need = []
-        with self._lock:
-            for k in keys:
-                ent = self._cache.get(k)
-                if ent is not None and self._fast_hit(ent, seq, read_ts):
-                    self._cache.move_to_end(k)
-                    self.hits += 1
-                    out[k] = ent[1]
-                else:
-                    need.append(k)
-        if not need:
-            return out
-        got = vb(need, read_ts)
-        to_store = []
-        with self._lock:
+        for k in keys:
+            ent = cache.get(k)
+            # _fast_hit, spelled out: a call a key is a fifth of a hit
+            if (
+                ent is not None and seq is not None and ent[2] == seq
+                and ent[4] and read_ts >= ent[3]
+            ):
+                out[k] = ent[1]
+            else:
+                need.append(k)
+        hit_keys = list(out) if need else keys
+        fresh = []  # (key, entry) to publish
+        fast = general = 0
+        if need:
+            got = vb(need, read_ts)
+            # the decoder beside the general one: for a plaintext store
+            # probed natively (an encrypted store, a process without
+            # the native library and MemKV keep the one decoder)
+            cold = decode_cold if getattr(kv, "native_probe", False) else None
             for k in need:
-                versions = got.get(k, [])
+                versions = got.get(k, ())
                 newest_ts = versions[0][0] if versions else 0
-                ent = self._cache.get(k)
+                ent = cache.get(k)
                 if ent is not None and ent[0] == newest_ts:
-                    self._cache[k] = (newest_ts, ent[1], seq, read_ts, complete)
-                    self._cache.move_to_end(k)
-                    self.hits += 1
-                    out[k] = ent[1]
+                    pl = ent[1]  # unchanged since it was decoded
                 else:
-                    out[k] = None  # decode outside the lock
-                    to_store.append((k, newest_ts, versions))
-        # one decode loop outside the lock, then ONE lock acquisition to
-        # publish the whole level's entries (level-batched fan-out: the
-        # per-key lock round-trips dominated wide levels)
-        decoded = []
-        for k, newest_ts, versions in to_store:
-            pl = PostingList.from_versions(
-                k, versions, kv=kv, read_ts=read_ts
-            )
-            out[k] = pl
-            decoded.append((k, newest_ts, pl))
-        if decoded:
-            with self._lock:
-                self.misses += len(decoded)
-                for k, newest_ts, pl in decoded:
-                    self._cache[k] = (
-                        newest_ts, pl, seq, read_ts, complete
-                    )
-                    self._cache.move_to_end(k)
-                while len(self._cache) > self.max_entries:
-                    self._cache.popitem(last=False)
+                    pl = None
+                    if cold is not None:
+                        if not versions:
+                            pl = PostingList(k)  # no record, none to decode
+                        elif len(versions) == 1:
+                            pl = cold(k, newest_ts, versions[0][1])
+                    if pl is None:
+                        pl = PostingList.from_versions(
+                            k, versions, kv=kv, read_ts=read_ts
+                        )
+                        general += 1
+                    else:
+                        fast += 1
+                out[k] = pl
+                fresh.append((k, (newest_ts, pl, seq, read_ts, complete)))
+        missed = fast + general
+        with self._lock:
+            try:
+                for k in hit_keys:
+                    cache.move_to_end(k)
+            except KeyError:
+                # dropped since the look-up: a commit, a tablet move, the LRU
+                for k in hit_keys:
+                    if k in cache:
+                        cache.move_to_end(k)
+            if fresh:
+                for k, ent in fresh:
+                    cache[k] = ent
+                    cache.move_to_end(k)
+                while len(cache) > self.max_entries:
+                    cache.popitem(last=False)
+        self.hits += len(keys) - missed
+        if not missed:
+            METRICS.inc("memlayer_hits_total", len(keys))
+            return out
+        self.misses += missed
+        METRICS.inc_many({
+            "memlayer_hits_total": len(keys) - missed,
+            "memlayer_misses_total": missed,
+            'level_cold_keys_total{path="fast"}': fast,
+            'level_cold_keys_total{path="general"}': general,
+        })
+        add_span_attr("cold", missed)
         return out
 
     def invalidate(self, keys: Iterable[bytes]):

@@ -351,6 +351,64 @@ def decode_record(data: bytes):
     return KIND_DELTA, None, postings, []
 
 
+_REC_HEAD = struct.Struct("<BI")  # kind, pack bytes (rollup) or postings
+_POST_HEAD = struct.Struct("<BQBBI")  # flags, uid, type, lang len, value len
+_U16 = struct.Struct("<H")
+_U32 = struct.Struct("<I")
+_TYPE_OF = {int(t): t for t in TypeID}
+
+
+def decode_cold(key: bytes, ts: int, rec: bytes) -> Optional["PostingList"]:
+    """The PostingList of a key whose ONE visible version is `rec`, for
+    the shape every bulk-loaded or rolled-up key has, else None: a
+    KIND_ROLLUP record, no split, postings without facets or language.
+    It decodes what such a record holds and not what a record can hold:
+    the pack through `uidpack.deserialize_small` where it is empty or a
+    few uids (else through `deserialize`), a posting with one struct
+    read, and ONE bounds check for the whole record: a read past the end
+    raises `struct.error`, and a slice cut short shows in the final
+    position. Whatever it does not recognise (a delta, facets, `@lang`,
+    splits, trailing or missing bytes, an unknown type id, a corrupt
+    pack) it hands back as None, undecoded, and the caller takes
+    `PostingList.from_versions`: `decode_record` stays the reference
+    and the one place a corrupt record is reported from."""
+    try:
+        kind, plen = _REC_HEAD.unpack_from(rec, 0)
+        if kind != KIND_ROLLUP:
+            return None
+        # the postings first: a record with facets (every `knows` list
+        # of a social graph) is handed back before its pack is touched
+        pos = 5 + plen
+        (cnt,) = _U32.unpack_from(rec, pos)
+        pos += 4
+        posts = []
+        for _ in range(cnt):
+            flags, uid, tid, llen, vlen = _POST_HEAD.unpack_from(rec, pos)
+            end = pos + 15 + vlen
+            if llen or _U16.unpack_from(rec, end)[0]:
+                return None  # @lang or facets
+            posts.append(
+                Posting(
+                    uid, (flags >> 1) & 0x3,
+                    rec[pos + 15 : end] if flags & 1 else None,
+                    _TYPE_OF[tid],
+                )
+            )
+            pos = end + 2
+        if pos != len(rec) and (
+            pos + 4 != len(rec) or _U32.unpack_from(rec, pos)[0]
+        ):
+            return None  # split starts, or bytes that are not the tail
+        got = uidpack.deserialize_small(rec, 5, plen)
+        if got is None:
+            got = (uidpack.deserialize(rec[5 : 5 + plen]), None)
+    except (struct.error, KeyError, ValueError):
+        return None
+    pl = PostingList(key, pack=got[0], value_postings=posts, min_ts=ts)
+    pl._uids_cache = got[1]
+    return pl
+
+
 def rollup_writes(
     key: bytes, uids: np.ndarray, posts: List[Posting], ts: int
 ) -> List[Tuple[bytes, int, bytes]]:
@@ -378,9 +436,9 @@ def rollup_writes(
                 encode_rollup(uidpack.encode(chunk), []),
             )
         )
-    empty = uidpack.encode(np.zeros((0,), np.uint64))
     writes.append(
-        (key, ts, encode_rollup(empty, list(posts), split_starts=starts))
+        (key, ts,
+         encode_rollup(uidpack.EMPTY, list(posts), split_starts=starts))
     )
     return writes
 
@@ -407,14 +465,16 @@ class PostingList:
         min_ts: int = 0,
     ):
         self.key = key
-        self.pack = pack or uidpack.encode(np.zeros((0,), np.uint64))
+        self.pack = pack if pack is not None else uidpack.EMPTY
         self.value_postings = value_postings or []
         # committed deltas above the rollup, ascending commit_ts
         self.deltas = deltas or []
         self.min_ts = min_ts  # ts of the rollup layer
         # newest version ts this list was built from — the identity used by
         # the device pack cache (key, latest_ts); 0 = empty/unknown
-        self.latest_ts = max((ts for ts, _ in self.deltas), default=min_ts)
+        self.latest_ts = (
+            max(ts for ts, _ in self.deltas) if self.deltas else min_ts
+        )
         self._uids_cache: Optional[np.ndarray] = None
         # multi-part list: per-part uid packs in ascending start-uid order
         # (the main record's pack is empty then; ref posting/list.go:519
@@ -689,5 +749,6 @@ class PostingList:
             parts.append(
                 (int(chunk[0]), encode_rollup(uidpack.encode(chunk), []))
             )
-        empty = uidpack.encode(np.zeros((0,), np.uint64))
-        return encode_rollup(empty, posts, split_starts=starts), ts, parts
+        return (
+            encode_rollup(uidpack.EMPTY, posts, split_starts=starts), ts, parts
+        )

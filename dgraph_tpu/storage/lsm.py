@@ -37,6 +37,7 @@ import zlib
 from array import array
 from typing import Dict, Iterator, List, Optional, Tuple
 
+from dgraph_tpu import native as _native
 from dgraph_tpu.storage.kv import KV
 
 _ENT = struct.Struct("<IQQI")  # key_len, ts, seq, val_len
@@ -190,8 +191,6 @@ class _SSTable:
         self._f = open(path, "rb")
         self._mm = mmap.mmap(self._f.fileno(), 0, access=mmap.ACCESS_READ)
         # native scan fast path (plaintext tables only)
-        from dgraph_tpu import native as _native
-
         self._native = enc_key is None and _native.sst_available()
         self._buf = (
             __import__("numpy").frombuffer(self._mm, dtype="uint8")
@@ -233,6 +232,19 @@ class _SSTable:
         self.min_key = self._index[0][0] if self._index else b""
         self.max_key = None  # lazily: last entry's key
         self._data_end = idx_off
+        # what sst_versions_multi needs of this table, built ONCE: the
+        # bloom's bits and the sparse index as flat arrays, so the range
+        # test, the bloom test and the index seek of a batched probe run
+        # in native code (the arrays live as long as the table)
+        self._probe_addr, self._probe_keep = (
+            _native.sst_probe_table(
+                self._buf, self._data_end,
+                self.bloom.bits if self.bloom is not None else None,
+                self._index, self._max_key(),
+            )
+            if self._native
+            else (None, None)
+        )
 
     @staticmethod
     def write(
@@ -345,8 +357,6 @@ class _SSTable:
         if not self.may_contain(key):
             return []
         if self._native:
-            from dgraph_tpu import native as _native
-
             start = self._index_start(key)
             tss, seqs, voffs, vlens = _native.sst_versions(
                 self._buf, self._data_end, start, key, bptr=self._buf_ptr
@@ -373,48 +383,42 @@ class _SSTable:
 
     def versions_of_many(self, keys_sorted: List[bytes]):
         """Batched versions_of over SORTED distinct keys: ONE native call
-        walks the table monotonically (badger MultiGet shape). Returns
-        {key: [(ts, seq, val)]} for present keys only. Falls back to
-        per-key probes without the native library."""
+        prunes (key range, bloom), seeks the sparse index and walks the
+        table monotonically (badger MultiGet shape), with no Python per
+        key before it. Returns {key: [(ts, seq, val)]} for present keys
+        only. Falls back to per-key probes without the native library
+        and on encrypted tables. The table is immutable: a caller that
+        holds a reference (`retain`) needs no lock."""
+        out = {}
         if not self._native:
-            out = {}
             for k in keys_sorted:
                 got = self.versions_of(k)
                 if got:
                     out[k] = got
             return out
-        import numpy as _np
-
-        from dgraph_tpu import native as _native
-
-        cands = [k for k in keys_sorted if self.may_contain(k)]
-        if not cands:
-            return {}
-        starts = _np.fromiter(
-            (self._index_start(k) for k in cands), _np.int64, len(cands)
-        )
-        counts, tss, seqs, voffs, vlens = _native.sst_versions_multi(
-            self._buf_ptr, self._data_end, cands, starts,
-            max(1024, 4 * len(cands)),
-        )
-        out = {}
-        off = 0
+        if not keys_sorted:
+            return out
+        flat = _native.sst_versions_multi(self._probe_addr, keys_sorted)
         mm = self._mm
-        for k, n in zip(cands, counts):
-            if n:
+        j = len(keys_sorted)  # the records follow the counts
+        for k, n in zip(keys_sorted, flat):
+            if n == 1:  # the bulk-loaded and the compacted shape
+                vo = flat[j + 2]
+                out[k] = [(flat[j], flat[j + 1], mm[vo : vo + flat[j + 3]])]
+                j += 4
+            elif n:
+                end = j + 4 * n
                 out[k] = [
-                    (int(tss[off + j]), int(seqs[off + j]),
-                     mm[voffs[off + j] : voffs[off + j] + vlens[off + j]])
-                    for j in range(n)
+                    (flat[i], flat[i + 1],
+                     mm[flat[i + 2] : flat[i + 2] + flat[i + 3]])
+                    for i in range(j, end, 4)
                 ]
-            off += n
+                j = end
         return out
 
     def scan(self, prefix: bytes = b""):
         """Yield (key, ts, seq, val) ascending from the first prefixed key."""
         if self._native:
-            from dgraph_tpu import native as _native
-
             start = self._index_start(prefix) if prefix else 0
             if prefix:
                 start = _native.sst_seek(
@@ -473,6 +477,11 @@ class LsmKV(KV):
         self.memtable_bytes = memtable_bytes
         self.compact_at = compact_at
         self.enc_key = enc_key
+        # a plaintext store probed by the native library: what the level
+        # reads' one-pass decoder is for (posting/memlayer.py); an
+        # encrypted store, and a process without the library, keep to
+        # the general decoder
+        self.native_probe = enc_key is None and _native.sst_available()
         self._mu = threading.RLock()
         # key -> [(ts, seq, val)] ascending ts
         self._mem: Dict[bytes, List[Tuple[int, int, bytes]]] = {}
@@ -784,29 +793,63 @@ class LsmKV(KV):
         """versions() for many keys with one monotone probe pass per table
         — the read path for level-batched query fan-out (badger MultiGet
         analog; kills the per-key re-seek that dominated 2-hop queries on
-        this backend)."""
+        this backend).
+
+        Under the store's lock only what must be consistent is taken: the
+        list of tables (each retained, so a flush or a compaction that
+        runs meanwhile leaves this probe on the tables it started with),
+        the memtable's entries for the asked keys and the markers that
+        can touch them. The tables are immutable, so the probe itself
+        runs unlocked: sixteen readers no longer take turns at it."""
         ks = sorted(set(keys_in))
         with self._mu:
-            per_key: Dict[bytes, Dict[int, Tuple[int, bytes]]] = {}
-            for t in self._tables:
-                for k, vers in t.versions_of_many(ks).items():
-                    _resolve_versions(
-                        per_key.setdefault(k, {}), k, vers, self._visible
-                    )
-            for k in ks:
-                vs = self._mem.get(k)
-                if vs:
-                    _resolve_versions(
-                        per_key.setdefault(k, {}), k, vs, self._visible
-                    )
-            out: Dict[bytes, List[Tuple[int, bytes]]] = {}
-            for k, d in per_key.items():
-                out[k] = [
-                    (ts, d[ts][1])
-                    for ts in sorted(d, reverse=True)
-                    if ts <= read_ts
-                ]
-            return out
+            tables = list(self._tables)
+            for t in tables:
+                t.retain()
+            mem = self._mem
+            mem_vers = (
+                [(k, list(mem[k])) for k in ks if k in mem] if mem else ()
+            )
+            drops = list(self._drops)
+            delbelow = (
+                {k: list(self._delbelow[k]) for k in ks
+                 if k in self._delbelow}
+                if self._delbelow else None
+            )
+        try:
+            found = [t.versions_of_many(ks) for t in tables]
+        finally:
+            for t in tables:
+                t.release()
+        if mem_vers:
+            found.append(dict(mem_vers))
+        recs = found[0] if found else {}
+        for src in found[1:]:
+            for k, vers in src.items():
+                got = recs.get(k)
+                recs[k] = vers if got is None else got + vers
+
+        def visible(key, ts, seq):
+            return _marker_visible(drops, delbelow or {}, key, ts, seq)
+
+        out: Dict[bytes, List[Tuple[int, bytes]]] = {}
+        for k, vers in recs.items():
+            if len(vers) == 1 and not drops and not (
+                delbelow and k in delbelow
+            ):
+                # one record and no marker that can hide it: nothing to
+                # resolve (the bulk-loaded and the compacted shape)
+                ts, _, val = vers[0]
+                out[k] = [(ts, val)] if ts <= read_ts else []
+                continue
+            d: Dict[int, Tuple[int, bytes]] = {}
+            _resolve_versions(d, k, vers, visible)
+            out[k] = [
+                (ts, d[ts][1])
+                for ts in sorted(d, reverse=True)
+                if ts <= read_ts
+            ]
+        return out
 
     def _merged_keys(self, prefix: bytes) -> Iterator[bytes]:
         import heapq

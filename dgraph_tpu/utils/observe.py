@@ -977,6 +977,17 @@ _CURRENT: "ContextVar[Optional[object]]" = ContextVar(
     "dgraph_tpu_current_span", default=None
 )
 
+
+def add_span_attr(name: str, delta: float) -> None:
+    """Add `delta` to a numeric attr of the innermost open span of this
+    context, for a layer that is called INSIDE a span it does not open
+    (the MemoryLayer under `level_task`: `cold`). Dropped, like any
+    attr, where the context is untraced."""
+    attrs = getattr(_CURRENT.get(), "attrs", None)
+    if attrs is not None:
+        attrs[name] = attrs.get(name, 0) + delta
+
+
 def _covered(start: float, end: float, kids: list) -> float:
     """Seconds of [start, end] that the children's intervals cover."""
     covered = 0.0
@@ -2851,6 +2862,26 @@ declare_metric(
 declare_metric(
     "counter", "level_tasks_started",
     "Vectorized (predicate, level) tasks started by the executor.",
+)
+declare_metric(
+    "counter", "memlayer_hits_total",
+    "Posting-list keys a read found decoded in the MemoryLayer (an entry "
+    "valid at the reader's read_ts, with or without a probe of the store).",
+)
+declare_metric(
+    "counter", "memlayer_misses_total",
+    "Posting-list keys a read had to fetch from the store and decode "
+    "(hits + misses = keys asked of the MemoryLayer).",
+)
+declare_metric(
+    "counter", "level_cold_keys_total",
+    "Keys a MemoryLayer miss decoded, by path: fast (one visible version, "
+    "a plain rollup record in a plaintext table, decoded by "
+    "posting/pl.decode_cold) or general (PostingList.from_versions: deltas, "
+    "facets, @lang, splits, encrypted tables, no native library, MemKV). "
+    "Summed over both paths it equals memlayer_misses_total; the span a "
+    "miss happens under (level_task; process for a root function's or an "
+    "order's own reads) carries the same count as its attr `cold`.",
 )
 declare_metric(
     "counter", "metrics_scrape_errors_total",
