@@ -274,6 +274,12 @@ class Server:
         # slow-query threshold (instance override of the registry knob)
         self.slow_query_ms = float(_config.get("SLOW_QUERY_MS"))
         self.mem = MemoryLayer()  # shared decoded-list read cache
+        # resident value columns (query/valcol.py): this engine tells
+        # them of a commit before its timestamp is readable
+        # (_columns_commit, ahead of every watermark move)
+        from dgraph_tpu.query.valcol import ValueColumns
+
+        self.mem.value_columns = ValueColumns()
         from dgraph_tpu.utils.cmsketch import StatsHolder
 
         self.stats = StatsHolder()  # selectivity stats (auto-fed on commit)
@@ -448,6 +454,7 @@ class Server:
         # watermark reads see the restored store from the first query
         # (max()-guarded: online restore can run beside live commits)
         self._snapshot_ts = max(self._snapshot_ts, self.zero.read_ts())
+        self._columns_reset()
         self.rebuild_vector_indexes()
 
     def rebuild_vector_indexes(self):
@@ -481,6 +488,7 @@ class Server:
             self._snapshot_ts = max(
                 self._snapshot_ts, self.zero.next_ts()
             )
+            self._columns_reset()
 
     def _alter_inner(self, schema_text, drop_attr, drop_all):
         with self._lock:
@@ -536,6 +544,7 @@ class Server:
         leased after our read_ts may publish a larger watermark before
         this assignment runs."""
         self._snapshot_ts = max(self._snapshot_ts, self.zero.read_ts())
+        self._columns_reset()
         return self._snapshot_ts
 
     def _ensure_vector_index(self, su):
@@ -703,6 +712,7 @@ class Server:
             try:
                 with self._lock:
                     for m in committed:
+                        self._columns_commit(m.txn, m.commit_ts)
                         # watermark BEFORE the apply barrier, advanced
                         # in commit-ts order (members cts-ascending,
                         # barriers FIFO) — the micro-batcher's
@@ -739,6 +749,21 @@ class Server:
                 commit_phase_ns(apply=time.perf_counter_ns() - tb)
 
         return barrier
+
+    def _columns_commit(self, txn: Txn, commit_ts: int) -> None:
+        """Tell the value columns which keys `commit_ts` wrote, before
+        the watermark lets a reader see them (query/valcol.py)."""
+        cols = self.mem.value_columns
+        cols.note_commit(txn.cache.deltas.keys(), commit_ts)
+        ck = getattr(txn, "col_keys", None)
+        if ck:
+            cols.note_commit(ck, commit_ts)
+
+    def _columns_reset(self) -> None:
+        """The store was written past the commit path (a bulk load, an
+        alter, a restore): every column goes, and none is built from a
+        view older than the new watermark."""
+        self.mem.value_columns.clear(self._snapshot_ts)
 
     def _post_commit(self, txn: Txn, commit_ts: int) -> None:
         """Per-txn post-commit work on the committer's own thread
@@ -784,6 +809,7 @@ class Server:
                 txn.write_deltas(self.kv, commit_ts)
             finally:
                 t2 = time.perf_counter_ns()
+                self._columns_commit(txn, commit_ts)
                 # watermark BEFORE the apply barrier: any read_ts
                 # allocated after this commit becomes visible observes
                 # the advanced watermark (micro-batcher snapshot key);
@@ -1626,6 +1652,9 @@ class Server:
                 sp.attrs["order_kept"] = ex.order_tally["order_kept_total"]
                 sp.attrs["order_buckets"] = ex.order_tally[
                     "order_buckets_total"]
+            if ex.column_tally:
+                sp.attrs["column_cands"] = ex.column_tally["cands"]
+                sp.attrs["column_kept"] = ex.column_tally["kept"]
         with observe.TRACER.span("encode", cpu=True, fine=True) as sp:
             data, enc_stats = encode_response_data(
                 nodes, val_vars=ex.val_vars, schema=self.schema, want=want

@@ -206,6 +206,29 @@ class LocalCache:
             for k in keys_list
         ]
 
+    def scan_values(self, prefix: bytes):
+        """(key, value) of every key under a data prefix that holds an
+        untagged scalar value at this read timestamp, in key order: one
+        pass over the store, each record decoded once and dropped (a
+        value column's build, query/valcol.py: a predicate's worth of
+        lists would push the working set out of the MemoryLayer). The
+        caller has checked that this txn holds no delta under the
+        prefix."""
+        from dgraph_tpu.posting.pl import decode_cold
+
+        kv, read_ts = self.kv, self.read_ts
+        for k, versions in kv.iterate_versions(prefix, read_ts):
+            pl = None
+            if len(versions) == 1:
+                pl = decode_cold(k, versions[0][0], versions[0][1])
+            if pl is None:
+                pl = PostingList.from_versions(
+                    k, versions, kv=kv, read_ts=read_ts
+                )
+            v = pl.get_value("", None)
+            if v is not None:
+                yield k, v
+
     def packed_operand(self, key: bytes):
         """The posting list as a compressed-domain dispatcher operand
         (query/dispatch.PackedOperand), or None when any uid delta —

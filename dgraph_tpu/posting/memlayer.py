@@ -40,6 +40,10 @@ class MemoryLayer:
         )
         self.hits = 0
         self.misses = 0
+        # query/valcol.ValueColumns, set by an engine that tells it of
+        # every commit before the commit is readable (api/server.py);
+        # None: no resident value columns over this store
+        self.value_columns = None
 
     @staticmethod
     def _fast_state(kv, read_ts: int):
@@ -236,6 +240,8 @@ class MemoryLayer:
         from dgraph_tpu.query.dispatch import DISPATCHER
 
         DISPATCHER.device_cache.invalidate_prefix(pfx)
+        if self.value_columns is not None:
+            self.value_columns.invalidate_prefix(pfx)
 
     def clear(self):
         with self._lock:

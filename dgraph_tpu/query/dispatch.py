@@ -33,7 +33,7 @@ import jax.numpy as jnp
 
 from dgraph_tpu.codec import uidpack
 from dgraph_tpu.codec.uidpack import join_segments, split_segments
-from dgraph_tpu.ops import packed_setops, setops
+from dgraph_tpu.ops import packed_setops, setops, valcol
 from dgraph_tpu.query import ragged
 from dgraph_tpu.utils.observe import METRICS, TRACER
 from dgraph_tpu.x import config, device
@@ -454,13 +454,16 @@ class SetOpDispatcher:
         return _HOST_ONLY if platform == "cpu" else _ACCEL_MIN_TOTAL
 
     def _run_device(
-        self, family: str, fetch, operands, ids: int, padded: int, keep=None
+        self, family: str, fetch, operands, ids: int, padded: int,
+        keep=None, spans: str = "setop",
     ) -> tuple:
         """What every device call shares after its operands are padded
         (`setop.pad`, at the call site, which also says how many real
         `ids` it pads to how many `padded` elements: counted here) and
         before its result is cut back into rows (`setop.split`, there
-        too): `setop.upload` of
+        too); a value column's programs open the same spans under
+        `valcol.` (`spans`), their launch naming its `use` where a set
+        op names its `family`: `setop.upload` of
         the operands still on the host — a numpy array, or a `_Sharded`
         one for the mesh; a device array is a DeviceCache hit — and,
         through `keep(operands as the device holds them)`, the
@@ -471,7 +474,7 @@ class SetOpDispatcher:
         program) and `setop.wait` (queueing behind other requests'
         programs, execution, download). Returns the outputs as numpy
         arrays."""
-        with TRACER.span("setop.upload", cpu=True, fine=True) as sp:
+        with TRACER.span(spans + ".upload", cpu=True, fine=True) as sp:
             nbytes = hits = misses = 0
             dev = []
             for x in operands:
@@ -491,13 +494,15 @@ class SetOpDispatcher:
             )
             if keep is not None:
                 keep(dev)
-        with TRACER.span(
-            "setop.launch", cpu=True, fine=True, family=family
-        ):
+        said = (
+            {"family": family} if spans == "setop"
+            else {"use": family.partition("#")[2]}
+        )
+        with TRACER.span(spans + ".launch", cpu=True, fine=True, **said):
             out = fetch()(*dev)
         # read for its wall time; its CPU time (the read-back's copy) is
         # taken too, so that it comes off the caller's self CPU time
-        with TRACER.span("setop.wait", cpu=True, fine=True) as sp:
+        with TRACER.span(spans + ".wait", cpu=True, fine=True) as sp:
             out = out if isinstance(out, tuple) else (out,)
             host = tuple(np.asarray(o) for o in out)
             down = sum(h.nbytes for h in host)
@@ -643,6 +648,39 @@ class SetOpDispatcher:
             if cached is not None:
                 return cached[0], b_key, pb
         return setops.pad_sorted(b64.astype(np.uint32), pb), b_key, pb
+
+    def run_column(
+        self, use: str, ids: np.ndarray, column: Optional[tuple], *scalars
+    ) -> tuple:
+        """One program of `ops/valcol.py` over `ids`, uint32 or float32,
+        in any order: `use` "filter" or "narrow" against a resident
+        value column, `column` = (uids and keys as the device holds
+        them, rows, padded rows) with the ids' low 32 bits (query/
+        valcol.py sees to the high ones); "scores" with no column, the
+        ids then being float32 scores. Padded as the flat form pads, to
+        a power of four, so a cell's requests share one program. Spans
+        `valcol.pad` / `.upload` / `.launch` / `.wait`, family
+        `column#<use>`. Returns the program's outputs, the per-id ones
+        still padded."""
+        with TRACER.span("valcol.pad", cpu=True, fine=True) as sp:
+            n = len(ids)
+            pa = _pow4(n)
+            fill = np.nan if use == "scores" else setops.UINT32_MAX
+            A = np.full((pa,), fill, dtype=ids.dtype)
+            A[:n] = ids
+            sp.attrs.update(ids=n, padded=pa)
+        held, pb = (), 0
+        if column is not None:
+            uids, keys_, rows, pb = column
+            held = (uids, np.int32(rows), keys_)
+        return self._run_device(
+            "column#" + use,
+            lambda: self._get_jitted_shared("column_" + use, pa, pb),
+            [A, np.int32(n), *held, *scalars],
+            n,
+            pa,
+            spans="valcol",
+        )
 
     def _union_rows_stacked(self, rows, b, row_tokens, b_token):
         """`union#shared`: a row's union needs the row's own output
@@ -983,7 +1021,8 @@ class SetOpDispatcher:
         """intersect / difference: ONE membership of the level's ids,
         flat (`pa` of them, padded), in `b`; the host keeps or drops by
         the mask. union: `setops.union` vmapped over a stack of rows
-        `pa` wide, `b` unbatched."""
+        `pa` wide, `b` unbatched. column_<use>: `ops/valcol.py`'s
+        program over `pa` flat ids and a column of `pb` rows."""
         key = (op + "#shared", pa, pb)
         fn = self._jit_cache.get(key)
         if fn is None:
@@ -994,6 +1033,13 @@ class SetOpDispatcher:
                         fn = jax.vmap(
                             setops.scoped("setop.union.shared", setops.union),
                             in_axes=(0, 0, None, None),
+                        )
+                    elif op.startswith("column_"):
+                        # a value column's program (`run_column`): the
+                        # column is the shared operand
+                        use = op[len("column_"):]
+                        fn = setops.scoped(
+                            "valcol." + use, valcol.KERNELS[use]
                         )
                     else:
                         fn = setops.scoped(

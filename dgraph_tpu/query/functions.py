@@ -51,7 +51,7 @@ class FuncRunner:
     def __init__(self, cache: LocalCache, st: State, ns: int = keys.GALAXY_NS,
                  vector_indexes=None, uid_vars=None, val_vars=None,
                  stats=None, ordered_uid_vars=None, batcher=None,
-                 planner=None):
+                 planner=None, tally=None):
         self.cache = cache
         self.st = st
         self.ns = ns
@@ -68,6 +68,9 @@ class FuncRunner:
         # their observed cardinalities back into its CardBook — the
         # estimate source for next queries' ordering decisions
         self.planner = planner
+        # the executor's count of what went through a value column
+        # (`Executor._tally_column`); None: nobody counts
+        self.tally = tally
 
     # -- helpers -------------------------------------------------------------
 
@@ -681,6 +684,10 @@ class FuncRunner:
                     break
         if sortable is not None and src is None:
             return self._range_scan(fn.attr, sortable, op, val)
+        if src is not None:
+            mask = self._column_mask(fn, src, [(op, val)])
+            if mask is not None:
+                return np.unique(np.asarray(src, np.uint64)[mask])
         cands = src if src is not None else self._scan_data_uids(fn.attr)
         out = []
         for u in cands:
@@ -699,6 +706,42 @@ class FuncRunner:
             ):
                 out.append(int(u))
         return _as_uids(out)
+
+    def _column_mask(self, fn: FuncSpec, ids, bounds) -> Optional[np.ndarray]:
+        """The inequality as a mask over `ids` (any order, repeats
+        allowed) from the predicate's resident value column, or None:
+        fewer candidates than the device line, a predicate or a request
+        no column serves (query/valcol.py)."""
+        from dgraph_tpu.query import valcol
+
+        mask = valcol.filter_mask(
+            self.cache, self.st, self.ns, fn.attr, fn.lang, ids, bounds
+        )
+        if mask is not None and self.tally is not None:
+            self.tally(len(ids), 0)
+        return mask
+
+    def column_filter_mask(self, fn: FuncSpec, ids) -> Optional[np.ndarray]:
+        """`fn` over a level's flat ids straight from the column, where
+        `fn` is one inequality or `between` on a stored predicate with
+        literal bounds; None sends the caller down the usual road."""
+        if fn.val_var or fn.is_count or fn.name not in (
+            "lt", "le", "gt", "ge", "between"
+        ):
+            return None
+        su = self.st.get(fn.attr)
+        if su is None or any(isinstance(a, tuple) for a in fn.args):
+            return None
+        ops = ("ge", "le") if fn.name == "between" else (fn.name,)
+        if len(fn.args) < len(ops):
+            return None
+        try:
+            bounds = [
+                (op, _coerce(a, su.value_type)) for op, a in zip(ops, fn.args)
+            ]
+        except (ValueError, TypeError):
+            return None  # the usual road raises it as it always has
+        return self._column_mask(fn, ids, bounds)
 
     def _range_scan(self, attr: str, tok, op: str, val: Val) -> np.ndarray:
         """Walk the sortable index range (ref worker/task.go:1881 eq-planning
@@ -765,6 +808,10 @@ class FuncRunner:
         return False
 
     def _between(self, fn: FuncSpec, src) -> np.ndarray:
+        if src is not None:
+            mask = self.column_filter_mask(fn, src)
+            if mask is not None:
+                return np.unique(np.asarray(src, np.uint64)[mask])
         lo = FuncSpec(name="ge", attr=fn.attr, args=[fn.args[0]], lang=fn.lang)
         hi = FuncSpec(name="le", attr=fn.attr, args=[fn.args[1]], lang=fn.lang)
         a = self._compare(lo, "ge", src)

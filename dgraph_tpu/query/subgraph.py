@@ -224,6 +224,10 @@ class Executor:
         # (sibling levels expand on pool threads, hence the lock)
         self._order_mu = threading.Lock()
         self.order_tally: Dict[str, int] = collections.Counter()
+        # ids this request gave a resident value column's programs
+        # (`cands`: filters and orders) and ids a column's narrowing
+        # left the comparator (`kept`); the `process` span carries both
+        self.column_tally: Dict[str, int] = collections.Counter()
         # cost-based planner (query/planner.py): whole-query evaluation
         # ordering + intersect-vs-filter strategy, observation-
         # equivalent by construction; None = declaration-order
@@ -251,7 +255,12 @@ class Executor:
             ordered_uid_vars=self.ordered_uid_vars,
             batcher=self.batcher,
             planner=self.planner,
+            tally=self._tally_column,
         )
+
+    def _tally_column(self, cands: int, kept: int) -> None:
+        with self._order_mu:
+            self.column_tally.update(cands=cands, kept=kept)
 
     # ------------------------------------------------------------------
     # Block orchestration (ref query.Request.Process query.go:3046)
@@ -268,6 +277,7 @@ class Executor:
 
     def process(self, blocks: List[GraphQuery]) -> List[ExecNode]:
         self.order_tally = collections.Counter()
+        self.column_tally = collections.Counter()
         try:
             return self._process(blocks)
         finally:
@@ -1157,19 +1167,30 @@ class Executor:
                 # materialization, no per-candidate verify). Sound
                 # because rows ⊆ merged makes rows ∩ match identical
                 # either way (query/planner.py pushdown_candidates).
+                # a lone inequality that the predicate's resident value
+                # column answers is a mask over the level's ids as they
+                # lie, row after row: no merged frontier, no
+                # intersection of the rows with what passed
+                mask = (
+                    self._runner().column_filter_mask(cgq.filter.func, flat)
+                    if cgq.filter.func is not None else None
+                )
                 cand = None
-                if self.planner is not None:
+                if mask is None and self.planner is not None:
                     cand = self.planner.pushdown_candidates(
                         cgq.filter, attr, int(len(flat)),
                         self._eval_filter_root,
                     )
-                if cand is None:
-                    cand = self.eval_filter(
-                        cgq.filter, ragged.merge_flat(flat, offs)
+                if mask is not None:
+                    flat, offs = ragged.apply_mask(flat, offs, mask)
+                else:
+                    if cand is None:
+                        cand = self.eval_filter(
+                            cgq.filter, ragged.merge_flat(flat, offs)
+                        )
+                    flat, offs = DISPATCHER.run_rows_vs_one_ragged(
+                        "intersect", flat, offs, cand, row_tokens=row_toks
                     )
-                flat, offs = DISPATCHER.run_rows_vs_one_ragged(
-                    "intersect", flat, offs, cand, row_tokens=row_toks
-                )
             lens = None
             # per-row Python features (edge facets, per-row ordering) still
             # walk rows: materialize zero-copy VIEWS into the flat buffer;
@@ -2252,11 +2273,40 @@ class Executor:
         )
         if got is not None:
             return "walked", got, kept, read
-        got = self._order_uids_generic(
-            gq, uids, ties_desc=o.desc and tk.is_lossy
-        )
+        ties_desc = o.desc and tk.is_lossy
+        if budget and need is not None:
+            # the walk gave up: the ids that can reach the window, from
+            # the predicate's resident value column where one serves
+            # this request (query/valcol.py), then the comparator
+            few = self._narrow_by_column(o, uids, need)
+            if few is not None:
+                got = self._order_uids_generic(gq, few, ties_desc=ties_desc)
+                return "column", got, kept + len(few), read
+        got = self._order_uids_generic(gq, uids, ties_desc=ties_desc)
         path = "over_budget" if budget else "values"
         return path, got, kept + len(uids), read
+
+    def _narrow_by_column(
+        self, o: Order, uids: np.ndarray, need: int
+    ) -> Optional[np.ndarray]:
+        """The ids among `uids` (as they lie) that can reach a window of
+        `need` under the leading key `o`: those whose value is at or
+        beyond the `need`-th in the key's direction, ties with it
+        included, cut on the device from the predicate's value column;
+        every id where fewer than `need` have a value. None where there
+        is nothing to cut or no column serves the request."""
+        if len(uids) <= need:
+            return None
+        from dgraph_tpu.query import valcol
+
+        got = valcol.narrow_mask(
+            self.cache, self.st, self.ns, o.attr, uids, need, o.desc
+        )
+        if got is None:
+            return None
+        few = np.asarray(uids, np.uint64)[got[0]]
+        self._tally_column(len(uids), len(few))
+        return few
 
     def _walk_in_order(
         self, o: Order, tk, uids: np.ndarray, need: Optional[int],
@@ -2322,45 +2372,38 @@ class Executor:
     def _order_uids_topk(
         self, gq: GraphQuery, o: Order, uids: np.ndarray
     ) -> Optional[np.ndarray]:
-        """Device top-k for `first: N` over a numeric value-var ordering:
-        one lax.top_k instead of a host sort (ref pagination path in
+        """`first: N` over a numeric value-var ordering of 4,096 ids or
+        more: the device keeps the ids whose score, rounded to float32,
+        is at or beyond the N-th (`ops/valcol.scores_narrow`: one jitted
+        program a padded size; rounding is monotone, so it only adds
+        ties, and ties are kept), and the comparator orders those by
+        the exact values. Returns the window's ids and what sorts next
+        to them, not the rest (ref pagination path in
         query/outputnode.go + worker/sort.go)."""
         if not o.val_var or gq.first is None or gq.first < 0 or gq.after is not None:
             return None
         vals = self.val_vars.get(o.val_var, {})
-        need = (gq.offset or 0) + gq.first
+        need = max(gq.offset or 0, 0) + gq.first
         if len(uids) < 4096 or need >= len(uids):
             return None  # host sort wins below dispatch overhead
-        scores = np.zeros((len(uids),), np.float64)
-        present_mask = np.zeros((len(uids),), bool)
-        for i, u in enumerate(uids):
-            v = vals.get(int(u))
-            if v is None:
-                continue  # missing values sink to the end
-            if not isinstance(v.value, (int, float)) or isinstance(v.value, bool):
-                return None  # non-numeric ordering: host path
-            scores[i] = float(v.value)
-            present_mask[i] = True
-        import jax
-        import jax.numpy as jnp
+        got = [vals.get(u) for u in uids.tolist()]
+        nums = [v.value for v in got if v is not None]
+        if not all(type(x) in (int, float) for x in nums):
+            return None  # non-numeric ordering: host path
+        try:
+            score = np.full((len(uids),), np.nan, np.float32)
+            score[[v is not None for v in got]] = nums
+        except OverflowError:
+            return None  # an int no float holds
+        if np.isnan(score).sum() != len(got) - len(nums):
+            return None  # a stored NaN: the comparator's business
+        from dgraph_tpu.query.dispatch import DISPATCHER
 
-        sc = np.where(
-            present_mask,
-            scores if o.desc else -scores,
-            -np.inf,  # missing rank last, then get dropped below
-        ).astype(np.float32)
-        k = min(need, int(present_mask.sum()))
-        if k == 0:
-            return np.zeros((0,), np.uint64)
-        _, idx = jax.lax.top_k(jnp.asarray(sc), k)
-        idx = np.asarray(idx)
-        top = uids[idx]
-        present = uids[present_mask]
-        if len(top) < len(present):
-            rest = np.setdiff1d(present, top, assume_unique=False)
-            # rest order is unspecified beyond the pagination window
-            return np.concatenate([top, rest])
-        return top
+        keep, _ = DISPATCHER.run_column(
+            "scores", score if o.desc else -score, None, np.int32(need)
+        )
+        few = uids[keep[: len(uids)]]
+        return self._order_uids_generic(gq, few)
 
     def _order_uids(
         self, gq: GraphQuery, uids: np.ndarray, full: bool = False
@@ -2416,8 +2459,12 @@ class Executor:
         date tokenizers are not: they encode the fields of the datetime
         as written, offset and all (10:30+05:00 sits in hour bucket 10),
         and the comparator orders by instant, so a datetime leading key
-        stays with the comparator. Everything else, and a walk that ran
-        out of budget or of buckets, sorts every candidate as before."""
+        is not walked. Such a key, and one whose walk ran out of budget,
+        is cut from the predicate's resident value column where the
+        candidates reach the device line and a column serves the
+        request (`_narrow_by_column`: ranks of the instants, so mixed
+        offsets are right there). Everything else sorts every candidate
+        as before."""
         path, kept, read = "generic", uids, 0
         o = gq.order[0]
         need = max(gq.offset or 0, 0) + gq.first
@@ -2441,6 +2488,11 @@ class Executor:
                 )
             if tk is not None:
                 path, kept, read = self._narrow_to_window(o, tk, uids, need)
+            if tk is None or path == "over_budget":
+                # a key the walk may not take, or gave up on
+                few = self._narrow_by_column(o, uids, need)
+                if few is not None:
+                    path, kept = "column", few
         with self._order_mu:
             self.order_tally.update({
                 f'order_window_total{{path="{path}"}}': 1,

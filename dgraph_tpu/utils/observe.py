@@ -2668,7 +2668,9 @@ declare_metric(
     "Per-family split of device_dispatch_total: intersect#shared, "
     "union#chain, intersect (pairs), intersect#sharded, vec.ivf, "
     "vec.brute, vec.sharded — the `family` attr of the setop.launch / "
-    "vec.launch spans.",
+    "vec.launch spans — and column#filter, column#narrow, "
+    "column#scores: a value column's programs (ops/valcol.py), one per "
+    "valcol.launch span, whose `use` attr is the part after the #.",
 )
 declare_metric(
     "counter", "device_download_bytes_total",
@@ -2719,7 +2721,11 @@ declare_metric(
     "before the window was full, so ids without a leading value count) "
     "and `over_budget` (the walk read len(ids)/8 buckets without "
     "filling the window, or a descending walk found more buckets than "
-    "that to list); the last three sort every candidate.",
+    "that to list); the last three sort every candidate. `column`: a "
+    "leading key the walk may not take (a datetime) or gave up on "
+    "(`over_budget`), cut on the device from the predicate's resident "
+    "value column to the ids at or beyond the window's last key, ties "
+    "included (query/valcol.py); the comparator orders those.",
 )
 declare_metric(
     "counter", "order_single_total{path=\"*\"}",
@@ -2732,8 +2738,41 @@ declare_metric(
     "or the candidates were placed), `over_budget` (a walk that read "
     "len(ids)/8 buckets without placing them, or a descending walk "
     "that found more buckets than that to list; the comparator then "
-    "ordered every candidate) and `topk` (device top-k over a numeric "
-    "value var).",
+    "ordered every candidate), `column` (where the walk gave up and "
+    "the candidates reach the device line: the ids that can reach the "
+    "window, cut on the device from the predicate's resident value "
+    "column, then the comparator) and `topk` (a numeric value var over "
+    "4,096 ids or more: the same cut over float32 scores, then the "
+    "comparator).",
+)
+declare_metric(
+    "counter", "value_column_builds_total",
+    "Resident value columns built (query/valcol.py): one scan of a "
+    "predicate's data keys at the request's read timestamp, its uids "
+    "and value ranks uploaded into the DeviceCache. A build happens on "
+    "the first filter or order that brings the predicate at least the "
+    "device line's candidates, and again after a commit to the "
+    "predicate or an eviction; inside a steady window it reads 0.",
+)
+declare_metric(
+    "counter", "value_column_invalidations_total",
+    "Resident value columns dropped because a commit, an alter, a bulk "
+    "load or a tablet move touched their predicate, before the change "
+    "became readable.",
+)
+declare_metric(
+    "gauge", "value_column_rows",
+    "Rows (uids with a value) of the value columns resident now.",
+)
+declare_metric(
+    "counter", "value_column_fallback_total{why=\"*\"}",
+    "Filters and orders that brought a predicate the device line's "
+    "candidates and were answered value by value all the same: `stale` "
+    "(the request reads below the timestamp the column was built at, "
+    "or below the predicate's last commit with no column to use), "
+    "`txn` (the transaction holds its own write to the predicate), "
+    "`type` (a list, @lang or non-numeric predicate, stored values of "
+    "another type, a NaN, uids under several high-32 segments).",
 )
 declare_metric(
     "counter", "order_candidates_total",
