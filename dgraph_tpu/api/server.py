@@ -1652,6 +1652,8 @@ class Server:
                 sp.attrs["order_kept"] = ex.order_tally["order_kept_total"]
                 sp.attrs["order_buckets"] = ex.order_tally[
                     "order_buckets_total"]
+            if ex.uid_ids:
+                sp.attrs["uid_ids"] = ex.uid_ids
             if ex.column_tally:
                 sp.attrs["column_cands"] = ex.column_tally["cands"]
                 sp.attrs["column_kept"] = ex.column_tally["kept"]

@@ -35,7 +35,23 @@ class QueryBudgetError(QueryError):
 
 
 def _as_uids(xs) -> np.ndarray:
+    if isinstance(xs, np.ndarray) and xs.dtype.kind in "ui":
+        return np.unique(xs.astype(np.uint64, copy=False))
     return np.array(sorted(set(int(x) for x in xs)), dtype=np.uint64)
+
+
+def _union_sorted(parts: List[np.ndarray]) -> np.ndarray:
+    """Sorted, duplicate-free union of uint64 arrays. A uid variable is
+    bound from a level's merged rows, so the usual input is ONE part
+    already in order: that one is handed back as it lies."""
+    if not parts:
+        return EMPTY
+    if len(parts) > 1:
+        return np.unique(np.concatenate(parts))
+    (out,) = parts
+    if len(out) > 1 and not bool((out[:-1] < out[1:]).all()):
+        out = np.unique(out)
+    return out
 
 
 EMPTY = np.zeros((0,), np.uint64)
@@ -51,7 +67,7 @@ class FuncRunner:
     def __init__(self, cache: LocalCache, st: State, ns: int = keys.GALAXY_NS,
                  vector_indexes=None, uid_vars=None, val_vars=None,
                  stats=None, ordered_uid_vars=None, batcher=None,
-                 planner=None, tally=None):
+                 planner=None, tally=None, uid_tally=None):
         self.cache = cache
         self.st = st
         self.ns = ns
@@ -71,6 +87,9 @@ class FuncRunner:
         # the executor's count of what went through a value column
         # (`Executor._tally_column`); None: nobody counts
         self.tally = tally
+        # likewise of the ids its `uid` functions were given
+        # (`Executor._tally_uid_ids`)
+        self.uid_tally = uid_tally
 
     # -- helpers -------------------------------------------------------------
 
@@ -216,29 +235,7 @@ class FuncRunner:
         if fn.is_count:
             return self._count_func(fn, name, src)
         if name == "uid":
-            uids = list(fn.args)
-            uvars = fn.uid_var.split(",") if fn.uid_var else []
-            if (
-                not uids
-                and len(uvars) == 1
-                and uvars[0] in self.ordered_uid_vars
-                and src is None
-            ):
-                # uid(A) where A is a shortest-path var: PATH order
-                # (ref TestShortestPathRev golden)
-                return np.asarray(self.uid_vars[uvars[0]], np.uint64)
-            for v in uvars:
-                if v in self.uid_vars:
-                    uids.extend(int(u) for u in self.uid_vars[v])
-                elif v in self.val_vars:
-                    # uid(value-var): the var's uid key set — INCLUDING the
-                    # MaxUint64 count-var key (ref query.go:1593; uid(f) on
-                    # `f as count(uid)` yields that sentinel row)
-                    uids.extend(self.val_vars[v].keys())
-            out = _as_uids(uids)
-            if src is not None:
-                out = np.intersect1d(out, src, assume_unique=True)
-            return out
+            return self._uid(fn, src)
         if name == "uid_in":
             return self._uid_in(fn, src)
         if name == "type":
@@ -268,6 +265,37 @@ class FuncRunner:
         if name == "checkpwd":
             return self._checkpwd(fn, src)
         raise QueryError(f"function {name!r} not supported")
+
+    def _uid(self, fn: FuncSpec, src: Optional[np.ndarray]) -> np.ndarray:
+        """uid(0x1, A, B): the literals and the named variables' sets,
+        united. A variable stays the array it was bound as; nothing
+        here walks its ids."""
+        uvars = fn.uid_var.split(",") if fn.uid_var else []
+        parts = [_as_uids(fn.args)] if fn.args else []
+        for v in uvars:
+            if v in self.uid_vars:
+                parts.append(np.asarray(self.uid_vars[v], np.uint64))
+            elif v in self.val_vars:
+                # uid(value-var): the var's uid key set — INCLUDING the
+                # MaxUint64 count-var key (ref query.go:1593; uid(f) on
+                # `f as count(uid)` yields that sentinel row)
+                held = self.val_vars[v]
+                parts.append(np.fromiter(held, np.uint64, len(held)))
+        if self.uid_tally is not None:
+            self.uid_tally(sum(len(p) for p in parts))
+        if (
+            not fn.args
+            and len(uvars) == 1
+            and uvars[0] in self.ordered_uid_vars
+            and src is None
+        ):
+            # uid(A) where A is a shortest-path var: PATH order
+            # (ref TestShortestPathRev golden)
+            return parts[0]
+        out = _union_sorted(parts)
+        if src is not None:
+            out = np.intersect1d(out, src, assume_unique=True)
+        return out
 
     def _checkpwd(self, fn: FuncSpec, src) -> np.ndarray:
         """checkpwd(pred, "pw") — verify a password-type value

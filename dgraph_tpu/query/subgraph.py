@@ -37,6 +37,7 @@ from dgraph_tpu.query.functions import (
     FuncRunner,
     QueryError,
     _as_uids,
+    _union_sorted,
 )
 from dgraph_tpu.schema.schema import State
 from dgraph_tpu.types.types import TypeID, Val, compare_vals, convert
@@ -228,6 +229,9 @@ class Executor:
         # (`cands`: filters and orders) and ids a column's narrowing
         # left the comparator (`kept`); the `process` span carries both
         self.column_tally: Dict[str, int] = collections.Counter()
+        # ids this request's `uid` functions were given: distinct
+        # literals and variables' lengths alike (uid_func_ids_total)
+        self.uid_ids = 0
         # cost-based planner (query/planner.py): whole-query evaluation
         # ordering + intersect-vs-filter strategy, observation-
         # equivalent by construction; None = declaration-order
@@ -256,11 +260,16 @@ class Executor:
             batcher=self.batcher,
             planner=self.planner,
             tally=self._tally_column,
+            uid_tally=self._tally_uid_ids,
         )
 
     def _tally_column(self, cands: int, kept: int) -> None:
         with self._order_mu:
             self.column_tally.update(cands=cands, kept=kept)
+
+    def _tally_uid_ids(self, given: int) -> None:
+        with self._order_mu:
+            self.uid_ids += given
 
     # ------------------------------------------------------------------
     # Block orchestration (ref query.Request.Process query.go:3046)
@@ -278,11 +287,15 @@ class Executor:
     def process(self, blocks: List[GraphQuery]) -> List[ExecNode]:
         self.order_tally = collections.Counter()
         self.column_tally = collections.Counter()
+        self.uid_ids = 0
         try:
             return self._process(blocks)
         finally:
-            if self.order_tally:
-                METRICS.inc_many(self.order_tally)
+            tally = dict(self.order_tally)
+            if self.uid_ids:
+                tally["uid_func_ids_total"] = self.uid_ids
+            if tally:
+                METRICS.inc_many(tally)
 
     def _process(self, blocks: List[GraphQuery]) -> List[ExecNode]:
         pending = list(blocks)
@@ -739,9 +752,7 @@ class Executor:
             out = np.asarray(
                 self._runner()._run(ft.func, src=None), np.uint64
             )
-            if len(out) > 1 and not bool(np.all(out[:-1] < out[1:])):
-                out = np.unique(out)  # e.g. path-ordered uid(var) roots
-            return out
+            return _union_sorted([out])  # e.g. path-ordered uid(var) roots
         parts = [self._eval_filter_root(c) for c in ft.children]
         op = "intersect" if ft.op == "and" else "union"
         return DISPATCHER.run_chain(op, parts).astype(np.uint64)
@@ -1113,12 +1124,11 @@ class Executor:
             if reverse and not su.directive_reverse:
                 raise QueryError(f"predicate {attr[1:]!r} has no @reverse index")
             cnode.is_uid_pred = True
-            level_keys = [
-                keys.ReverseKey(attr[1:], int(u), self.ns)
+            level_keys = (
+                keys.ReverseKeys(attr[1:], parent.dest_uids, self.ns)
                 if reverse
-                else keys.DataKey(attr, int(u), self.ns)
-                for u in parent.dest_uids
-            ]
+                else keys.DataKeys(attr, parent.dest_uids, self.ns)
+            )
             # ONE task per (predicate, level): the whole parent list reads
             # in a single batched call returning the ragged (flat, offsets)
             # level buffer (ref worker/task.go one task per attr; the
@@ -1274,10 +1284,7 @@ class Executor:
             # per-uid loop here never prefetched its DataKeys, so the LSM
             # path was N point lookups (bugfix); values_many batches the
             # memlayer/LSM probe in a single pass
-            dkeys = [
-                keys.DataKey(attr, int(u), self.ns)
-                for u in parent.dest_uids
-            ]
+            dkeys = keys.DataKeys(attr, parent.dest_uids, self.ns)
             t0 = time.perf_counter()
             with TRACER.span(
                 "level_task", cpu=True, attr=attr, parents=len(dkeys),

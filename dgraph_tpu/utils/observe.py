@@ -2794,6 +2794,15 @@ declare_metric(
     "are more.",
 )
 declare_metric(
+    "counter", "uid_func_ids_total",
+    "Ids the `uid` functions of requests were given (query/functions.py "
+    "_uid): each distinct literal, and the length of each named "
+    "variable's array, counted once a call and flushed once a request; the "
+    "`process` span carries the request's own sum as `uid_ids`. Which "
+    "traffic hands the executor large uid variables: the sets arrive "
+    "and leave as sorted arrays and are not walked id by id.",
+)
+declare_metric(
     "counter", "digest_evicted_total",
     "Digest-store rows evicted past DGRAPH_TPU_DIGEST_SHAPES and "
     "folded into the sticky per-namespace `other` bucket "

@@ -23,6 +23,8 @@ import struct
 from dataclasses import dataclass
 from typing import Optional
 
+import numpy as np
+
 TAG_DEFAULT = 0x00
 TAG_SCHEMA = 0x01
 TAG_TYPE = 0x02
@@ -75,6 +77,28 @@ def DataKey(attr: str, uid: int, ns: int = GALAXY_NS) -> bytes:
 
 def ReverseKey(attr: str, uid: int, ns: int = GALAXY_NS) -> bytes:
     return _kind_prefix(KIND_REVERSE, attr, ns) + struct.pack(">Q", uid)
+
+
+def _uid_keys(kind: int, attr: str, uids, ns: int) -> list[bytes]:
+    """One key per uid, from the uids' array in one pass: the prefix
+    beside each uid's big-endian bytes as rows of one byte matrix,
+    handed out as `bytes` row by row by numpy."""
+    p = _kind_prefix(kind, attr, ns)
+    be = np.asarray(uids, np.uint64).astype(">u8")
+    rows = np.empty((len(be), len(p) + 8), np.uint8)
+    rows[:, : len(p)] = np.frombuffer(p, np.uint8)
+    rows[:, len(p) :] = be.view(np.uint8).reshape(-1, 8)
+    return rows.view(f"V{len(p) + 8}").ravel().tolist()
+
+
+def DataKeys(attr: str, uids, ns: int = GALAXY_NS) -> list[bytes]:
+    """[DataKey(attr, u, ns) for u in uids], without the walk."""
+    return _uid_keys(KIND_DATA, attr, uids, ns)
+
+
+def ReverseKeys(attr: str, uids, ns: int = GALAXY_NS) -> list[bytes]:
+    """[ReverseKey(attr, u, ns) for u in uids], without the walk."""
+    return _uid_keys(KIND_REVERSE, attr, uids, ns)
 
 
 def IndexKey(attr: str, term: bytes, ns: int = GALAXY_NS) -> bytes:
