@@ -34,9 +34,24 @@ from dgraph_tpu.worker.remote import RetryBudgetExhausted
 from dgraph_tpu.worker.tabletmove import TabletFencedError
 from dgraph_tpu.zero.zero import TxnConflictError
 
+# A kept connection with no request for this long is closed, so that
+# clients that went away do not pin handler threads (the reason Go's
+# net/http server, which upstream's alpha serves through, has one).
+_IDLE_S = 120.0
+
 
 class _Handler(BaseHTTPRequestHandler):
     server_version = "dgraph-tpu/0.1"
+    # persistent connections: one thread serves a connection's requests
+    # in turn (`handle`) until the client speaks HTTP/1.0, asks for a
+    # close, goes quiet for `_IDLE_S` (`_Listener.service_actions`), or
+    # a reply leaves the stream out of step (`send_response`). The
+    # socket stays blocking: one with a timeout polls before every read
+    # and write, and gives the interpreter's lock away twice for each.
+    protocol_version = "HTTP/1.1"
+    # a route that writes a reply's head and body apart would otherwise
+    # wait for the client's delayed ACK of the head (Nagle)
+    disable_nagle_algorithm = True
     engine: Server = None  # type: ignore[assignment]
     txns: Dict[int, TxnHandle] = {}
     txn_owner: Dict[int, str] = {}
@@ -44,21 +59,96 @@ class _Handler(BaseHTTPRequestHandler):
     # body and reply sizes of the request in hand (http.request's attrs)
     _bytes_in = 0
     _bytes_out = 0
+    # of the request in hand: body bytes read, whether a reply began
+    _body_read = 0
+    _replied = False
 
     def __init__(self, request, client_address, server, front=None):
-        # the connection's front door (`_Front`), None with TRACE off
+        # the request's front door (`_Front`), None with TRACE off: the
+        # listener's for a connection's first, `_arrived`'s for the rest
         self._front = front
         super().__init__(request, client_address, server)
 
     def log_message(self, *a):  # quiet
         pass
 
+    # -- the connection ------------------------------------------------------
+
+    def handle(self):
+        # BaseHTTPRequestHandler.handle's loop, each request awaited by
+        # `_arrived`; on a kept connection `http.tail` ends where the
+        # thread turns to the next request, on the last in `finish`
+        while self._arrived():
+            self._body_read, self._replied = 0, False
+            self.handle_one_request()
+            if self.close_connection:
+                return
+            if self._front is not None:
+                self._front.closed()
+                self._front = None
+
+    def _arrived(self) -> bool:
+        """Wait for the next request's first bytes. False where the
+        client closed or reset, or the listener closed the connection
+        (idle for `_IDLE_S`, or the server closing). With TRACE on, a
+        kept connection's request gets its front door here: its head,
+        and the instant that stands in for the accept, begin where the
+        read returns."""
+        server = self.server
+        with server.idle_lock:
+            if server.closing:
+                return False
+            server.idle[self.connection] = time.monotonic()
+        try:
+            arrived = bool(self.rfile.peek(1))
+        except OSError:  # reset
+            return False
+        finally:
+            with server.idle_lock:
+                server.idle.pop(self.connection, None)
+        if arrived and self._front is None and observe.trace_enabled():
+            head = observe.Stretch("http.head")
+            self._front = _Front(server.clients, head.start, None, None)
+            self._front.head = head
+        return arrived
+
+    def finish(self):
+        try:
+            super().finish()
+        finally:
+            if self._front is not None:
+                self._front.closed()
+
+    def send_response(self, code, message=None):
+        # a reply after which the client's stream may be out of step
+        # says so, and the connection closes after it
+        in_step = self.close_connection or self._in_step()
+        self._replied = True
+        super().send_response(code, message)
+        if not in_step:
+            self.send_header("Connection", "close")
+
+    def _in_step(self) -> bool:
+        """Whether the next request begins where this one's body ended:
+        no reply begun yet, and the body read whole, not chunked."""
+        headers = getattr(self, "headers", None)
+        if self._replied or headers is None or "Transfer-Encoding" in headers:
+            return False
+        try:
+            return self._body_read >= int(headers.get("Content-Length") or 0)
+        except ValueError:
+            return False
+
     # -- helpers -------------------------------------------------------------
 
     def _body(self) -> bytes:
         n = int(self.headers.get("Content-Length", 0))
         self._bytes_in = n
-        return self.rfile.read(n) if n else b""
+        data = self.rfile.read(n) if n else b""
+        self._body_read = len(data)
+        if len(data) < n:  # the client closed part way
+            raise ValueError(f"request body ends at byte {len(data)} of {n}")
+        return data
 
     def _reply(self, obj, code=200):
         # `http.reply`: response assembly and the socket write; a fine
@@ -68,6 +158,11 @@ class _Handler(BaseHTTPRequestHandler):
             sp.attrs["bytes"] = self._bytes_out = self._write(obj, code)
 
     def _write(self, obj, code) -> int:
+        if self._replied:
+            # an error after the reply began: a second reply would be
+            # read as the answer to the client's next request
+            self.close_connection = True
+            return 0
         # responses whose `data` carries pre-encoded wire bytes (the
         # streaming arena encoder, query/streamjson.py) are SPLICED —
         # the result tree never runs through json.dumps a second time
@@ -80,8 +175,10 @@ class _Handler(BaseHTTPRequestHandler):
         self.send_response(code)
         self.send_header("Content-Type", "application/json")
         self.send_header("Content-Length", str(len(data)))
-        self.end_headers()
-        self.wfile.write(data)
+        # end_headers with the body: one send, so one turn at the
+        # interpreter's lock where two sends would wait twice
+        self._headers_buffer.extend((b"\r\n", data))
+        self.flush_headers()
         return len(data)
 
     def _error(self, msg, code=400):
@@ -303,7 +400,8 @@ class _Handler(BaseHTTPRequestHandler):
         # `http.request`: the root of a served request's span tree, from
         # the handler's first line to after the reply has been written
         # (the request line and headers were parsed before do_POST:
-        # `http.head`, and the socket is closed after it: `http.tail`)
+        # `http.head`; after it the thread turns to the connection's
+        # next request, or closes it: `http.tail`)
         self._bytes_in = self._bytes_out = 0
         root = (TRACER.span("http.request", cpu=True, path=path)
                 if front is None
@@ -785,21 +883,28 @@ def _backlog(listening: socket.socket) -> Optional[int]:
 
 
 class _Front:
-    """One connection's front door, with TRACE on: what the listener saw
-    at its accept, and the two stretches of the handler thread outside
-    `http.request` (`observe.Stretch`). `http.head` runs from the
-    thread's first line to do_POST's first lines, where `http.request`
-    opens (the request line, the headers, the client's stamp);
-    `http.tail` from the written reply to the closed socket. Their
-    numbers reach the request's record as attrs of `http.request`:
-    `handoff_ms` (accept to the thread's first line: the thread's start
-    and its wait for the interpreter's lock), `head_ms`, `tail_ms`,
-    `backlog`, `accept_gap_ms` (since the listener's previous accept)
-    and, under a profiler session, `head_cpu_ms` and `tail_cpu_ms`
-    (attrs, not self CPU: the spans' readers read what they read
-    before). A client's stamp (`dgraph_tpu/client.py`) adds `connect_ms`
-    and `client_gap_ms`. `tail_ms`, `tail_cpu_ms` and `client_gap_ms`
-    are set once the socket is closed, after the root has finished."""
+    """One request's front door, with TRACE on: the instant it was
+    taken up (`accepted`) and the two stretches of the handler thread
+    outside `http.request` (`observe.Stretch`). On a connection's first
+    request `accepted` is the listener's accept, and `http.head` runs
+    from the thread's first line; on a kept connection's later request
+    both are the moment the thread's read of the request line returned
+    (`_Handler._arrived`), so the idle time between two requests is in
+    neither. `http.head` ends at do_POST's first lines, where
+    `http.request` opens (the request line, the headers, the client's
+    stamp); `http.tail` runs from the written reply to the thread's
+    turn to the connection's next request, or to the end of its
+    handler. Their numbers reach the request's record as attrs of
+    `http.request`: `handoff_ms` (`accepted` to the head: on a first
+    request the thread's start and its wait for the interpreter's lock,
+    on a later one 0), `head_ms`, `tail_ms`, on a first request
+    `backlog` and `accept_gap_ms` (since the listener's previous
+    accept), and under a profiler session `head_cpu_ms` and
+    `tail_cpu_ms` (attrs, not self CPU: the spans' readers read what
+    they read before). A client's stamp (`dgraph_tpu/client.py`) adds
+    `connect_ms` (the stamp to `accepted`) and `client_gap_ms`.
+    `tail_ms`, `tail_cpu_ms` and `client_gap_ms` are set by `closed`,
+    after the root has finished."""
 
     __slots__ = ("clients", "accepted", "accept_gap_ms", "backlog", "head",
                  "root", "tail", "stamp", "prev", "replied_at")
@@ -858,7 +963,7 @@ class _Front:
             self.tail = observe.Stretch("http.tail", root.trace_id)
 
     def closed(self) -> None:
-        """The socket is closed: the tail's and the client's attrs."""
+        """The request is done with: the tail's and the client's attrs."""
         self.head.close()
         root = self.root
         if root is None:
@@ -915,11 +1020,16 @@ class _Listener(ThreadingHTTPServer):
         self._last_accept: Optional[float] = None
         self._tcp_info = True  # until the platform shows it fills none
         self.clients = _ClientReplies()
+        # connections whose thread waits for their next request
+        # (`_Handler._arrived`), with the moment it began to
+        self.idle: Dict[socket.socket, float] = {}
+        self.idle_lock = threading.Lock()
+        self.closing = False
 
     def get_request(self):
-        # One serial thread, which paces a sixteen-client cell (PERF.md,
-        # PR 38): with TRACE on it keeps the accept's instant and the
-        # queue behind it for the connection's thread, and nothing more.
+        # One serial thread, once a connection: with TRACE on it keeps
+        # the accept's instant and the queue behind it for the
+        # connection's first request, and nothing more.
         sock, addr = self.socket.accept()
         if observe.trace_enabled():
             now, last = time.time(), self._last_accept
@@ -932,8 +1042,8 @@ class _Listener(ThreadingHTTPServer):
         return sock, addr
 
     def process_request_thread(self, request, client_address):
-        # ThreadingMixIn's, with the connection counted, its front door
-        # handed to its handler and closed after the socket is
+        # ThreadingMixIn's, with the connection counted and its first
+        # request's front door handed to its handler
         front = self._fronts.pop(request, None)
         if front is not None:
             front.head = observe.Stretch("http.head")
@@ -944,8 +1054,35 @@ class _Listener(ThreadingHTTPServer):
             self.handle_error(request, client_address)
         finally:
             self.shutdown_request(request)
-            if front is not None:
-                front.closed()
+
+    def service_actions(self):
+        # serve_forever's turn after each accept or half second: a kept
+        # connection idle for `_IDLE_S` is closed, and its thread ends
+        now = time.monotonic()
+        with self.idle_lock:
+            stale = [s for s, t in self.idle.items() if now - t > _IDLE_S]
+            for sock in stale:
+                del self.idle[sock]
+                _hang_up(sock)
+
+    def server_close(self):
+        # as Go's Server.Shutdown: the kept connections that wait for a
+        # request are closed, a request in hand is answered first; then
+        # ThreadingMixIn joins the handler threads
+        with self.idle_lock:
+            self.closing = True
+            for sock in self.idle:
+                _hang_up(sock)
+        super().server_close()
+
+
+def _hang_up(sock: socket.socket) -> None:
+    """End a connection whose thread waits in a read: the read returns
+    empty, and the thread closes it."""
+    try:
+        sock.shutdown(socket.SHUT_RDWR)
+    except OSError:
+        pass
 
 
 class HTTPServer:

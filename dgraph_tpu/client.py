@@ -15,23 +15,34 @@ API: login (JWT pair with automatic refresh-and-retry), alter, transactions
 
 from __future__ import annotations
 
+import http.client
 import itertools
 import json
 import os
+import select
+import threading
 import time
-import urllib.error
-import urllib.request
+import urllib.parse
 from typing import Any, Dict, Optional
 
 # Every request carries "<client id> <sequence number> <time.time_ns()
-# just before the connection opens>". The alpha (api/http_server.py)
-# turns it into `connect_ms` (the stamp to its accept: the connect, the
-# kernel's accept queue, the listener's turn) and `client_gap_ms` (this
-# stamp less the moment it wrote the same client's previous reply: the
-# client's own time between an answer and its next request). Both
-# clocks are the host's CLOCK_REALTIME; a client on another host gives
-# readings only as plausible as the two clocks agree.
+# just before the request goes out>", before the connect where the
+# request opens a connection. The alpha (api/http_server.py) turns it
+# into `connect_ms` (the stamp to its accept, or on a kept connection to
+# its read of the request line: the connect, the kernel's queues, the
+# handler thread's turn) and `client_gap_ms` (this stamp less the moment
+# it wrote the same client's previous reply: the client's own time
+# between an answer and its next request). Both clocks are the host's
+# CLOCK_REALTIME; a client on another host gives readings only as
+# plausible as the two clocks agree.
 STAMP_HEADER = "X-Dgraph-Client-Stamp"
+
+
+def _readable(sock) -> bool:
+    """Whether a socket has bytes, or its peer's close, waiting."""
+    poller = select.poll()
+    poller.register(sock, select.POLLIN)
+    return bool(poller.poll(0))
 
 
 class DgraphClientError(Exception):
@@ -46,8 +57,18 @@ class RetriableError(DgraphClientError):
 
 
 class DgraphClient:
+    """One alpha's HTTP API. Each thread that uses the client keeps one
+    HTTP/1.1 connection to it (as dgo and pydgraph keep one channel),
+    so the client is as thread-safe as a connection per request."""
+
     def __init__(self, url: str, timeout: float = 60.0):
         self.url = url.rstrip("/")
+        parts = urllib.parse.urlsplit(self.url)
+        self._connection = (http.client.HTTPSConnection
+                            if parts.scheme == "https"
+                            else http.client.HTTPConnection)
+        self._netloc, self._base = parts.netloc, parts.path
+        self._local = threading.local()
         self.timeout = timeout
         self._access: Optional[str] = None
         self._refresh: Optional[str] = None
@@ -71,28 +92,66 @@ class DgraphClient:
         headers = {"Content-Type": ctype}
         if self._access:
             headers["X-Dgraph-AccessToken"] = self._access
-        req = urllib.request.Request(
-            self.url + path, data=data, headers=headers, method=method
-        )
-        req.add_header(STAMP_HEADER, self._stamp())
+        # a read the server cannot have applied twice
+        read = method == "GET" or path.split("?", 1)[0] == "/query"
+        status, raw = self._exchange(method, path, data, headers, read)
+        if 200 <= status < 300:
+            return json.loads(raw)
         try:
-            with urllib.request.urlopen(req, timeout=self.timeout) as r:
-                return json.loads(r.read())
-        except urllib.error.HTTPError as e:
-            try:
-                payload = json.loads(e.read())
-            except Exception:
-                payload = {}
-            msg = (payload.get("errors") or [{}])[0].get("message", str(e))
-            if e.code == 401 and self._refresh and not _retried:
-                # expired access token: refresh once and retry (dgo behavior)
-                self._do_refresh()
-                return self._do(path, body, ctype, method, _retried=True)
-            if e.code == 409:
-                raise RetriableError(msg, e.code, payload) from None
-            raise DgraphClientError(msg, e.code, payload) from None
-        except urllib.error.URLError as e:
-            raise DgraphClientError(f"connection failed: {e.reason}") from None
+            payload = json.loads(raw)
+        except ValueError:
+            payload = {}
+        msg = (payload.get("errors") or [{}])[0].get(
+            "message", f"HTTP Error {status}")
+        if status == 401 and self._refresh and not _retried:
+            # expired access token: refresh once and retry (dgo behavior)
+            self._do_refresh()
+            return self._do(path, body, ctype, method, _retried=True)
+        if status == 409:
+            raise RetriableError(msg, status, payload)
+        raise DgraphClientError(msg, status, payload)
+
+    def _exchange(self, method, path, data, headers, read, fresh=False):
+        """(status, body) of one request over the calling thread's kept
+        connection. A kept connection the server closed while it lay
+        idle is found before the send where the close has arrived;
+        where it has not, the request fails before the reply's status
+        line, and goes once more on a new connection if it cannot have
+        been applied twice: a read, or a send that failed."""
+        conn = getattr(self._local, "conn", None)
+        if conn is not None and (conn.sock is None or _readable(conn.sock)):
+            # closed after a reply that said so, or the server's close
+            # (or stray bytes) waits: never a reply to a request of ours
+            conn.close()
+            conn = None
+        reused = conn is not None
+        if conn is None:
+            conn = self._local.conn = self._connection(
+                self._netloc, timeout=self.timeout)
+        headers[STAMP_HEADER] = self._stamp()
+        sent = answered = False
+        try:
+            conn.request(method, self._base + path, body=data, headers=headers)
+            sent = True
+            resp = conn.getresponse()
+            answered = True
+            return resp.status, resp.read()
+        except (OSError, http.client.HTTPException) as e:
+            conn.close()
+            self._local.conn = None
+            if (reused and not fresh and not answered
+                    and isinstance(e, ConnectionError) and (read or not sent)):
+                return self._exchange(method, path, data, headers, read,
+                                      fresh=True)
+            raise DgraphClientError(f"connection failed: {e}") from None
+
+    def close(self) -> None:
+        """Close the calling thread's kept connection, if it has one; its
+        next request opens another."""
+        conn = getattr(self._local, "conn", None)
+        if conn is not None:
+            conn.close()
+            self._local.conn = None
 
     def _stamp(self) -> str:
         return f"{self._stamp_id} {next(self._seq)} {time.time_ns()}"

@@ -2972,7 +2972,8 @@ declare_metric(
     "counter", "http_connections_total",
     "TCP connections the HTTP listener accepted (api/http_server.py "
     "_Listener). Over num_queries it is the connections a query costs: "
-    "1.0 while every request opens its own.",
+    "1.0 where every request opens its own, near 0 where clients keep "
+    "theirs (HTTP/1.1, as dgraph_tpu/client.py does).",
 )
 declare_metric(
     "counter", "http_client_stamp_dropped_total",

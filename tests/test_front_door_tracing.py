@@ -1,9 +1,11 @@
 """The front door and the closed loop, traced (api/http_server.py
 `_Listener` / `_Front`, utils/observe.py `Stretch`, dgraph_tpu/client.py's
-stamp): every `/query` record carries the accept, the hand-off to the
-handler thread, the request head, the tail and the client's own time as
-attrs of `http.request`, the pieces tile a client's loop from one stamp
-to the next, and nothing the span readers read before moves."""
+stamp): every `/query` record carries the accept (on a kept
+connection's later request, the read of its request line), the hand-off
+to the handler thread, the request head, the tail and the client's own
+time as attrs of `http.request`, the pieces tile a client's loop from
+one stamp to the next on a new connection and on a kept one, and nothing
+the span readers read before moves."""
 
 import glob
 import importlib
@@ -26,8 +28,8 @@ from dgraph_tpu.utils.observe import METRICS, TRACER
 
 CLIENTS, PER_CLIENT = 4, 5
 QUERY = '{ q(func: eq(name, "p1")) { uid name } }'
-FRONT = ("connect_ms", "handoff_ms", "head_ms", "backlog", "accept_gap_ms",
-         "tail_ms")
+FRONT = ("connect_ms", "handoff_ms", "head_ms", "tail_ms")
+AT_ACCEPT = ("backlog", "accept_gap_ms")  # a connection's first request
 CLIENT_ATTRS = ("http.request.connect_ms", "http.request.client_gap_ms")
 # the readers of the request records that were there before the front
 # door, and the five that read it
@@ -71,8 +73,9 @@ def _post(url, text, stamp=None) -> dict:
         return json.loads(r.read())
 
 
-def _drive(url):
-    """CLIENTS closed-loop clients, PER_CLIENT requests each. Returns
+def _drive(url, fresh=False):
+    """CLIENTS closed-loop clients, PER_CLIENT requests each, each client
+    on one kept connection, or on a new one a request (`fresh`). Returns
     per client [(stamp it sent, trace id of the answer, seconds from
     the stamp to the answer read)]."""
     out = [None] * CLIENTS
@@ -93,6 +96,8 @@ def _drive(url):
             done = time.time()
             at = int(sent[-1].split()[2]) / 1e9
             got.append((sent[-1], tid, done - at))
+            if fresh:
+                c.close()
         out[i] = got
 
     threads = [threading.Thread(target=loop, args=(i,))
@@ -120,14 +125,14 @@ def _records(tids, profiled=False, key="http.request.tail_ms"):
         time.sleep(0.01)
 
 
-@pytest.fixture(scope="module")
-def loop(served):
+@pytest.fixture(scope="module", params=["kept", "fresh"])
+def loop(served, request):
     url, _ = served
     c0 = METRICS.value("http_connections_total")
-    per = _drive(url)
+    per = _drive(url, fresh=request.param == "fresh")
     conns = METRICS.value("http_connections_total") - c0
     tids = {tid for got in per for _, tid, _ in got}
-    return per, _records(tids), conns
+    return per, _records(tids), conns, request.param
 
 
 @pytest.fixture(scope="module")
@@ -156,14 +161,9 @@ def profiled_records(served, tmp_path_factory):
 
 
 @pytest.mark.parametrize("attr", FRONT)
-def test_every_query_record_carries_the_front_door(served, loop, attr):
-    _, recs, _ = loop
+def test_every_query_record_carries_the_front_door(loop, attr):
+    _, recs, _, _ = loop
     assert len(recs) == CLIENTS * PER_CLIENT
-    if attr == "backlog" and not served[1].httpd._tcp_info:
-        # a platform that fills no TCP_INFO (the chip's host): absent
-        assert not any("http.request.backlog" in r["attrs"]
-                       for r in recs.values())
-        return
     for rec in recs.values():
         assert rec["root_attrs"]["path"] == "/query"
         value = rec["attrs"][f"http.request.{attr}"]
@@ -176,8 +176,30 @@ def test_every_query_record_carries_the_front_door(served, loop, attr):
                    for r in recs.values())
 
 
+@pytest.mark.parametrize("attr", AT_ACCEPT)
+def test_the_accept_only_on_a_connections_first_request(served, loop,
+                                                         attr):
+    """`backlog` and `accept_gap_ms` are the listener's, read at an
+    accept; a kept connection's later request was taken up by its own
+    thread's read, so its hand-off is 0."""
+    per, recs, _, mode = loop
+    if attr == "backlog" and not served[1].httpd._tcp_info:
+        # a platform that fills no TCP_INFO (the chip's host): absent
+        assert not any("http.request.backlog" in r["attrs"]
+                       for r in recs.values())
+        return
+    for got in per:
+        attrs = [recs[tid]["attrs"] for _, tid, _ in got]
+        has = [f"http.request.{attr}" in a for a in attrs]
+        assert has == [True] + [mode == "fresh"] * (PER_CLIENT - 1)
+        assert attrs[0][f"http.request.{attr}"] >= 0
+        if mode == "kept":
+            assert all(a["http.request.handoff_ms"] == 0.0
+                       for a in attrs[1:])
+
+
 def test_client_gap_only_after_a_clients_first_request(loop):
-    per, recs, _ = loop
+    per, recs, _, _ = loop
     for got in per:
         has = ["http.request.client_gap_ms" in recs[tid]["attrs"]
                for _, tid, _ in got]
@@ -189,7 +211,7 @@ def test_the_pieces_tile_each_clients_loop(loop):
     request's client gap is the time from one stamp to the next; the
     root opens before the client has its answer (and may close after:
     the reply is written inside it)."""
-    per, recs, _ = loop
+    per, recs, _, _ = loop
     for got in per:
         for (s0, t0, took), (s1, t1, _) in zip(got, got[1:]):
             a, b = recs[t0], recs[t1]["attrs"]
@@ -203,9 +225,40 @@ def test_the_pieces_tile_each_clients_loop(loop):
                                       str(int(s0.split()[1]) + 1)]
 
 
-def test_a_connection_a_request(loop):
-    _, recs, conns = loop
-    assert conns == len(recs) == CLIENTS * PER_CLIENT
+def test_a_connection_a_client_or_a_request(loop):
+    """`http_connections_total` counts accepts: one a client while it
+    keeps its connection, one a request where each opens its own."""
+    _, recs, conns, mode = loop
+    assert len(recs) == CLIENTS * PER_CLIENT
+    assert conns == (CLIENTS if mode == "kept" else CLIENTS * PER_CLIENT)
+
+
+def test_the_idle_time_between_kept_requests_is_in_no_piece(served):
+    """On a kept connection the head and the connect start where the
+    read of the request line returns, and the tail ends where the thread
+    turns to its next read: a client's pause falls into its own gap,
+    and the pieces still tile the loop."""
+    url, _ = served
+    c, sent = DgraphClient(url), []
+    stamp = c._stamp
+    c._stamp = lambda: sent.append(stamp()) or sent[-1]
+    c0 = METRICS.value("http_connections_total")
+    first = c.query(QUERY)["extensions"]["trace_id"]
+    time.sleep(0.3)
+    second = c.query(QUERY)["extensions"]["trace_id"]
+    assert METRICS.value("http_connections_total") - c0 == 1
+    recs = _records({first, second})
+    a, b = recs[first]["attrs"], recs[second]["attrs"]
+    assert a["http.request.tail_ms"] < 100
+    assert b["http.request.connect_ms"] < 100
+    assert b["http.request.head_ms"] < 100
+    assert b["http.request.handoff_ms"] == 0.0
+    assert b["http.request.client_gap_ms"] >= 300
+    door = sum(a[f"http.request.{k}"] for k in (
+        "connect_ms", "handoff_ms", "head_ms"))
+    between = (int(sent[1].split()[2]) - int(sent[0].split()[2])) / 1e6
+    assert door + recs[first]["wall_ms"] + b["http.request.client_gap_ms"] \
+        == pytest.approx(between, abs=1.0)
 
 
 @pytest.mark.parametrize("stamp,dropped", [
@@ -248,7 +301,7 @@ def test_profiled_records_one_per_request_with_head_and_tail_cpu(
 
 def _stripped(rec: dict) -> dict:
     """The record as the program gave it before the front door."""
-    ours = {f"http.request.{k}" for k in FRONT + (
+    ours = {f"http.request.{k}" for k in FRONT + AT_ACCEPT + (
         "client_gap_ms", "head_cpu_ms", "tail_cpu_ms")}
     return dict(rec, attrs={k: v for k, v in rec["attrs"].items()
                             if k not in ours},
