@@ -3,6 +3,17 @@ client, the bundled `DgraphClient` over the socket. It never initialises
 a jax backend (the parent starts it with JAX_PLATFORMS=cpu and nothing
 here imports jax), so it shares neither the chip nor the alpha's GIL.
 
+A query kind that sets `WRITES = True` writes: its `request` also takes
+the client's number and the draw's sequence number (`Client.draw`), and
+its text is {"set": n-quads, "delete": n-quads (optional)}, sent as ONE
+transaction committed at once (`ClientTxn.mutate(.., commit_now=True)`).
+A 409 (`RetriableError`: the transaction aborted on a conflict) is sent
+again with the same text, at most WRITE_RETRIES times; the record counts
+`retries`, and keeps `commit_ts`, the server's `uids` and `attempt_sent`
+(when the attempt that committed went out) for the history the reads are
+judged against (`chipbench/history.py`). The committed writes of the
+warm-up phases are kept and handed back with the window's records.
+
 Commands arrive as JSON lines on stdin, replies leave as JSON lines on
 stdout:
   first line   the spec: url, config, mix, seed
@@ -11,8 +22,9 @@ stdout:
                                        class the window's planned requests
                                        have and no warm-up request had yet
   {"cmd": "run", "seconds": s, "out": path}
-                                       the window; the records are pickled
-                                       to `path`
+                                       the window; its records and the
+                                       warm-up's committed writes are
+                                       pickled to `path`
   {"cmd": "stop"}
 """
 
@@ -30,6 +42,7 @@ import numpy as np
 from chipbench import draw
 
 REQUEST_TIMEOUT_S = 120
+WRITE_RETRIES = 3  # a write that aborts on a conflict is sent again
 
 
 def load_kinds(mix: dict):
@@ -52,6 +65,7 @@ class Client:
         self.conn = DgraphClient(spec["url"], timeout=REQUEST_TIMEOUT_S)
         self.streams = {phase: draw.stream(spec["seed"], phase, number)
                         for phase in (0, 1)}
+        self.drawn = {0: 0, 1: 0}
         # the window's first requests, drawn ahead so that warm-up can
         # see which shape classes they have
         self.planned = [self.draw(1)
@@ -59,11 +73,21 @@ class Client:
         self.covered = set()
 
     def draw(self, phase: int):
-        """The stream's next request: (kind index, key, text)."""
+        """The stream's next request: (kind index, key, text). A writing
+        kind is also told the client's number and the draw's sequence
+        number, 2n + phase for the phase's n-th draw: together they name
+        one draw of a run, the same one under the same seed, so what a
+        write creates can carry ids no other write of the run has."""
         rng = self.streams[phase]
+        seq = 2 * self.drawn[phase] + phase
+        self.drawn[phase] += 1
         ki = int(rng.choice(len(self.kinds), p=self.weights))
-        key, text = self.kinds[ki].request(
-            self.catalog, self.mix["kinds"][ki]["params"], rng)
+        kind, params = self.kinds[ki], self.mix["kinds"][ki]["params"]
+        if getattr(kind, "WRITES", False):
+            key, text = kind.request(self.catalog, params, rng,
+                                     self.number, seq)
+        else:
+            key, text = kind.request(self.catalog, params, rng)
         return ki, key, text
 
     def shape(self, req):
@@ -79,14 +103,37 @@ class Client:
     def send(self, req) -> dict:
         """Send one request and wait for its answer."""
         ki, key, text = req
+        kind = self.kinds[ki]
         rec = {"client": self.number, "kind": ki, "key": key,
                "sent": time.perf_counter(), "answer": None, "error": None}
         try:
-            rec["answer"] = self.kinds[ki].parse(self.conn.query(text))
+            if getattr(kind, "WRITES", False):
+                self.write(kind, text, rec)
+            else:
+                rec["answer"] = kind.parse(self.conn.query(text))
         except Exception as e:  # the boundary: any failure is a failed request
             rec["error"] = f"{type(e).__name__}: {e}"[:300]
         rec["done"] = time.perf_counter()
         return rec
+
+    def write(self, kind, text: dict, rec: dict) -> None:
+        """One transaction, committed at once; an abort is sent again."""
+        from dgraph_tpu.client import RetriableError
+
+        rec["retries"] = 0
+        while True:
+            rec["attempt_sent"] = time.perf_counter()
+            try:
+                data = self.conn.txn().mutate(
+                    set_rdf=text["set"], del_rdf=text.get("delete", ""),
+                    commit_now=True)
+                break
+            except RetriableError:
+                if rec["retries"] == WRITE_RETRIES:
+                    raise
+                rec["retries"] += 1
+        rec["commit_ts"], rec["uids"] = data["commitTs"], data["uids"]
+        rec["answer"] = kind.parse(data)
 
     def warm(self, req=None) -> dict:
         req = self.draw(0) if req is None else req
@@ -151,13 +198,20 @@ def cover(clients, cap_draws: int) -> dict:
         if shape in missing:
             missing.discard(shape)
             found.append((c, req))
-    errors = []
+    errors, sent = [], []
     for i in range(0, len(found), len(clients)):  # rounds, one to a client
         batch = found[i:i + len(clients)]
         recs = in_threads(batch, lambda cr: cr[0].warm(cr[1]))
         errors += [r["error"] for r in recs if r["error"]]
+        sent += recs
     return {"classes": len(needed), "sent": len(found),
-            "unreached": len(missing), "draws": draws, "errors": errors}
+            "unreached": len(missing), "draws": draws, "errors": errors}, sent
+
+
+def committed(recs) -> list:
+    """The writes among `recs` that committed and were answered."""
+    return [r for r in recs
+            if r.get("commit_ts") is not None and r["error"] is None]
 
 
 def main() -> int:
@@ -173,18 +227,23 @@ def main() -> int:
         sys.stdout.flush()
 
     reply({"ready": len(clients)})
+    warm_writes = []
     for line in sys.stdin:
         cmd = json.loads(line)
         if cmd["cmd"] == "warm":
             recs = in_threads(clients, lambda c: c.warm())
+            warm_writes += committed(recs)
             reply({"errors": [r["error"] for r in recs if r["error"]],
                    "seconds": max(r["done"] - r["sent"] for r in recs)})
         elif cmd["cmd"] == "cover":
-            reply(cover(clients, spec["mix"].get("cap_draws", 0)))
+            summary, recs = cover(clients, spec["mix"].get("cap_draws", 0))
+            warm_writes += committed(recs)
+            reply(summary)
         elif cmd["cmd"] == "run":
             t0, recs = window(clients, cmd["seconds"])
             with open(cmd["out"], "wb") as f:
-                pickle.dump({"t0": t0, "records": recs}, f)
+                pickle.dump({"t0": t0, "records": recs,
+                             "warm_writes": warm_writes}, f)
             reply({"t0": t0, "requests": len(recs)})
         elif cmd["cmd"] == "stop":
             break

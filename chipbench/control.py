@@ -1,7 +1,10 @@
 """The control of a cell's comparison: the reference put in the
 program's place with one step of the configuration's guarantee taken
 away (each query kind's `control`), run through the same comparison on
-the same requests the window sent. It has to come out NOT correct.
+the same requests the window sent. It has to come out NOT correct. In a
+mix that writes, each read's control is taken one acknowledged write
+behind (state lo - 1 of `chipbench/history.py`) and judged against the
+program's own history of commits.
 
   python3 -m chipbench.control --workload <cell> --seed <n> --seconds <s>
 
@@ -21,6 +24,7 @@ import sys
 
 import numpy as np
 
+from chipbench import history
 from chipbench import run as harness
 
 
@@ -34,8 +38,13 @@ def summary(numbers: dict) -> dict:
 
 
 def judged(state: dict, answers_of) -> dict:
-    numbers = harness.numbers_of(state["mix"], state["model"],
-                                 state["sample"], answers_of=answers_of)
+    if state["history"] is None:
+        numbers = harness.numbers_of(state["mix"], state["model"],
+                                     state["sample"], answers_of=answers_of)
+    else:
+        numbers = history.numbers(state["mix"], state["kinds"],
+                                  state["model"], state["sample"],
+                                  state["history"], stale=True)
     checks = harness.judge(state["config"], numbers, 0)
     return {"correct": all(c["ok"] for c in checks.values()),
             "raw": summary(numbers),
@@ -45,13 +54,13 @@ def judged(state: dict, answers_of) -> dict:
 
 def control_numbers(state: dict) -> dict:
     """The control's numbers and, where a query kind plants `faults` in
-    the program's own answers, each fault's."""
+    the program's own answers (a mix that reads only), each fault's."""
     model, seed = state["model"], state["seed"]
     out = judged(state, lambda kind, params, keys, answers: kind.control(
         model, params, keys))
     out["program_raw"] = summary(state["numbers"])
-    names = {name for k in state["mix"]["kinds"]
-             for name in getattr(harness.kind_of(k), "FAULTS", ())}
+    names = {name for k in state["kinds"] for name in getattr(k, "FAULTS", ())
+             if state["history"] is None}
     out["faults"] = {
         name: judged(state, lambda kind, params, keys, answers: (
             kind.faults(model, params, keys, answers, seed)[name]

@@ -454,13 +454,18 @@ def _write_rdf(path: str, model: Model) -> int:
     return len(out)
 
 
+def kept(store_dir: str) -> bool:
+    """Whether `store_dir` holds a whole store (its load ran to the end)."""
+    return os.path.exists(os.path.join(store_dir, "LOADED"))
+
+
 def install(config: dict, seed: int, alpha, store_dir: str):
     """Build the model; open the kept store of this seed, or bulk-load
     and sync one — what `dgraph-tpu bulk` then `dgraph-tpu alpha` do.
     Returns (model, {"loaded": bool, ...})."""
     p_dir = os.path.join(store_dir, "p")
     done = os.path.join(store_dir, "LOADED")
-    if os.path.exists(done):
+    if kept(store_dir):
         model = make(config, seed)
         t0 = time.perf_counter()
         alpha.open(p_dir)

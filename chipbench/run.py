@@ -7,7 +7,9 @@ the closed-loop clients run in a child process (`chipbench/client.py`).
 Set-up (data, store, index, upload, compile or cache load, warm-up)
 ends when the window's first request is sent. After the window closes
 the device's peak memory is read, the alpha is stopped, and the answers
-the window produced are compared with the plain reference.
+the window produced are compared with the plain reference. A mix whose
+query kinds write is judged against the history of its commits
+(`chipbench/history.py`), on a copy of the kept store (`install`).
 
 Everything that belongs to one configuration, mix, query kind, layer
 metric or planted fault is a file of its own, found by the name in
@@ -45,9 +47,12 @@ import tempfile
 
 import numpy as np
 
+from chipbench import client, history
+
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 KEEP_STORES = 16  # over the seeds of a check's two sets, so that none is evicted
+RUN_COPY = "run-copy"  # a writing run's store, inside the kept store's directory
 QUIET_ROUNDS, CAP_ROUNDS = 2, 12  # warm-up: rounds that compile nothing, cap
 
 
@@ -114,6 +119,53 @@ def store_dir(config: dict, maker, seed: int) -> str:
     return os.path.join(base, mine)
 
 
+def copy_store(kept: str, dst: str) -> None:
+    """`kept` as it stands, at `dst`: the LSM's tables (`*.tbl`, written
+    once under another name and renamed into place, read through a
+    read-only map, removed whole) as hard links, every other file (the
+    WAL, which is appended to in place, the manifest, the maker's
+    markers) as a copy. What a run writes lands in new files of the copy
+    or in its own WAL, and the kept store's bytes stay as they were
+    (`chipbench/tests` pins that)."""
+
+    def put(src: str, to: str):
+        if src.endswith(".tbl"):
+            try:
+                return os.link(src, to)
+            except OSError:  # another file system: copy it
+                pass
+        return shutil.copy2(src, to)
+
+    shutil.rmtree(dst, ignore_errors=True)  # left by a run that was cut
+    shutil.copytree(kept, dst, copy_function=put,
+                    ignore=lambda d, names: [RUN_COPY] if d == kept else [])
+
+
+def install(maker, config: dict, seed: int, alpha, writes: bool):
+    """The maker's `install` on this seed's kept store: (model, what it
+    reports, the directory to remove after the run or None). A mix that
+    writes never opens the kept store: where the maker keeps stores
+    (`kept`), it opens a copy taken before the alpha opens it (built
+    first, as ever, where there is no kept store yet), and `copy_s`
+    says what the copy cost; no later run of the seed meets its writes."""
+    kept = store_dir(config, maker, seed)
+    if not (writes and hasattr(maker, "kept")):
+        return (*maker.install(config, seed, alpha, kept), None)
+    built = None
+    if not maker.kept(kept):
+        built = maker.install(config, seed, alpha, kept)[1]
+        alpha.close()
+    copy = os.path.join(kept, RUN_COPY)
+    t0 = time.perf_counter()
+    copy_store(kept, copy)
+    copy_s = time.perf_counter() - t0
+    model, info = maker.install(config, seed, alpha, copy)
+    info = dict(info, copy_s=copy_s)
+    if built is not None:
+        info["built"] = built
+    return model, info, copy
+
+
 class Child:
     """The load generator's process and its line protocol."""
 
@@ -177,6 +229,40 @@ def warm_up(child: Child, clock, mix: dict) -> dict:
             "cover": {k: v for k, v in covered.items() if k != "errors"}}
 
 
+class GcClock:
+    """The collector's passes in this process, which hosts the handler
+    threads, while `on`: per generation, how many and their seconds. A
+    pass holds the interpreter's lock, so this is a witness for a slow
+    window; no metric reads it."""
+
+    def __init__(self):
+        self.on = False
+        self.count, self.seconds = [0, 0, 0], [0.0, 0.0, 0.0]
+        self._t = 0.0
+        gc.callbacks.append(self)
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._t = time.perf_counter()
+        elif self.on:
+            self.count[info["generation"]] += 1
+            self.seconds[info["generation"]] += time.perf_counter() - self._t
+
+    def close(self) -> dict:
+        gc.callbacks.remove(self)
+        return {"count": self.count,
+                "seconds": [round(s, 4) for s in self.seconds]}
+
+
+def answered_by_bin(recs: list, t0: float, seconds: float,
+                    width: float = 5.0) -> list:
+    """Answers per `width` seconds of the window, for whoever reads a
+    far-off run: a stall shows as one low bin, a slow process as all."""
+    done = [r["done"] - t0 for r in recs if r["error"] is None]
+    edges = np.arange(0.0, seconds + width / 2, width)
+    return np.histogram(done, edges)[0].tolist()
+
+
 def percentile(values, p: float) -> float:
     return float(np.percentile(np.asarray(values, np.float64), p))
 
@@ -198,8 +284,9 @@ def end_to_end(name: str, recs: list, t0: float, seconds: float,
 
 
 def sample_records(recs: list, mix: dict, seed: int) -> list:
-    """The answers compared: all of them, or a sample drawn from the
-    seed with the slowest request in it."""
+    """The answers compared (reads: a write is judged by the reads that
+    see it): all of them, or a sample drawn from the seed with the
+    slowest request in it."""
     ok = [r for r in recs if r["error"] is None]
     n = mix.get("compare_sample", 0)
     if not n or len(ok) <= n:
@@ -325,15 +412,18 @@ def run(args, after=None) -> dict:
         f"{args.workload} seed {args.seed} on {device}")
 
     maker = importlib.import_module(f"chipbench.data.{config['data']}")
+    kinds = [kind_of(k) for k in mix["kinds"]]
+    writes = history.writing(kinds)
     clock = alpha_mod.CompileClock()
+    gc_clock = GcClock()
     alpha = alpha_mod.Alpha()
     fetches = alpha.fetches
-    child = None
+    child = copy = None
     tmp = tempfile.mkdtemp(prefix="chipbench_")
     try:
-        model, install = maker.install(
-            config, args.seed, alpha, store_dir(config, maker, args.seed))
-        say(f"installed: {install}")
+        model, install_info, copy = install(maker, config, args.seed, alpha,
+                                            writes)
+        say(f"installed: {install_info}")
         child = Child({"url": alpha.serve(), "config": config, "mix": mix,
                        "seed": args.seed})
         warm = warm_up(child, clock, mix)
@@ -347,11 +437,13 @@ def run(args, after=None) -> dict:
         cpu0 = time.process_time()
         setup_s = time.perf_counter() - T0
         trace = None
+        gc_clock.on = True
         if args.trace:
             reply, trace = traced(child, args.seconds, mix, out_path)
         else:
             reply = child.ask({"cmd": "run", "seconds": args.seconds,
                                "out": out_path})
+        gc_clock.on = False
         c1, f1 = clock.snap(), fetches.snap()
         peak = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
                    for d in devs)
@@ -369,6 +461,9 @@ def run(args, after=None) -> dict:
             child.close()
         alpha.close()
         shutil.rmtree(tmp, ignore_errors=True)
+        if copy is not None:
+            shutil.rmtree(copy, ignore_errors=True)
+        gc_window = gc_clock.close()
     gc.collect()
 
     t0, recs = got["t0"], got["records"]
@@ -381,7 +476,9 @@ def run(args, after=None) -> dict:
     # a witness of a stalled host, for whoever reads a far-off run
     ends = np.sort([t0] + [r["done"] for r in recs])
     longest_gap_s = float(np.max(np.diff(ends))) if len(recs) else 0.0
-    say(f"longest time with no answer: {longest_gap_s:.3f}s")
+    by_bin = answered_by_bin(recs, t0, args.seconds)
+    say(f"longest time with no answer: {longest_gap_s:.3f}s; answers by 5 s "
+        f"{by_bin}; the collector in the window {gc_window}")
     metrics = {}
     ctx = None
     if args.trace:
@@ -404,7 +501,7 @@ def run(args, after=None) -> dict:
             "requests": sum(1 for r in recs if r["error"] is None),
             "fetches": alpha_mod.delta(f0, f1),
             "compiles_in_window": c1["compiles"] - c0["compiles"],
-            "install": install, "warm": warm, "describe": describe,
+            "install": install_info, "warm": warm, "describe": describe,
             "config": config, "device_kind": device["kind"],
             "peaks": load_json(os.path.join(HERE, "peaks.json")),
         }
@@ -423,16 +520,31 @@ def run(args, after=None) -> dict:
     # the comparison that decides `correct`: after the window has closed,
     # the peak has been read and the alpha's state is freed
     t_ref = time.perf_counter()
-    sample = sample_records(recs, mix, args.seed)
-    if captured and mix.get("compare_sample"):
-        pick = np.random.default_rng([args.seed, 78]).permutation(
-            len(captured))[: mix["compare_sample"]]
-        captured = [captured[i] for i in sorted(pick)]
-    numbers = numbers_of(mix, model, sample, captured)
+    past = None
+    if writes:
+        # every committed write of the run, in commit order
+        past = sorted(got["warm_writes"] + client.committed(recs),
+                      key=lambda r: r["commit_ts"])
+        wrote = {i for i, k in enumerate(kinds) if getattr(k, "WRITES", False)}
+        sample = sample_records([r for r in recs if r["kind"] not in wrote],
+                                mix, args.seed)
+        if captured:
+            raise ValueError("a writing mix takes no tapped programs")
+        numbers = history.numbers(mix, kinds, model, sample, past)
+    else:
+        sample = sample_records(recs, mix, args.seed)
+        if captured and mix.get("compare_sample"):
+            pick = np.random.default_rng([args.seed, 78]).permutation(
+                len(captured))[: mix["compare_sample"]]
+            captured = [captured[i] for i in sorted(pick)]
+        numbers = numbers_of(mix, model, sample, captured)
     checks = judge(config, numbers, failed_requests)
     correct = all(c["ok"] for c in checks.values())
-    say(f"compared {len(sample)} of {len(recs)} answers in "
-        f"{time.perf_counter() - t_ref:.1f}s")
+    compare_s = time.perf_counter() - t_ref
+    say(f"compared {len(sample)} of {len(recs)} answers"
+        + (f" against {len(past)} committed writes (retried "
+           f"{sum(w['retries'] for w in past)} times)" if writes else "")
+        + f" in {compare_s:.1f}s")
 
     result = {"correct": correct, "attempted": len(recs),
               "failed": failed_requests + int(
@@ -446,14 +558,17 @@ def run(args, after=None) -> dict:
             "idle_gaps": [[n, s] for n, s in by_span.items()][:10],
         }
     result["rehearsal"] = bool(args.rehearsal)
-    result["setup"] = {"install": install, "warm": warm,
+    result["compare_s"] = compare_s
+    result["setup"] = {"install": install_info, "warm": warm,
                        "compiles": c0, "compiles_in_window":
                        c1["compiles"] - c0["compiles"],
                        "longest_gap_s": longest_gap_s,
-                       "alpha_cpu_s": alpha_cpu_s}
+                       "alpha_cpu_s": alpha_cpu_s,
+                       "answered_by_5s": by_bin, "gc_in_window": gc_window}
     if after is not None:
         result["after"] = after({"model": model, "config": config,
-                                 "mix": mix, "sample": sample,
+                                 "mix": mix, "kinds": kinds,
+                                 "sample": sample, "history": past,
                                  "numbers": numbers, "captured": captured,
                                  "seed": args.seed})
     result["checks"] = {n: [c["value"], c["op"], c["limit"]]

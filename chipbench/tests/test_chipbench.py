@@ -162,6 +162,8 @@ def test_rehearsal_runs_a_cell_end_to_end(name, trace):
     assert out["rehearsal"] is True and list(out)[-1] == "checks"
     assert out["correct"] is True and out["failed"] == 0
     assert out["attempted"] > 0
+    # a mix that only reads opens the kept store in place: no copy
+    assert "copy_s" not in out["setup"]["install"]
     group = "per_layer" if trace else "end_to_end"
     want = {m["name"] for m in BENCH[group]
             if "workloads" not in m or name in m["workloads"]}
@@ -335,6 +337,116 @@ def test_a_lone_miss_of_the_approximate_index_stays_correct():
     verdict = _vector_verdict(vconf, model, params, keys, answers)
     assert verdict["inexact_answers"]["ok"]
     assert not verdict["dist_excess"]["ok"]
+
+
+# -- the judging rule of a mix that writes, on histories made by hand -------------
+
+
+def _toy():
+    """A mix over a model that is a set of names: kind 0 writes (adds
+    its key), kind 1 reads (answers the names, sorted); the control
+    answers as the reference does."""
+    from types import SimpleNamespace
+
+    add = SimpleNamespace(
+        WRITES=True,
+        apply=lambda model, params, key, answer: model.add(key),
+        check=lambda model, params, keys, answers, captured=None: {})
+    look = SimpleNamespace(
+        check=lambda model, params, keys, answers, captured=None: {
+            "wrong_answers": [float(a != sorted(model)) for a in answers]},
+        control=lambda model, params, keys: ([sorted(model)] * len(keys),
+                                              None))
+    return {"kinds": [{"kind": "add", "params": {}},
+                      {"kind": "look", "params": {}}]}, [add, look]
+
+
+def _write(key, sent, done, commit_ts):
+    return {"kind": 0, "key": key, "answer": "0x1", "sent": sent,
+            "attempt_sent": sent, "done": done, "commit_ts": commit_ts,
+            "retries": 0, "error": None}
+
+
+def _read(sent, done, answer):
+    return {"kind": 1, "key": None, "answer": answer, "sent": sent,
+            "done": done, "error": None}
+
+
+# (writes as (key, sent, done, commit ts), the read as (sent, done,
+# answer), wrong, changed by writes, order violated)
+HISTORIES = {
+    "read_after_ack_misses_the_write": (
+        [("a", 0, 1, 1)], (2, 3, []), 1, 0, 0),
+    "read_after_ack_sees_the_write": (
+        [("a", 0, 1, 1)], (2, 3, ["a"]), 0, 1, 0),
+    "concurrent_write_missed": (
+        [("a", 1, 3, 1)], (0.5, 2.5, []), 0, 0, 0),
+    "concurrent_write_seen": (
+        [("a", 1, 3, 1)], (0.5, 2.5, ["a"]), 0, 1, 0),
+    "read_sees_a_write_sent_after_its_answer": (
+        [("a", 2, 3, 1)], (0, 1, ["a"]), 1, 0, 0),
+    "read_sees_w2_without_w1": (
+        [("a", 0, 5, 1), ("b", 0, 5, 2)], (1, 2, ["b"]), 1, 0, 0),
+    "read_sees_a_prefix_of_the_commits": (
+        [("a", 0, 5, 1), ("b", 0, 5, 2)], (1, 2, ["a"]), 0, 1, 0),
+    "commit_order_against_real_time": (
+        [("a", 0, 1, 2), ("b", 3, 4, 1)], (2, 2.5, ["a"]), 1, 0, 1),
+}
+
+
+@pytest.mark.parametrize("case", sorted(HISTORIES))
+def test_a_read_is_judged_by_the_commits_it_could_have_seen(case):
+    from chipbench import history
+
+    writes, (sent, done, answer), wrong, changed, violated = HISTORIES[case]
+    mix, kinds = _toy()
+    past = sorted((_write(*w) for w in writes), key=lambda w: w["commit_ts"])
+    got = history.numbers(mix, kinds, set(), [_read(sent, done, answer)],
+                          past)
+    assert got["wrong_answers"] == [float(wrong)]
+    assert got["reads_changed_by_writes"] == [float(changed)]
+    assert got["order_violations"] == [float(violated)]
+    assert got["writes_committed"] == [1.0] * len(writes)
+
+
+def test_the_control_reads_one_acknowledged_write_behind():
+    from chipbench import history
+
+    mix, kinds = _toy()
+    reads = [_read(2, 3, ["a"]), _read(0, 0.5, [])]
+    past = [_write("a", 0, 1, 1)]
+    assert history.numbers(mix, kinds, set(), reads, past)[
+        "wrong_answers"] == [0.0, 0.0]
+    # the read sent after the ack answers as state 0 does; the read
+    # before any write has no state behind it and stays right
+    assert history.numbers(mix, kinds, set(), reads, past, stale=True)[
+        "wrong_answers"] == [1.0, 0.0]
+
+
+def test_a_mix_that_only_reads_gets_the_numbers_it_always_got():
+    """With no write the rule has one state: the numbers are
+    `run.numbers_of`'s, name for name and answer for answer, a wrong
+    answer among them, and the harness adds none of its own."""
+    from chipbench import history, run
+    from chipbench.data import snb
+
+    config = _rehearsal_config("snb.short16")
+    model, cat = snb.make(config, 5), snb.catalog(config, 5)
+    mix = mix_of("snb.short16")
+    kinds = [run.kind_of(k) for k in mix["kinds"]]
+    rng = np.random.default_rng(3)
+    recs = []
+    for i in range(40):
+        ki = i % len(kinds)
+        params = mix["kinds"][ki]["params"]
+        key = kinds[ki].request(cat, params, rng)[0]
+        recs.append({"kind": ki, "key": key, "sent": i, "done": i + 0.5,
+                     "error": None,
+                     "answer": kinds[ki].reference(model, params, [key])[0]})
+    recs[3]["answer"] = recs[3]["answer"][1:] or [("nobody",)]
+    old = run.numbers_of(mix, model, recs)
+    assert history.numbers(mix, kinds, model, recs, []) == old
+    assert sum(old["wrong_answers"]) == 1
 
 
 # -- the trace reduction ----------------------------------------------------------
@@ -808,4 +920,49 @@ def test_new_pieces_need_only_new_files(tmp_path):
     assert added == {"configs/snb-small.json", "queries/ic1_again.py",
                      "queries/newest.py", "faults/order_drops_first.py",
                      "mixes/walls.json", "layer_metrics/requests_answered.py"}
+    assert all(after[p] == data for p, data in before.items())
+
+
+def test_a_writing_cell_needs_only_new_files(tmp_path, monkeypatch):
+    """In a copy of the benchmark, with no file of the copy edited: a
+    query kind that writes, a maker whose model takes what it writes, a
+    mix that pairs it with `is3`, a fault that serves reads one commit
+    behind (`chipbench/tests/write_cell.py`). The rehearsal is correct
+    with writes committed and reads that saw them; the fault and the
+    control are not; the seed's kept store is not touched by any run
+    after the one that built it."""
+    from chipbench import run
+    from chipbench.tests import write_cell
+
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    before = write_cell.build(str(tmp_path))
+
+    def run_it(module, *args):
+        return last_json(write_cell.drive(str(tmp_path), module, *args))
+
+    out = run_it("chipbench.run", "--trace", "0", "--rehearsal")
+    assert out["correct"] is True and out["failed"] == 0
+    assert out["checks"]["writes_committed"][0] >= 1
+    assert out["checks"]["reads_changed_by_writes"][0] >= 1
+    install = out["setup"]["install"]
+    assert install["built"]["loaded"] is True and install["copy_s"] >= 0
+    store = tmp_path / "chipbench" / ".store"
+    kept = {p: p.read_bytes() for p in store.rglob("*") if p.is_file()}
+    assert kept and not any(run.RUN_COPY in p.parts for p in kept)
+
+    out = run_it("chipbench.tests.faults", "reads_behind", "--trace", "0")
+    assert out["correct"] is False and out["checks"]["wrong_answers"][0] > 0
+    assert "built" not in out["setup"]["install"]
+
+    out = run_it("chipbench.control", "--rehearsal")
+    assert out["program"]["correct"] is True
+    assert out["control"]["correct"] is False
+
+    assert {p: p.read_bytes() for p in store.rglob("*")
+            if p.is_file()} == kept
+    after = {str(p): p.read_bytes()
+             for p in (tmp_path / "chipbench").rglob("*") if p.is_file()
+             and ".store" not in p.parts and "__pycache__" not in p.parts}
+    cb = str(tmp_path / "chipbench") + os.sep
+    assert {p[len(cb):] for p in set(after) - set(before)} == write_cell.ADDED
     assert all(after[p] == data for p, data in before.items())

@@ -85,15 +85,26 @@ def test_reader_gives_none_without_records_and_zero_is_a_reading(name):
     assert got == (1.0 if name == "host_stall_ms_per_req" else 0.0)
 
 
+def test_the_value_columns_host_side_is_the_boundarys():
+    """`snb.ic9` dispatches through `valcol.*`: its pad, upload and
+    launch are the device boundary's host side, its wait is not."""
+    rec = record(10.0, {"valcol.pad": 1.0, "valcol.upload": 2.0,
+                        "valcol.launch": 3.0, "process": 4.0,
+                        "valcol.wait": 0.5}, {"valcol.wait": 5.0})
+    got = reader("boundary_cpu_ms_per_req").read({"span_records": [rec]})
+    assert got == 6.0
+
+
 def test_every_new_reader_is_declared_with_its_cells():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     declared = {m["name"]: m for m in bench["per_layer"]}
     for name in NEW:
         m = declared[name]
-        cells = ["snb.ic1"] if name == "level_read_cpu_ms_per_req" else [
-            "snb.ic1", "vec.solo16"]
-        assert m["workloads"] == cells
+        # every cell that sends `/query` requests; vec.solo16's read no level
+        cells = ["snb.ic1", "snb.short16", "snb.ic9"] + (
+            [] if name == "level_read_cpu_ms_per_req" else ["vec.solo16"])
+        assert sorted(m["workloads"]) == sorted(cells)
         assert m["better"] == "lower"
         assert m["source"] == ("program_span"  # it counts launch spans
                                if name == "dispatches_per_req"
