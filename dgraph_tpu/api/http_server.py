@@ -779,27 +779,32 @@ class _Handler(BaseHTTPRequestHandler):
         commit_now = qs.get("commitNow", ["false"])[0] == "true"
         start_ts = int(qs.get("startTs", ["0"])[0])
 
-        if start_ts and start_ts in self.txns:
-            txn = self.txns[start_ts]
-        else:
-            txn = self.engine.new_txn()
+        # `mutate`: the transaction, its N-Quads' parse and apply
+        # (`mutate.parse`, `mutate.apply`) and, with commitNow, its
+        # commit (`commit.wait`, server.py)
+        with TRACER.span("mutate", cpu=True):
+            if start_ts and start_ts in self.txns:
+                txn = self.txns[start_ts]
+            else:
+                txn = self.engine.new_txn()
 
-        if "json" in ctype:
-            uids = txn.mutate_json(
-                set_obj=obj.get("set"),
-                del_obj=obj.get("delete"),
-                access_jwt=token,
-            )
-        else:
-            # RDF body: {set { ... } delete { ... }} or bare nquads
-            set_rdf, del_rdf = _split_rdf_blocks(body)
-            uids = txn.mutate_rdf(
-                set_rdf=set_rdf, del_rdf=del_rdf, access_jwt=token
-            )
+            if "json" in ctype:
+                uids = txn.mutate_json(
+                    set_obj=obj.get("set"),
+                    del_obj=obj.get("delete"),
+                    access_jwt=token,
+                )
+            else:
+                # RDF body: {set { ... } delete { ... }} or bare nquads
+                set_rdf, del_rdf = _split_rdf_blocks(body)
+                uids = txn.mutate_rdf(
+                    set_rdf=set_rdf, del_rdf=del_rdf, access_jwt=token
+                )
 
+            if commit_now:
+                self.txns.pop(txn.start_ts, None)  # finished: no linger
+                commit_ts = txn.commit()
         if commit_now:
-            self.txns.pop(txn.start_ts, None)  # finished txns don't linger
-            commit_ts = txn.commit()
             self._reply(
                 {
                     "data": {

@@ -12,6 +12,8 @@ through dql.parse -> query.Executor -> JsonEncoder.
 
 from __future__ import annotations
 
+import contextlib
+import itertools
 import json
 import threading
 import time
@@ -45,6 +47,19 @@ from dgraph_tpu.types.types import TypeID, Val
 from dgraph_tpu.utils import observe
 from dgraph_tpu.x import keys
 from dgraph_tpu.zero.zero import TxnConflictError, ZeroLite
+
+
+@contextlib.contextmanager
+def _applying(txn: Txn, edges: Optional[int]):
+    """`mutate.apply` around one mutation's edges going into `txn`:
+    `edges` (the N-Quads applied, where the caller counted them) and
+    `keys` (keys holding a delta of the transaction afterwards; a
+    columnar write set names its keys only at commit)."""
+    with observe.TRACER.span("mutate.apply", cpu=True, fine=True) as sp:
+        yield
+        if edges is not None:
+            sp.attrs["edges"] = edges
+        sp.attrs["keys"] = len(txn.cache.deltas)
 
 
 class TxnHandle:
@@ -87,7 +102,10 @@ class TxnHandle:
     ) -> Dict[str, str]:
         from dgraph_tpu.loaders.rdf import parse_rdf as _prdf
 
-        set_nqs, del_nqs = _prdf(set_rdf), _prdf(del_rdf)
+        with observe.TRACER.span("mutate.parse", cpu=True, fine=True) as sp:
+            set_nqs, del_nqs = _prdf(set_rdf), _prdf(del_rdf)
+            sp.attrs["nquads"] = len(set_nqs) + len(del_nqs)
+        observe.METRICS.inc("mutate_nquads_total", len(set_nqs) + len(del_nqs))
         body = f"set:{set_rdf!r} del:{del_rdf!r}"
         ns, user = self.server._authorize_mutation(
             access_jwt,
@@ -95,7 +113,8 @@ class TxnHandle:
             body,
         )
         self.txn.tenant_ns = ns  # per-tenant commit SLO slice
-        uids = self.server._apply_nquads(self.txn, set_nqs, del_nqs, ns)
+        with _applying(self.txn, len(set_nqs) + len(del_nqs)):
+            uids = self.server._apply_nquads(self.txn, set_nqs, del_nqs, ns)
         if commit_now:
             self.commit()
         return uids
@@ -121,7 +140,8 @@ class TxnHandle:
                 body,
             )
         self.txn.tenant_ns = ns  # per-tenant commit SLO slice
-        uids = self.server._apply_json(self.txn, set_obj, del_obj, ns)
+        with _applying(self.txn, None):
+            uids = self.server._apply_json(self.txn, set_obj, del_obj, ns)
         if commit_now:
             self.commit()
         return uids
@@ -609,11 +629,16 @@ class Server:
         n_edges = txn.pending_postings()
         ticket = self.serving.admit_write(n_edges)
         t_commit0 = time.monotonic()
+        # `commit.wait`: this committer's time from asking to commit to
+        # its commit's apply barrier; where its own thread ran the batch
+        # (or the serial path), the `commit` span inside holds that work
+        waited = observe.TRACER.span("commit.wait", fine=True)
         try:
             if not bool(_config.get("GROUP_COMMIT")):
                 # escape hatch (DGRAPH_TPU_GROUP_COMMIT=0): today's
                 # serial per-txn path, byte-for-byte
-                commit_ts = self._commit_serial(txn)
+                with waited:
+                    commit_ts = self._commit_serial(txn)
             else:
                 gc = self._group_commit
                 if gc is None:
@@ -628,7 +653,7 @@ class Server:
                                 self._gc_propose,
                                 serial_fn=self._gc_serial,
                             )
-                with _METRICS.timer("commit_latency_seconds"):
+                with _METRICS.timer("commit_latency_seconds"), waited:
                     commit_ts = gc.commit(txn)
                 if not getattr(txn, "gc_bypassed", False):
                     # the bypass ran the serial path, whose inline
@@ -663,7 +688,11 @@ class Server:
         barrier (watermark + zero.applied in commit-ts order)."""
         from dgraph_tpu.utils.observe import METRICS, TRACER
 
-        with TRACER.span("commit", batch=len(members)):
+        METRICS.inc("commit_batches_total")
+        # the batch's work, once, in the tree of the thread that leads
+        # it; its apply barrier runs later on the same thread and adds
+        # `apply_ms` when done
+        with TRACER.span("commit", batch=len(members)) as sp:
             t0 = time.perf_counter_ns()
             committed = assign_verdicts(
                 members,
@@ -703,8 +732,10 @@ class Server:
                 for m in committed:
                     if m.error is None:
                         m.error = e
-            commit_phase_ns(
-                oracle=t1 - t0, propose=time.perf_counter_ns() - t1
+            t2 = time.perf_counter_ns()
+            commit_phase_ns(oracle=t1 - t0, propose=t2 - t1)
+            sp.attrs.update(
+                oracle_ms=(t1 - t0) / 1e6, propose_ms=(t2 - t1) / 1e6
             )
 
         def barrier():
@@ -712,7 +743,8 @@ class Server:
             try:
                 with self._lock:
                     for m in committed:
-                        self._columns_commit(m.txn, m.commit_ts)
+                        self._columns_commit(
+                            m.txn, m.commit_ts, m.error is None)
                         # watermark BEFORE the apply barrier, advanced
                         # in commit-ts order (members cts-ascending,
                         # barriers FIFO) — the micro-batcher's
@@ -746,18 +778,54 @@ class Server:
                 if ok:
                     METRICS.inc("num_commits", ok)
                     self.serving.on_commit()  # ONE epoch bump per batch
-                commit_phase_ns(apply=time.perf_counter_ns() - tb)
+                applied = time.perf_counter_ns() - tb
+                commit_phase_ns(apply=applied)
+                sp.attrs["apply_ms"] = applied / 1e6
 
         return barrier
 
-    def _columns_commit(self, txn: Txn, commit_ts: int) -> None:
-        """Tell the value columns which keys `commit_ts` wrote, before
-        the watermark lets a reader see them (query/valcol.py)."""
-        cols = self.mem.value_columns
-        cols.note_commit(txn.cache.deltas.keys(), commit_ts)
-        ck = getattr(txn, "col_keys", None)
-        if ck:
-            cols.note_commit(ck, commit_ts)
+    def _columns_commit(self, txn: Txn, commit_ts: int, wrote: bool) -> None:
+        """Tell the value columns which keys `commit_ts` wrote, and how
+        to read each one's value as the commit left it, before the
+        watermark lets a reader see them (query/valcol.py). Where the
+        commit's write landed (`wrote`), a key's value is that of what
+        it wrote there, its deltas or its columnar record: every posting
+        of a scalar predicate has one uid, so the newest write decides
+        the value whatever was there before. A commit whose write failed
+        is read back from the store at `commit_ts`, a read that gives
+        the interpreter's lock away inside the commit barrier: read back
+        for every row, the barrier took 10.6-11.2 s of a 45 s
+        `snb.mixed16` window on a v5e's host, 0.54-1.37 s without."""
+        from dgraph_tpu.posting.pl import PostingList, decode_record
+
+        deltas = txn.cache.deltas
+        records = None
+
+        def value_of(key: bytes) -> Optional[Val]:
+            nonlocal records
+            posts = deltas.get(key) if wrote else None
+            if wrote and posts is None:
+                if records is None:
+                    records = {k: rec for k, rec, _ in
+                               getattr(txn, "col_records", None) or ()}
+                rec = records.get(key)
+                posts = decode_record(rec)[2] if rec is not None else None
+            if posts:
+                return PostingList(key).get_value("", posts)
+            return self._value_at(key, commit_ts)
+
+        self.mem.value_columns.note_commit(
+            itertools.chain(deltas, getattr(txn, "col_keys", None) or ()),
+            commit_ts, value_of)
+
+    def _value_at(self, key: bytes, ts: int) -> Optional[Val]:
+        """A scalar data key's value at `ts`, past every cache; None
+        where it has none."""
+        from dgraph_tpu.posting.pl import PostingList
+
+        return PostingList.from_versions(
+            key, self.kv.versions(key, ts), kv=self.kv, read_ts=ts
+        ).get_value()
 
     def _columns_reset(self) -> None:
         """The store was written past the commit path (a bulk load, an
@@ -791,13 +859,12 @@ class Server:
     def _commit_serial(self, txn: Txn, timed: bool = True) -> int:
         # serialized: MemKV is single-writer, and readers must not see a
         # commit_ts whose deltas aren't written yet (ADVICE r1 #2)
-        import contextlib
-
         from dgraph_tpu.utils.observe import METRICS, TRACER
 
         from dgraph_tpu.worker.groupcommit import commit_phase_ns
 
-        with TRACER.span("commit"), (
+        METRICS.inc("commit_batches_total")  # a batch of one
+        with TRACER.span("commit", batch=1) as sp, (
             METRICS.timer("commit_latency_seconds")
             if timed
             else contextlib.nullcontext()
@@ -805,22 +872,24 @@ class Server:
             t0 = time.perf_counter_ns()
             commit_ts = self.zero.commit(txn.start_ts, txn.conflict_keys, track=True)
             t1 = time.perf_counter_ns()
+            wrote = False
             try:
                 txn.write_deltas(self.kv, commit_ts)
+                wrote = True
             finally:
                 t2 = time.perf_counter_ns()
-                self._columns_commit(txn, commit_ts)
+                self._columns_commit(txn, commit_ts, wrote)
                 # watermark BEFORE the apply barrier: any read_ts
                 # allocated after this commit becomes visible observes
                 # the advanced watermark (micro-batcher snapshot key);
                 # max() guards a concurrent bump_snapshot
                 self._snapshot_ts = max(self._snapshot_ts, commit_ts)
                 self.zero.applied(commit_ts)
-                commit_phase_ns(
-                    oracle=t1 - t0,
-                    propose=t2 - t1,
-                    apply=time.perf_counter_ns() - t2,
-                )
+                t3 = time.perf_counter_ns()
+                commit_phase_ns(oracle=t1 - t0, propose=t2 - t1, apply=t3 - t2)
+                sp.attrs.update(oracle_ms=(t1 - t0) / 1e6,
+                                propose_ms=(t2 - t1) / 1e6,
+                                apply_ms=(t3 - t2) / 1e6)
         METRICS.inc("num_commits")
         self.mem.invalidate(txn.cache.deltas.keys())
         ck = getattr(txn, "col_keys", None)

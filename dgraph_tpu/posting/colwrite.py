@@ -605,8 +605,9 @@ def _encode_colsets(colsets: List[ColumnarWriteSet]):
 def encode_txn(txn) -> List[Tuple[bytes, bytes, str]]:
     """Serial-commit encode of one txn's columnar write set: returns
     ready-to-put (key, record, attr) triples and stamps the side
-    channels (col_keys for invalidation, col_stats for the selectivity
-    sketch, col_nposts for the postings-written metric). Falls back to
+    channels (col_keys for invalidation, col_records for the value
+    columns, col_stats for the selectivity sketch, col_nposts for the
+    postings-written metric). Falls back to
     materialize (returning []) when the kernel refuses — the caller's
     ordinary deltas path then handles everything."""
     col = getattr(txn, "col", None)
@@ -620,6 +621,7 @@ def encode_txn(txn) -> List[Tuple[bytes, bytes, str]]:
     out, side = got
     mkeys, stats_rows, nposts = side[0]
     txn.col_keys = mkeys
+    txn.col_records = out[0]
     txn.col_stats = stats_rows
     txn.col_nposts = nposts
     col.note_traffic()
@@ -650,6 +652,7 @@ def batch_encode(members) -> Dict[object, List[Tuple[bytes, bytes, str]]]:
     result = {}
     for m, pairs, (mkeys, stats_rows, nposts) in zip(live, out, side):
         m.txn.col_keys = mkeys
+        m.txn.col_records = pairs
         m.txn.col_stats = stats_rows
         m.txn.col_nposts = nposts
         m.txn.col.note_traffic()

@@ -2808,25 +2808,57 @@ declare_metric(
     "predicate's data keys at the request's read timestamp, its uids "
     "and value ranks uploaded into the DeviceCache. A build happens on "
     "the first filter or order that brings the predicate at least the "
-    "device line's candidates, and again after a commit to the "
-    "predicate or an eviction; inside a steady window it reads 0.",
-)
+    "device line's candidates, and again after a drop "
+    "(value_column_invalidations_total); a commit the column follows "
+    "and an eviction build nothing, so inside a steady window, writes "
+    "and all, it reads 0.")
 declare_metric(
     "counter", "value_column_invalidations_total",
-    "Resident value columns dropped because a commit, an alter, a bulk "
-    "load or a tablet move touched their predicate, before the change "
-    "became readable.",
+    "Resident value columns dropped because a change they cannot "
+    "follow touched their predicate, before it became readable: a "
+    "commit of a value of another type, a NaN or a uid under another "
+    "high-32 segment, a commit whose values the engine cannot say, an "
+    "alter, a bulk load, a restore, a tablet move. A commit of values "
+    "the column can take patches it (value_column_patched_rows_total).",
 )
 declare_metric(
     "gauge", "value_column_rows",
     "Rows (uids with a value) of the value columns resident now.",
 )
 declare_metric(
+    "counter", "value_column_patched_rows_total",
+    "Rows that commits wrote to predicates with a value column, or "
+    "with one being built, taken into the predicate's delta in the "
+    "commit barrier (query/valcol.py note_commit; span valcol.patch) "
+    "instead of dropping the column: a reader at or above the commit "
+    "sees them over the column's base.",
+)
+declare_metric(
+    "gauge", "value_column_delta_rows",
+    "Rows committed past the base of the value columns resident now: "
+    "what a reader lays over the device's arrays (span valcol.delta), "
+    "until a merge makes them the base.",
+)
+declare_metric(
+    "counter", "value_column_delta_rows_read_total",
+    "Delta rows laid over a column's base, summed over the uses (the "
+    "`rows` of the valcol.delta spans, exact where those ride in one "
+    "tree per 50 ms): over the column#filter and column#narrow "
+    "dispatches, the delta a use reads.",
+)
+declare_metric(
+    "counter", "value_column_merges_total",
+    "Value columns whose delta outgrew its bound and was merged with "
+    "the base on the host and uploaded as a new base, off the "
+    "request's path and with no scan (query/valcol.py).",
+)
+declare_metric(
     "counter", "value_column_fallback_total{why=\"*\"}",
     "Filters and orders that brought a predicate the device line's "
     "candidates and were answered value by value all the same: `stale` "
-    "(the request reads below the timestamp the column was built at, "
-    "or below the predicate's last commit with no column to use), "
+    "(the request reads below the timestamp of the column's base and "
+    "of the base a merge replaced, or below the newest commit the "
+    "predicate's delta no longer holds with no column to use), "
     "`txn` (the transaction holds its own write to the predicate), "
     "`type` (a list, @lang or non-numeric predicate, stored values of "
     "another type, a NaN, uids under several high-32 segments).",
@@ -3015,6 +3047,19 @@ declare_metric(
     "engine's serial path directly — skipping the condvar handoffs "
     "that lose to serial at batch width ~1.05. Disable with "
     "DGRAPH_TPU_GROUP_COMMIT_BYPASS=0.",
+)
+declare_metric(
+    "counter", "commit_batches_total",
+    "Batches of commits written under one lock hold, each one `commit` "
+    "span: a group-commit batch (worker/groupcommit.py) or a serial "
+    "commit, a batch of one (the adaptive bypass, "
+    "DGRAPH_TPU_GROUP_COMMIT=0). Over num_commits: the realized "
+    "width, bypassed commits counted.",
+)
+declare_metric(
+    "counter", "mutate_nquads_total",
+    "N-Quads parsed by RDF mutations (span mutate.parse): with "
+    "commit_batches_total, the write path's work by count.",
 )
 declare_metric(
     "counter", "group_commit_total",

@@ -13,10 +13,12 @@ from __future__ import annotations
 
 import json
 import os
+import shutil
 import signal
 import socket
 import subprocess
 import sys
+import tempfile
 import threading
 import time
 from typing import Dict, List, Optional, Tuple
@@ -104,6 +106,10 @@ class ProcCluster:
         self.procs: Dict[int, subprocess.Popen] = {}
         self._cfgs: Dict[int, dict] = {}
         self.data_dir = data_dir
+        # the replicas' configs and logs: a directory of this cluster's
+        # own, so clusters of concurrent processes never read each
+        # other's alpha_<id>.json
+        self._cfg_dir = data_dir or tempfile.mkdtemp(prefix="dgraph_tpu_proc_")
         zero_impl = None
         if replicated_zero:
             from dgraph_tpu.zero.remote import RemoteZero
@@ -247,7 +253,7 @@ class ProcCluster:
     def _spawn(self, node_id: int):
         cfg = self._cfgs[node_id]
         module = cfg.get("_module", "dgraph_tpu.worker.alpha_process")
-        cfg_dir = self.data_dir or "/tmp/dgraph_tpu_proc"
+        cfg_dir = self._cfg_dir
         os.makedirs(cfg_dir, exist_ok=True)
         path = os.path.join(cfg_dir, f"alpha_{node_id}.json")
         with open(path, "w") as f:
@@ -323,6 +329,8 @@ class ProcCluster:
         applyshard.shutdown()
         for nid in list(self.procs):
             self.kill(nid)
+        if not self.data_dir:
+            shutil.rmtree(self._cfg_dir, ignore_errors=True)
         self.pool.close()
         if self.intents is not None:
             self.intents.close()

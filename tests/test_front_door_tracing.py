@@ -329,7 +329,9 @@ def test_a_front_door_reader_reads_the_records_or_none(
         profiled_records, name):
     _, recs, _, _ = profiled_records
     got = _reader(name).read({"span_records": list(recs.values())})
-    assert isinstance(got, float) and got >= 0
+    # a float: the client's gap is taken from the end of the root that
+    # wrote the previous reply, so its median can read below 0
+    assert isinstance(got, float)
     assert _reader(name).read(
         {"span_records": [_stripped(r) for r in recs.values()]}) is None
     assert _reader(name).read({"span_records": None}) is None
@@ -472,3 +474,110 @@ def test_client_replies_keep_the_newest_clients(monkeypatch):
     assert replies.swap(("c", 1), c1) is None  # "a" goes out
     assert replies.swap(("a", 5), object()) is None
     assert replies.swap(("c", 2), object()) is c1
+
+
+# -- a write's tree ------------------------------------------------------------
+
+WRITERS, WRITES = 4, 6
+WRITE_SPANS = ("http.request", "http.read", "mutate", "mutate.parse",
+               "mutate.apply", "commit.wait", "http.reply")
+WRITE_COUNTERS = ("mutate_nquads_total", "commit_batches_total",
+                  "num_commits")
+WRITE_READERS = ("write_cpu_ms_per_write", "commit_wait_ms_per_write",
+                 "commit_batch_mean")
+
+
+@pytest.fixture(scope="module")
+def write_records(served, tmp_path_factory):
+    """WRITERS clients committing WRITES writes of three N-Quads each at
+    once, under a profiler session: their `/mutate` records, the
+    counters that moved, and each record's spans."""
+    import jax
+
+    url, _ = served
+    before = {c: METRICS.value(c) for c in WRITE_COUNTERS}
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    t0 = time.time()
+    jax.profiler.start_trace(str(tmp_path_factory.mktemp("writes")),
+                             profiler_options=opts)
+    try:
+        def loop(i):
+            c = DgraphClient(url)
+            for j in range(WRITES):
+                c.txn().mutate(set_rdf="\n".join(
+                    f'_:{b} <name> "w{i}.{j}.{b}" .' for b in "abc"),
+                    commit_now=True)
+
+        threads = [threading.Thread(target=loop, args=(i,))
+                   for i in range(WRITERS)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+    finally:
+        jax.profiler.stop_trace()
+    moved = {c: METRICS.value(c) - before[c] for c in WRITE_COUNTERS}
+    deadline = time.monotonic() + 20
+    while True:
+        recs = [r for r in TRACER.request_records(1024, profiled=True)
+                if r["start"] >= t0 and r["root_attrs"].get("path") == "/mutate"]
+        if len(recs) == WRITERS * WRITES or time.monotonic() > deadline:
+            break
+        time.sleep(0.01)
+    spans = {r["trace_id"]: TRACER.trace_spans(int(r["trace_id"], 16))
+             for r in recs}
+    return recs, moved, spans
+
+
+def test_a_write_is_one_tree(write_records):
+    """http.request > {http.read, mutate > {mutate.parse, mutate.apply,
+    commit.wait}, http.reply}; a batch's `commit` sits under the
+    `commit.wait` of the thread that ran it."""
+    recs, _, spans = write_records
+    assert len(recs) == WRITERS * WRITES
+    for rec in recs:
+        assert {n: rec["counts"].get(n) for n in WRITE_SPANS} == {
+            n: 1 for n in WRITE_SPANS}
+        assert rec["counts"].get("commit", 0) <= 1
+        by_id = {sp["span_id"]: sp for sp in spans[rec["trace_id"]]}
+        parent = {sp["name"]: by_id[sp["parent_id"]]["name"]
+                  for sp in by_id.values() if sp["parent_id"] in by_id}
+        assert {n: parent[n] for n in WRITE_SPANS[1:]} == {
+            "http.read": "http.request", "mutate": "http.request",
+            "mutate.parse": "mutate", "mutate.apply": "mutate",
+            "commit.wait": "mutate", "http.reply": "http.request"}
+        if "commit" in rec["counts"]:
+            assert parent["commit"] == "commit.wait"
+            commit = next(sp for sp in by_id.values()
+                          if sp["name"] == "commit")
+            assert {"batch", "oracle_ms", "propose_ms", "apply_ms"} <= set(
+                commit["attrs"])
+
+
+def test_the_write_counters_are_its_spans(write_records):
+    """`mutate_nquads_total` is the N-Quads the `mutate.parse` spans
+    parsed, `commit_batches_total` the `commit` spans (one a batch, in
+    its leader's tree), `num_commits` the transactions their `batch`
+    attrs carried."""
+    recs, moved, _ = write_records
+    summed = {k: sum(r["attrs"].get(k, 0) for r in recs) for k in (
+        "mutate.parse.nquads", "mutate.apply.edges", "commit.batch")}
+    assert moved["mutate_nquads_total"] == summed["mutate.parse.nquads"] \
+        == summed["mutate.apply.edges"] == 3 * WRITERS * WRITES
+    assert moved["commit_batches_total"] == sum(
+        r["counts"].get("commit", 0) for r in recs) >= 1
+    assert moved["num_commits"] == summed["commit.batch"] == WRITERS * WRITES
+
+
+@pytest.mark.parametrize("name", WRITE_READERS)
+def test_a_write_reader_reads_the_records_or_none(write_records, name):
+    recs, moved, _ = write_records
+    got = _reader(name).read({"mutate_records": recs})
+    assert isinstance(got, float) and got >= 0
+    if name == "commit_batch_mean":
+        assert got == WRITERS * WRITES / moved["commit_batches_total"]
+    if name == "write_cpu_ms_per_write":  # the CPU clock, profiled trees
+        assert all(r["profiled"] for r in recs)
+    assert _reader(name).read({"mutate_records": None}) is None
