@@ -101,12 +101,21 @@ def _pow4(n: int) -> int:
     return p if p.bit_length() % 2 else p * 2
 
 
-def _cut_rows(op: str, flat, offs, member) -> List[np.ndarray]:
+def _flat_of(rows) -> Tuple[np.ndarray, np.ndarray]:
+    """A level's (flat u64 ids, offsets): as it lies when the door is
+    handed a `ragged.RaggedRows`, packed once from a list of rows."""
+    if isinstance(rows, ragged.RaggedRows):
+        return np.asarray(rows.flat, np.uint64), rows.offs
+    return ragged.pack_rows([np.asarray(r, np.uint64) for r in rows])
+
+
+def _cut_rows(op: str, flat, offs, member) -> ragged.RaggedRows:
     """The rows of a ragged (flat, offs) level after intersect or
-    difference with a set, given each id's membership in it: views of
-    ONE kept array, cut at the new offsets."""
+    difference with a set, given each id's membership in it: ONE kept
+    array and its new offsets, whose rows are views cut only when
+    someone asks for them."""
     keep = ~member if op == "difference" else member
-    return ragged.row_views(*ragged.apply_mask(flat, offs, keep))
+    return ragged.RaggedRows(*ragged.apply_mask(flat, offs, keep))
 
 
 def _np_op(op: str, a: np.ndarray, b: np.ndarray) -> np.ndarray:
@@ -526,7 +535,7 @@ class SetOpDispatcher:
         b: np.ndarray,
         row_tokens: Optional[Sequence[Optional[tuple]]] = None,
         b_token: Optional[tuple] = None,
-    ) -> List[np.ndarray]:
+    ) -> Sequence[np.ndarray]:
         """Apply `op` to each (row, b) with ONE shared b operand — the
         dominant query shape (uid_matrix rows vs a filter result, recurse
         frontier vs seen-set). b uploads once per call instead of being
@@ -548,11 +557,22 @@ class SetOpDispatcher:
         or drop by and cut into rows at the offsets. The program is
         given at most four times the real ids. Only `union#shared`,
         which needs each row's own output width, and the pair buckets
-        (`_run_bucket`) pad rows into a stack and vmap over it."""
-        rows = list(rows)
-        if not rows:
+        (`_run_bucket`) pad rows into a stack and vmap over it.
+
+        `rows` is a list of rows, packed once here, or a level as it
+        lies (`ragged.RaggedRows`), whose flat ids go through as they
+        are: no row is cut or packed on the way. Intersect and
+        difference hand back a `ragged.RaggedRows` of the kept ids
+        either way; the other paths a list of rows."""
+        form = "ragged" if isinstance(rows, ragged.RaggedRows) else "rows"
+        if form == "rows":
+            rows = list(rows)
+        if not len(rows):
             return []
-        total = sum(len(r) for r in rows) + len(b)
+        total = len(b) + (
+            rows.flat.size if form == "ragged"
+            else sum(len(r) for r in rows)
+        )
         if total < self._min_total():
             # the host kernels answer it: no span (these are the
             # small, frequent ones), one counter
@@ -562,9 +582,7 @@ class SetOpDispatcher:
                 # concatenated rows beats per-row native calls (ctypes
                 # marshaling dominates at small sizes)
                 b64 = np.asarray(b, np.uint64)
-                flat, offs = ragged.pack_rows(
-                    [np.asarray(r, np.uint64) for r in rows]
-                )
+                flat, offs = _flat_of(rows)
                 if len(b64) and len(flat):
                     idx = np.minimum(
                         np.searchsorted(b64, flat), len(b64) - 1
@@ -574,6 +592,7 @@ class SetOpDispatcher:
                     mask = np.zeros(len(flat), bool)
                 return _cut_rows(op, flat, offs, mask)
             return [_np_op(op, r, b) for r in rows]
+        METRICS.inc(f'setop_door_total{{form="{form}"}}')
         if (
             op in ("intersect", "difference")
             and len(b) >= _SHARD_MIN_B
@@ -589,9 +608,7 @@ class SetOpDispatcher:
             "setop.pad", cpu=True, fine=True, family=family
         ) as sp:
             b64 = np.asarray(b, np.uint64)
-            flat, offs = ragged.pack_rows(
-                [np.asarray(r, np.uint64) for r in rows]
-            )
+            flat, offs = _flat_of(rows)
             # one hi-32 test over the level's ids, not one split a row
             his = [x >> np.uint64(32) for x in (flat, b64) if x.size]
             hi = int(his[0][0]) if his else 0
@@ -775,10 +792,11 @@ class SetOpDispatcher:
         (flat, offs) shape without materializing per-row lists.
 
         The host path is fully vectorized — one searchsorted over the
-        whole flat buffer plus one cumsum to rebuild offsets — which is
+        whole flat buffer, `ragged.apply_mask` to rebuild offsets — which is
         the CPU-backend fast path for every traversal level. The device
-        path goes through `run_rows_vs_one` on zero-copy row views, and
-        gets views of one kept array back."""
+        path hands `run_rows_vs_one` the level as it lies and takes the
+        kept level back the same way; it packs only a plain list of
+        rows, which a wrapper around the door may return."""
         n = len(offs) - 1
         b64 = np.asarray(b, np.uint64)
         if n == 0:
@@ -802,11 +820,13 @@ class SetOpDispatcher:
             return ragged.apply_mask(flat, offs, mask)
         res = self.run_rows_vs_one(
             op,
-            ragged.row_views(flat, offs),
+            ragged.RaggedRows(flat, offs),
             b64,
             row_tokens=row_tokens,
             b_token=b_token,
         )
+        if isinstance(res, ragged.RaggedRows):
+            return res.flat, res.offs
         return ragged.pack_rows(res)
 
     def run_chain(self, op: str, parts: Sequence[np.ndarray]) -> np.ndarray:

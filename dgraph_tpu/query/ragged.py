@@ -103,10 +103,11 @@ def apply_mask(
     flat: np.ndarray, offs: np.ndarray, mask: np.ndarray
 ) -> Tuple[np.ndarray, np.ndarray]:
     """Keep flat[mask], recomputing offsets — the vectorized form of a
-    per-row filter (one cumsum instead of n row scans)."""
-    cum = np.zeros((flat.size + 1,), np.int64)
-    np.cumsum(mask, out=cum[1:])
-    return flat[mask], cum[offs]
+    per-row filter: a row's new start is the number of kept ids before
+    its old one, one searchsorted of the offsets into the kept ids'
+    positions (no running count over every id)."""
+    nz = np.flatnonzero(mask)
+    return flat[nz], np.searchsorted(nz, offs)
 
 
 def paginate(

@@ -2730,6 +2730,15 @@ declare_metric(
     "valcol.launch span, whose `use` attr is the part after the #.",
 )
 declare_metric(
+    "counter", "setop_door_total{form=\"*\"}",
+    "Calls of the shared-operand set-op door (query/dispatch.py "
+    "run_rows_vs_one) that went on to the device path, by the form "
+    "the level came in: form=\"ragged\" a level as it lies (flat ids "
+    "and offsets, no row cut or packed), form=\"rows\" a list of rows "
+    "packed once at the door. Counted where the call is made, like "
+    "device_dispatch_total.",
+)
+declare_metric(
     "counter", "device_download_bytes_total",
     "Bytes read back from the device by the set-op dispatcher and the "
     "jitted vector tiers (the `bytes` of setop.wait / vec.wait spans); "

@@ -156,23 +156,87 @@ def test_every_family_says_what_it_pads(on_device, variant):
     assert [(p["ids"], p["padded"]) for p in pads] == [(ids, padded)]
 
 
+def _never(what):
+    def never(*_a, **_kw):
+        raise AssertionError(what)
+    return never
+
+
 def test_flat_path_splits_and_joins_no_row(on_device, monkeypatch):
     """The hi-32 test is one pass over the level's ids: no
     `split_segments` / `join_segments` a row on the way in or out, in
-    the list form or the ragged form."""
-    def never(*_a, **_kw):
-        raise AssertionError("a per-row segment split on the flat path")
-
+    the list form or the ragged form; and the ragged form is neither
+    cut into row views nor packed again."""
+    never = _never("a per-row segment split on the flat path")
     monkeypatch.setattr(dispatch, "split_segments", never)
     monkeypatch.setattr(dispatch, "join_segments", never)
     rows, b, _ = _shape("high_segment")
     flat, offs = ragged.pack_rows(rows)
+    got = {op: on_device.run_rows_vs_one(op, rows, b) for op in REF}
+    monkeypatch.setattr(ragged, "row_views", _never("a level cut into rows"))
+    monkeypatch.setattr(ragged, "pack_rows", _never("a level packed again"))
     for op in REF:
-        got = on_device.run_rows_vs_one(op, rows, b)
         out, out_offs = on_device.run_rows_vs_one_ragged(op, flat, offs, b)
-        assert np.array_equal(out, np.concatenate(got))
-        assert np.array_equal(np.diff(out_offs), [len(g) for g in got])
-        assert np.array_equal(got[3], REF[op](rows[3], b))
+        assert np.array_equal(out, np.concatenate(got[op]))
+        assert np.array_equal(np.diff(out_offs), [len(g) for g in got[op]])
+        assert np.array_equal(got[op][3], REF[op](rows[3], b))
+
+
+DOOR = {form: f'setop_door_total{{form="{form}"}}'
+        for form in ("ragged", "rows")}
+
+
+@pytest.mark.parametrize("shape", [s for s in SHAPES if _shape(s)[2]])
+@pytest.mark.parametrize("op", ["intersect", "difference"])
+def test_ragged_form_goes_through_the_door_as_it_lies(
+        on_device, monkeypatch, op, shape):
+    """On the device path a level reaches the door as (flat, offs) and
+    comes back so: no `row_views` on the way in, no `pack_rows` on the
+    way out, the list form's answers and numpy's row for row, and one
+    `setop_door_total{form="ragged"}` for each call that reaches the
+    door (a list of rows counts under `rows`)."""
+    d = on_device
+    rows, b, _ = _shape(shape)
+    before = {f: METRICS.value(name) for f, name in DOOR.items()}
+    want = d.run_rows_vs_one(op, rows, b)
+    assert METRICS.value(DOOR["rows"]) - before["rows"] == 1
+    flat, offs = ragged.pack_rows(rows)
+    monkeypatch.setattr(ragged, "row_views", _never("a level cut into rows"))
+    monkeypatch.setattr(ragged, "pack_rows", _never("a level packed again"))
+    out, out_offs = d.run_rows_vs_one_ragged(op, flat, offs, b)
+    assert METRICS.value(DOOR["ragged"]) - before["ragged"] == int(
+        bool(flat.size and b.size))
+    assert METRICS.value(DOOR["rows"]) - before["rows"] == 1
+    assert out.dtype == np.uint64 and out_offs.dtype == np.int64
+    assert np.array_equal(np.diff(out_offs), [len(w) for w in want])
+    for i, (r, w) in enumerate(zip(rows, want)):
+        assert np.array_equal(w, REF[op](r, b))
+        assert np.array_equal(out[out_offs[i]: out_offs[i + 1]], w)
+
+
+@pytest.mark.parametrize("cut", [0, 2], ids=["as_is", "halved"])
+@pytest.mark.parametrize("op", ["intersect", "difference"])
+def test_a_door_wrapped_to_return_rows_still_yields_the_level(
+        on_device, op, cut):
+    """A wrapper around the door that hands back a plain list of rows,
+    as a planted fault does, is packed once by the ragged caller: its
+    rows, and whatever it did to them, are the level that comes out."""
+    d = on_device
+    rows, b, _ = _shape("hub")
+    orig = d.run_rows_vs_one
+
+    def wrapped(op, rows, b, *a, **kw):
+        return [np.asarray(r)[len(r) // cut if cut else 0:]
+                for r in orig(op, rows, b, *a, **kw)]
+
+    d.run_rows_vs_one = wrapped
+    flat, offs = ragged.pack_rows(rows)
+    out, out_offs = d.run_rows_vs_one_ragged(op, flat, offs, b)
+    assert out.dtype == np.uint64 and len(out_offs) == len(rows) + 1
+    for i, r in enumerate(rows):
+        w = REF[op](r, b)
+        w = w[len(w) // cut:] if cut else w
+        assert np.array_equal(out[out_offs[i]: out_offs[i + 1]], w)
 
 
 @pytest.mark.parametrize("n, want", [
