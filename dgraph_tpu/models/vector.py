@@ -49,10 +49,16 @@ Metrics match tok/hnsw/helper.go:98-114: euclidean, cosine, dotproduct.
 Supported distance ordering: smaller = closer (dot negated).
 
 Mutability: rows are append-only with tombstones (no swap-compaction, so
-quantized sidecars and IVF cell ids stay valid across removes); the
-jitted device matrix compacts lazily on rebuild (the MVCC analog of pack
-re-upload on rollup), while the quantized engine folds mutations in
-incrementally.
+quantized sidecars and IVF cell ids stay valid across removes), and both
+engines fold mutations in incrementally. The jitted device snapshot is
+built with spare room (spare corpus rows, spare IVF slabs that belong to
+no cell until an appended row needs one); the next search applies the
+pending writes in place on the device (`_apply_pending`: appended rows
+to their top-2 cells, tombstones as the probe's -1 padding) through
+update programs that donate the arrays they change. It compacts on a
+rebuild (the MVCC analog of pack re-upload on rollup), which runs only
+when the spare room runs out, dead rows pile up, or the snapshot is too
+small for the update programs to pay (`vector_ivf_rebuilds_total{why}`).
 """
 
 from __future__ import annotations
@@ -87,6 +93,31 @@ _PRECISION = "highest"
 # already fast and keeps small-corpus layouts float-exact (tests force
 # the native path by zeroing this)
 _ASSIGN_NATIVE_MIN_MACS = 2e10
+
+# A device snapshot of at least _LIVE_MIN_ROWS rows takes writes in place
+# (`_apply_pending`); a smaller one is rebuilt after a write instead: its
+# rebuild is milliseconds of host work, where the update programs would
+# first have to compile. Its room for writes is fixed when it is built:
+# one spare IVF slab for every _SPARE_SHARE slabs the cells fill, and
+# at least one for every four cells (appended rows spread over the
+# cells, and a cell whose last slab is full takes a whole spare), and
+# the corpus rows its pow2 padding leaves free, or _ROOM_MIN_ROWS or
+# 1/32 more rows, whichever is more, rounded up to _PAD_ROWS where that
+# padding leaves fewer (a corpus just under a power of two would
+# otherwise double its HBM for room it does not need). A snapshot whose
+# tombstoned rows pass 1/_DEAD_SHARE of its live ones is rebuilt: the
+# probe gathers dead slab rows as padding.
+_LIVE_MIN_ROWS = 4096
+_SPARE_SHARE = 16
+_DEAD_SHARE = 4
+_ROOM_MIN_ROWS = 1024
+# rows one update program takes (a burst of more is taken in several);
+# its two programs are compiled at a snapshot's first write, so no later
+# write compiles
+_APPLY_ROWS = 64
+# the device arrays an update program donates, in its argument order
+_SLAB_KEYS = ("flat_vecs", "flat_sq", "flat_rows", "slab_cell")
+_CORPUS_KEYS = ("vecs", "sqnorm", "valid")
 
 
 def _nthreads() -> int:
@@ -167,15 +198,21 @@ def _span(name: str, **attrs):
     )
 
 
-def _run_tier(tier: str, nq: int, fetch, args, up_bytes: int, want=(0, 1)):
+def _run_tier(tier: str, nq: int, fetch, args_of, up_bytes: int, lock,
+              want=(0, 1)):
     """What every jitted tier shares between its host plan (`vec.plan`:
     probe plan, query upload; at the call site) and its host
     post-processing (`vec.post`, there too): `vec.launch` (jit fetch
     and the call that enqueues the program) and `vec.wait` (queueing
     behind other requests' programs, execution, read-back of the
-    outputs in `want`; the others come back as None)."""
-    with _span("vec.launch", tier=tier, nq=nq):
-        out = fetch()(*args)
+    outputs in `want`; the others come back as None). `args_of()`
+    reads the snapshot's arrays under the index's launch `lock`: an
+    update program donates the arrays it changes and swaps them under
+    that lock, and a program launched on a donated buffer fails. The
+    span opens once the lock is held, so that its start is the
+    launch's, in the order the programs reach the device."""
+    with lock, _span("vec.launch", tier=tier, nq=nq):
+        out = fetch()(*args_of())
     # read for its wall time; its CPU time (the read-back's copy) is
     # taken too, so that it comes off the caller's self CPU time
     with _span("vec.wait", tier=tier, nq=nq) as sp:
@@ -269,7 +306,8 @@ def _ivf_probe(metric: str, m_slabs: int, npool: int):
             - 2.0 * jnp.matmul(cents, q, precision=_PRECISION)
             + (q * q).sum()
         )
-        slab_score = cd[slab_cell]
+        # a spare slab (cell -1) is never probed until a cell takes it
+        slab_score = jnp.where(slab_cell >= 0, cd[slab_cell], jnp.inf)
         _, sidx = jax.lax.top_k(-slab_score, m_slabs)
         sub = flat_vecs[sidx]            # (M, S, d) gather
         rows = flat_rows[sidx].reshape(-1)
@@ -311,6 +349,67 @@ def _jit_ivf_batch(metric: str, m_slabs: int, npool: int):
         )(cents, csq, slab_cell, flat_vecs, flat_sq, flat_rows, Q)
 
     return jax.jit(run)
+
+
+@functools.lru_cache(maxsize=1)
+def _jit_slab_assign():
+    """The top-2 cells of each pending row against the trained
+    centroids: `_assign_top2_exact`'s arithmetic, on the device."""
+    import jax
+    import jax.numpy as jnp
+
+    def slab_assign(cents, csq, X):
+        with jax.named_scope("vec.ivf_assign"):
+            d2 = csq[None, :] - 2.0 * jnp.matmul(
+                X, cents.T, precision=_PRECISION
+            )
+            return jax.lax.top_k(-d2, 2)[1].astype(jnp.int32)
+
+    return jax.jit(slab_assign)
+
+
+@functools.lru_cache(maxsize=2)
+def _jit_slab_update(slabs: bool):
+    """The in-place update of a device snapshot, donating what it
+    changes (`_SLAB_KEYS` where `slabs`, then `_CORPUS_KEYS`): appended
+    rows `X` (squared norms `sq`) land in corpus rows `drow` and, where
+    the snapshot has an IVF, in flat slab positions `pos` (each taking
+    row `src` of X); the rows `ddead` and the slab positions `dpos` are
+    tombstoned (invalid, row id -1: what the probe skips as padding);
+    slabs `gslab` are given to cells `gcell`. Padding indices lie past
+    their array's end and are dropped."""
+    import jax
+
+    def corpus(vecs, sqnorm, valid, X, sq, drow, ddead):
+        return (
+            vecs.at[drow].set(X, mode="drop"),
+            sqnorm.at[drow].set(sq, mode="drop"),
+            valid.at[ddead].set(False, mode="drop")
+            .at[drow].set(True, mode="drop"),
+        )
+
+    def slab_update(*a):
+        with jax.named_scope("vec.ivf_update"):
+            if not slabs:
+                return corpus(*a)
+            (fv, fs, fr, sc, vecs, sqnorm, valid,
+             X, sq, drow, ddead, src, pos, dpos, gslab, gcell) = a
+            T, S, d = fv.shape
+            n = T * S
+            fv = fv.reshape(n, d).at[pos].set(X[src], mode="drop")
+            fs = fs.reshape(n).at[pos].set(sq[src], mode="drop")
+            fr = (
+                fr.reshape(n).at[dpos].set(-1, mode="drop")
+                .at[pos].set(drow[src], mode="drop")
+            )
+            sc = sc.at[gslab].set(gcell, mode="drop")
+            return (
+                fv.reshape(T, S, d), fs.reshape(T, S), fr.reshape(T, S),
+                sc, *corpus(vecs, sqnorm, valid, X, sq, drow, ddead),
+            )
+
+    donated = len(_SLAB_KEYS) * slabs + len(_CORPUS_KEYS)
+    return jax.jit(slab_update, donate_argnums=tuple(range(donated)))
 
 
 # ---------------------------------------------------------------------------
@@ -542,9 +641,19 @@ class VectorIndex:
 
         self._dirty = True
         # jit-path device snapshot: corpus arrays, compacted uid map,
-        # slab IVF, mesh — ONE dict, replaced whole (see _sync_device)
+        # slab IVF, mesh — ONE dict, replaced whole by a rebuild, its
+        # arrays swapped in place by an update (see _sync_device)
         self._device: Optional[dict] = None
-        self._sync_lock = threading.Lock()  # one rebuild at a time
+        self._sync_lock = threading.Lock()  # one rebuild or update at a time
+        # held while a search reads the snapshot's arrays and launches
+        # on them, and while an update donates them and swaps in its
+        # outputs: no program is ever launched on a donated buffer
+        self._launch_lock = threading.Lock()
+        # the writes the device snapshot has not taken yet, in order:
+        # host rows appended, host rows tombstoned, index writes
+        self._log_add: list = []
+        self._log_del: list = []
+        self._log_writes = 0
 
         # quantized engine state (row-aligned sidecars + incremental IVF)
         self._q: Optional[dict] = None
@@ -601,6 +710,8 @@ class VectorIndex:
             self._valid[row] = 1
             self._rows[uid] = row
             self._live += 1
+            self._log_add.append(row)
+            self._log_writes += 1
             self._dirty = True
 
     def remove(self, uid: int) -> None:
@@ -609,6 +720,7 @@ class VectorIndex:
             if row is None:
                 return
             self._tombstone(row)
+            self._log_writes += 1
             self._dirty = True
 
     def _tombstone(self, row: int) -> None:
@@ -616,6 +728,7 @@ class VectorIndex:
         self._valid[row] = 0
         self._uid_of[row] = 0
         self._live -= 1
+        self._log_del.append(row)
         if self._qivf is not None and row < self._qivf["assigned"]:
             self._qivf["dead"] += 1
 
@@ -638,6 +751,7 @@ class VectorIndex:
             self._q = None
             self._qivf = None
             self._device = None
+            self._log_add, self._log_del, self._log_writes = [], [], 0
 
     def __len__(self) -> int:
         return self._live
@@ -707,10 +821,12 @@ class VectorIndex:
 
     def _sync_device(self) -> dict:
         """The device snapshot a search runs on: corpus arrays, uid map,
-        slab IVF and (when sharded) the mesh — one dict, replaced whole,
-        so a search that holds it never sees half of a rebuild. Rebuilt
-        when rows changed; concurrent searches wait for ONE rebuild
-        instead of each uploading a copy."""
+        slab IVF and (when sharded) the mesh. Where rows changed, the
+        pending writes are applied to it in place (`_apply_pending`) or,
+        where it cannot take them, it is rebuilt whole; concurrent
+        searches wait for ONE of either. `_dirty` is cleared only once
+        the writes a search must see are on the device, so a search
+        sent after a write was acknowledged finds it."""
         if not self._dirty:
             # bound only on this path: a local that still named the old
             # snapshot would keep its HBM alive through the rebuild
@@ -719,7 +835,15 @@ class VectorIndex:
                 return dev
         with self._sync_lock:
             if self._device is None or self._dirty:
-                self._rebuild_device()
+                # no local names the snapshot: a rebuild frees it first
+                why = ("first" if self._device is None
+                       else self._apply_pending(self._device))
+                if why is not None:
+                    self._rebuild_device()
+                    _metrics().inc_many({
+                        "vector_ivf_rebuilds_total": 1,
+                        f'vector_ivf_rebuilds_total{{why="{why}"}}': 1,
+                    })
             return self._device
 
     def _rebuild_device(self) -> None:
@@ -727,6 +851,7 @@ class VectorIndex:
         import jax
         import jax.numpy as jnp
 
+        shard = bool(config.get("SHARD_VECTORS")) and len(jax.devices()) > 1
         with self._lock:
             # gather atomically: the quant path's compaction renumbers
             # rows and swaps these buffers under the same lock, so an
@@ -734,25 +859,33 @@ class VectorIndex:
             # new (shorter) arrays
             live_idx = np.flatnonzero(self._valid[: self._n])
             nlive = int(live_idx.size)
+            live = nlive >= _LIVE_MIN_ROWS and not shard
             cap = _pow2_rows(nlive)
+            room = max(nlive // 32, _ROOM_MIN_ROWS)
+            if live and cap - nlive < room:
+                cap = -(-(nlive + room) // _PAD_ROWS) * _PAD_ROWS
             d = self._vecs.shape[1]
             mat = np.zeros((cap, d), np.float32)
             mat[:nlive] = self._vecs[live_idx]
             uids = np.zeros((cap,), np.uint64)
             uids[:nlive] = self._uid_of[live_idx]
+            dev_of_host = np.full((self._n,), -1, np.int64)
+            dev_of_host[live_idx] = np.arange(nlive)
+            # the old snapshot is released before the new one uploads: at
+            # 1M x 768 the two do not fit in 16 GB of HBM together (the
+            # rebuild after one insert died RESOURCE_EXHAUSTED on a v5e).
+            # It stays None if the rebuild fails, so the next search
+            # retries; dropped before `_dirty` clears, so that no search
+            # takes the old one for current
+            self._device = None
             # cleared with the gather: a row that lands after it dirties
             # the index again instead of being lost
             self._dirty = False
-        # the old snapshot is released before the new one uploads: at
-        # 1M x 768 the two do not fit in 16 GB of HBM together (the
-        # rebuild after one insert died RESOURCE_EXHAUSTED on a v5e).
-        # It stays None if the rebuild fails, so the next search retries
-        self._device = None
+            self._log_add, self._log_del, self._log_writes = [], [], 0
         valid = np.zeros((cap,), bool)
         valid[:nlive] = True
         mesh = None
-        shard = bool(config.get("SHARD_VECTORS"))
-        if shard and len(jax.devices()) > 1:
+        if shard:
             # row-shard the corpus over the device mesh: per-shard top-k,
             # all_gather, global reduce (parallel/mesh.py sharded_topk —
             # the TP-over-rows data plane for 1M×768-class corpora)
@@ -784,6 +917,14 @@ class VectorIndex:
         ivf = None
         if nlive >= self.ivf_threshold:
             ivf = self._train_ivf(mat[:nlive])
+            if live:
+                # positions of the rows appended later, unknown yet
+                ivf["pos"] = np.vstack(
+                    [ivf["pos"], np.full((cap - nlive, 2), -1, np.int64)]
+                )
+                _metrics().set_gauge(
+                    "vector_ivf_spare_slabs", len(ivf["spare"])
+                )
         self._device = {
             "vecs": vecs,
             "uids": uids,  # host: gathered row indices map back to uids
@@ -791,7 +932,245 @@ class VectorIndex:
             "sqnorm": sqnorm,
             "ivf": ivf,
             "mesh": mesh,
+            # what an update needs (`_apply_pending`): rows in use of
+            # `cap`, each host row's device row (-1: none), dead rows
+            "live": live,
+            "rows": nlive,
+            "cap": cap,
+            "dev_of_host": dev_of_host,
+            "dead": 0,
+            "programs": None,
         }
+
+    def _apply_pending(self, dev: dict) -> Optional[str]:
+        """Take the pending writes into `dev` in place, in as few update
+        programs as the buckets allow (`_jit_slab_update`): each appended
+        row into a fresh corpus row and, where there is an IVF, into a
+        free row of each of its top-2 cells' last slab (`_slab_assign`
+        on the device finds the cells; `_place` the rows), or of a spare
+        slab given to that cell; each tombstoned row out of the corpus
+        and out of its slab positions. Centroids are not retrained (an
+        IVF `add`). Returns None, or why a rebuild has to take them:
+        "small" (too small to take writes, or sharded), "spare" (its
+        room is spent), "dead" (tombstones piled up; "first" is there
+        being no snapshot). Under self._sync_lock."""
+        with self._lock:
+            if not dev["live"]:
+                return "small"
+            adds = np.asarray(self._log_add, np.int64)
+            dels = np.asarray(self._log_del, np.int64)
+            writes = self._log_writes
+            self._log_add, self._log_del, self._log_writes = [], [], 0
+            # a row appended and tombstoned since the last update is in
+            # neither: host rows are never reused
+            adds = adds[self._valid[adds] != 0]
+            X = self._vecs[adds]
+            U = self._uid_of[adds]
+            n_host = self._n
+        try:
+            why = self._take(dev, adds, dels, writes, X, U, n_host)
+        except BaseException:
+            # the drained writes, and perhaps donated arrays, are lost to
+            # this snapshot: the next search rebuilds from the host rows
+            self._device = None
+            raise
+        if why is None:
+            with self._lock:
+                self._dirty = bool(self._log_add or self._log_del)
+        return why
+
+    def _take(self, dev, adds, dels, writes, X, U, n_host) -> Optional[str]:
+        """`_apply_pending`'s work on the drained writes: host rows
+        `adds` (vectors X, uids U) appended, `dels` tombstoned."""
+        host = dev["dev_of_host"]
+        if host.size < n_host:
+            host = dev["dev_of_host"] = np.concatenate(
+                [host, np.full((n_host - host.size,), -1, np.int64)]
+            )
+        drows = host[dels]
+        drows = drows[drows >= 0]
+        n0, k = dev["rows"], int(adds.size)
+        if n0 + k > dev["cap"]:
+            return "spare"
+        live_after = n0 - dev["dead"] - drows.size + k
+        if (dev["dead"] + drows.size) * _DEAD_SHARE > live_after:
+            return "dead"
+        ivf = dev["ivf"]
+        with _span("ivf.apply", rows=k, tombstones=int(drows.size),
+                   spare_used=0, writes=writes) as sp:
+            progs = self._apply_programs(dev)
+            placed = None
+            if ivf is not None and k:
+                placed = self._place(ivf, self._assign_on_device(
+                    ivf, X, progs))
+                if placed is None:
+                    return "spare"
+                sp.attrs["spare_used"] = placed["spare_used"]
+            new = np.arange(n0, n0 + k, dtype=np.int64)
+            # visible to no program before the update below runs
+            dev["uids"][n0 : n0 + k] = U
+            self._launch_updates(dev, progs, X, (X * X).sum(axis=1), new,
+                                 drows, placed)
+            host[adds] = new
+            host[dels] = -1
+            dev["rows"] = n0 + k
+            dev["dead"] += int(drows.size)
+            if ivf is not None:
+                if k:
+                    ivf["pos"][new] = placed["pos"]
+                ivf["pos"][drows] = -1
+                _metrics().inc_many({
+                    "vector_ivf_appended_rows_total": 2 * k,
+                    "vector_ivf_tombstoned_rows_total": 2 * int(drows.size),
+                })
+                _metrics().set_gauge(
+                    "vector_ivf_spare_slabs", len(ivf["spare"])
+                )
+        return None
+
+    def _apply_programs(self, dev: dict) -> tuple:
+        """(assign, update) for `dev`'s shapes at _APPLY_ROWS rows,
+        compiled ahead of time at its first write."""
+        progs = dev["programs"]
+        if progs is not None:
+            return progs
+        import jax
+
+        def spec(shape, dtype):
+            return jax.ShapeDtypeStruct(tuple(shape), np.dtype(dtype))
+
+        ivf = dev["ivf"]
+        donated = [dev[key] for key in _CORPUS_KEYS]
+        if ivf is not None:
+            donated = [ivf["dev"][key] for key in _SLAB_KEYS] + donated
+        d, b = dev["vecs"].shape[1], _APPLY_ROWS
+        ins = [spec((b, d), np.float32), spec((b,), np.float32),
+               spec((b,), np.int32), spec((b,), np.int32)]
+        if ivf is not None:
+            ins += [spec((2 * b,), np.int32)] * 5
+        update = _jit_slab_update(ivf is not None).lower(
+            *(spec(a.shape, a.dtype) for a in donated), *ins
+        ).compile()
+        assign = None
+        if ivf is not None:
+            cents = ivf["dev"]["cents"]
+            assign = _jit_slab_assign().lower(
+                spec(cents.shape, cents.dtype),
+                spec(ivf["dev"]["csq"].shape, np.float32),
+                spec((b, d), np.float32),
+            ).compile()
+        progs = dev["programs"] = (assign, update)
+        return progs
+
+    def _assign_on_device(self, ivf: dict, X: np.ndarray,
+                          progs: tuple) -> np.ndarray:
+        """(k, 2) top-2 cells of the rows X, one program each
+        _APPLY_ROWS of them (host arrays go to a compiled program as they
+        are: it uploads them in one call, where `jnp.asarray` each costs
+        a dispatch)."""
+        B = _APPLY_ROWS
+        out = []
+        for off in range(0, len(X), B):
+            part = X[off : off + B]
+            pad = _padded(part, B, 0.0, np.float32)
+            with _span("ivf.apply.launch", family="vec.ivf_assign"):
+                got = progs[0](ivf["dev"]["cents"], ivf["dev"]["csq"], pad)
+            with _span("ivf.apply.wait", family="vec.ivf_assign"):
+                got = np.asarray(got)
+            out.append(got[: len(part)])
+            _metrics().inc_many({
+                "vector_ivf_apply_programs_total": 1,
+                "device_dispatch_total": 1,
+                'device_dispatch_total{family="vec.ivf_assign"}': 1,
+                "device_upload_bytes_total": pad.nbytes,
+                "device_download_bytes_total": got.nbytes,
+            })
+        return np.concatenate(out)
+
+    @staticmethod
+    def _place(ivf: dict, cells: np.ndarray) -> Optional[dict]:
+        """Where each appended row goes: a free row of each of its cells'
+        last slab, or of the next spare slab, given to that cell. Commits
+        the IVF's bookkeeping (`fill`, `last`, `spare`) and returns the
+        plan; None, with nothing committed, where the spares run out."""
+        S = _SLAB
+        fill, last, spare = ivf["fill"], ivf["last"], ivf["spare"]
+        fills: dict = {}
+        lasts: dict = {}
+        given = []  # (row index, slab, cell)
+        pos = np.empty(cells.shape, np.int64)
+        for i, pair in enumerate(cells.tolist()):
+            for j, c in enumerate(pair):
+                s = lasts.get(c, int(last[c]))
+                f = fills.get(s, int(fill[s]))
+                if f == S:
+                    if len(given) == len(spare):
+                        return None
+                    s, f = spare[len(given)], 0
+                    lasts[c] = s
+                    given.append((i, s, c))
+                pos[i, j] = s * S + f
+                fills[s] = f + 1
+        for s, f in fills.items():
+            fill[s] = f
+        for c, s in lasts.items():
+            last[c] = s
+        del spare[: len(given)]
+        return {"pos": pos, "given": np.asarray(given, np.int64).reshape(-1, 3),
+                "spare_used": len(given)}
+
+    def _launch_updates(self, dev, progs, X, sq, new, drows, placed):
+        """Launch the update programs, up to _APPLY_ROWS appended and
+        as many tombstoned rows each, donating the snapshot's arrays and
+        swapping in what each returns under the launch lock."""
+        ivf = dev["ivf"]
+        B = _APPLY_ROWS
+        for off in range(0, max(len(new), len(drows)), B):
+            a = slice(off, off + B)
+            cap = dev["cap"]
+            ins = [
+                _padded(X[a], B, 0.0, np.float32),
+                _padded(sq[a], B, 0.0, np.float32),
+                _padded(new[a], B, cap, np.int32),
+                _padded(drows[a], B, cap, np.int32),
+            ]
+            if ivf is not None:
+                flat = ivf["dev"]["flat_rows"].size
+                pos = placed["pos"][a] if placed is not None else np.zeros(
+                    (0, 2), np.int64)
+                dpos = ivf["pos"][drows[a]].reshape(-1)
+                dpos = dpos[dpos >= 0]
+                given = (placed["given"] if placed is not None
+                         else np.zeros((0, 3), np.int64))
+                given = given[(given[:, 0] >= off) & (given[:, 0] < off + B)]
+                ins += [
+                    _padded(np.repeat(np.arange(len(pos)), 2), 2 * B, 0,
+                            np.int32),
+                    _padded(pos.reshape(-1), 2 * B, flat, np.int32),
+                    _padded(dpos, 2 * B, flat, np.int32),
+                    _padded(given[:, 1], 2 * B, len(ivf["fill"]), np.int32),
+                    _padded(given[:, 2], 2 * B, 0, np.int32),
+                ]
+            with self._launch_lock, _span("ivf.apply.launch",
+                                          family="vec.ivf_update"):
+                held = [dev[key] for key in _CORPUS_KEYS]
+                if ivf is not None:
+                    held = [ivf["dev"][key] for key in _SLAB_KEYS] + held
+                out = progs[1](*held, *ins)
+                del held
+                if ivf is not None:
+                    for key, arr in zip(_SLAB_KEYS, out):
+                        ivf["dev"][key] = arr
+                    out = out[len(_SLAB_KEYS):]
+                for key, arr in zip(_CORPUS_KEYS, out):
+                    dev[key] = arr
+            _metrics().inc_many({
+                "vector_ivf_apply_programs_total": 1,
+                "device_dispatch_total": 1,
+                'device_dispatch_total{family="vec.ivf_update"}': 1,
+                "device_upload_bytes_total": sum(
+                    int(x.nbytes) for x in ins),
+            })
 
     # -- search ----------------------------------------------------------------
 
@@ -842,8 +1221,8 @@ class VectorIndex:
                     lambda: functools.partial(
                         pmesh.sharded_topk, dev["mesh"]
                     ),
-                    (dev["vecs"], dev["valid"], qd, npool),
-                    q.nbytes,
+                    lambda: (dev["vecs"], dev["valid"], qd, npool),
+                    q.nbytes, self._launch_lock,
                 )
                 cand_uids = dev["uids"][idx]
             elif self._jit_ivf_wins(1, dev["ivf"]):
@@ -859,8 +1238,8 @@ class VectorIndex:
                 cand_dists, idx = _run_tier(
                     "brute", 1,
                     lambda: _jit_brute(self.metric, int(npool)),
-                    (dev["vecs"], dev["sqnorm"], dev["valid"], qd),
-                    q.nbytes,
+                    lambda: (dev["vecs"], dev["sqnorm"], dev["valid"], qd),
+                    q.nbytes, self._launch_lock,
                 )
                 cand_uids = dev["uids"][idx]
 
@@ -933,8 +1312,8 @@ class VectorIndex:
         _, idx = _run_tier(
             "brute", m,
             lambda: _jit_brute_batch(self.metric, int(kk)),
-            (dev["vecs"], dev["sqnorm"], dev["valid"], Qd),
-            Qp.nbytes, want=(1,),
+            lambda: (dev["vecs"], dev["sqnorm"], dev["valid"], Qd),
+            Qp.nbytes, self._launch_lock, want=(1,),
         )
         with _span("vec.post", tier="brute", nq=m):
             return dev["uids"][idx[:m]]
@@ -971,6 +1350,11 @@ class VectorIndex:
         are append-only and replaced — never shrunk — so a snapshot
         stays valid across concurrent mutations)."""
         with self._lock:
+            # the quantized engine serves: the device snapshot goes with
+            # the log of the writes it has not taken (the compaction
+            # below renumbers host rows); a later jitted search rebuilds
+            self._device = None
+            self._log_add, self._log_del, self._log_writes = [], [], 0
             self._compact_locked()
             self._quant_sync_locked()
             self._qivf_sync_locked()
@@ -1634,12 +2018,19 @@ class VectorIndex:
 
             # slab layout: pad each cell to a multiple of _SLAB so every slab
             # belongs to exactly one cell; top-M slab probing is then a
-            # static-shape device op (_jit_ivf)
+            # static-shape device op (_jit_ivf). A snapshot that takes
+            # writes (_LIVE_MIN_ROWS) gets spare slabs after the cells',
+            # of cell -1 until an appended row needs one
             S = _SLAB
             slabs_per_cell = np.maximum(1, -(-lens // S))
             n_slabs = int(slabs_per_cell.sum())
-            flat_rows = np.full((n_slabs * S,), -1, np.int64)
-            slab_cell = np.zeros((n_slabs,), np.int32)
+            n_spare = (
+                max(n_slabs // _SPARE_SHARE, nlist // 4)
+                if n >= _LIVE_MIN_ROWS else 0
+            )
+            n_total = n_slabs + n_spare
+            flat_rows = np.full((n_total * S,), -1, np.int64)
+            slab_cell = np.full((n_total,), -1, np.int32)
             off = 0
             for ci in range(nlist):
                 rws = flat_rows_cm[starts[ci] : ends[ci]]
@@ -1647,10 +2038,23 @@ class VectorIndex:
                 flat_rows[off * S : off * S + len(rws)] = rws
                 slab_cell[off : off + nsl] = ci
                 off += nsl
-            fr2 = flat_rows.reshape(n_slabs, S)
-            fv = np.zeros((n_slabs * S, d), np.float32)
+            fr2 = flat_rows.reshape(n_total, S)
+            fv = np.zeros((n_total * S, d), np.float32)
             sel = flat_rows >= 0
             fv[sel] = mat[flat_rows[sel]]
+            # an update's bookkeeping: rows laid in each slab, each
+            # cell's last slab, the spare slabs left, each row's two
+            # flat positions
+            last = np.cumsum(slabs_per_cell) - 1
+            fill = np.zeros((n_total,), np.int32)
+            fill[:n_slabs] = S
+            fill[last] = lens - (slabs_per_cell - 1) * S
+            at = np.flatnonzero(sel)
+            by_row = np.argsort(flat_rows[at], kind="stable")
+            rows_sorted = flat_rows[at][by_row]
+            pos = np.full((n, 2), -1, np.int64)
+            pos[rows_sorted, np.arange(at.size) - np.searchsorted(
+                rows_sorted, rows_sorted)] = at[by_row]
 
             if self.nprobe is None:
                 # embedding corpora cluster (the index contract); a handful of
@@ -1664,18 +2068,22 @@ class VectorIndex:
             m_slabs = int(min(n_slabs, max(8, round(self.nprobe * avg_slabs))))
             fsq = (fv * fv).sum(axis=1).astype(np.float32)
         up_bytes = int(fv.nbytes + fsq.nbytes + c_np.nbytes)
-        with _span("ivf.upload", rows=n_slabs * S, bytes=up_bytes):
+        with _span("ivf.upload", rows=n_total * S, bytes=up_bytes):
             ivf = {
                 "centroids": c_np,
                 "cell_lens": lens.astype(np.int32),
                 "m_slabs": m_slabs,
-                "n_slabs": n_slabs,
+                "n_slabs": n_slabs,  # the cells' at build; spares follow
+                "fill": fill,
+                "last": last,
+                "spare": list(range(n_slabs, n_total)),
+                "pos": pos,
                 "dev": {
                     "cents": jnp.asarray(c_np),
                     "csq": jnp.asarray((c_np * c_np).sum(axis=1)),
                     "slab_cell": jnp.asarray(slab_cell),
-                    "flat_vecs": jnp.asarray(fv.reshape(n_slabs, S, d)),
-                    "flat_sq": jnp.asarray(fsq.reshape(n_slabs, S)),
+                    "flat_vecs": jnp.asarray(fv.reshape(n_total, S, d)),
+                    "flat_sq": jnp.asarray(fsq.reshape(n_total, S)),
                     "flat_rows": jnp.asarray(fr2.astype(np.int32)),
                 },
             }
@@ -1702,16 +2110,8 @@ class VectorIndex:
         dd, rows = _run_tier(
             "ivf", 1,
             lambda: _jit_ivf(self.metric, int(m), npool),
-            (
-                dev["cents"],
-                dev["csq"],
-                dev["slab_cell"],
-                dev["flat_vecs"],
-                dev["flat_sq"],
-                dev["flat_rows"],
-                qd,
-            ),
-            q.nbytes,
+            lambda: _probe_args(dev, qd),
+            q.nbytes, self._launch_lock,
         )
         with _span("vec.post", tier="ivf", nq=1):
             ok = rows >= 0
@@ -1756,16 +2156,8 @@ class VectorIndex:
             _, rows = _run_tier(
                 "ivf", chunk,
                 lambda: _jit_ivf_batch(self.metric, int(m), npool),
-                (
-                    dev["cents"],
-                    dev["csq"],
-                    dev["slab_cell"],
-                    dev["flat_vecs"],
-                    dev["flat_sq"],
-                    dev["flat_rows"],
-                    qd,
-                ),
-                qc.nbytes, want=(1,),
+                lambda: _probe_args(dev, qd),
+                qc.nbytes, self._launch_lock, want=(1,),
             )
             with _span("vec.post", tier="ivf", nq=chunk):
                 for i in range(min(chunk, len(Q) - off)):
@@ -1804,6 +2196,20 @@ def _distances_batch(V, sqnorm, Q, metric):
         return 1.0 - dot / jnp.maximum(vn[None, :] * qn[:, None], 1e-12)
     qsq = (Q * Q).sum(axis=1)
     return sqnorm[None, :] - 2.0 * dot + qsq[:, None]
+
+
+def _probe_args(dev: dict, q) -> tuple:
+    """An IVF probe's arguments: the slab IVF's device arrays (`dev`:
+    its "dev" dict, as they stand) and the query or query batch."""
+    return (dev["cents"], dev["csq"], dev["slab_cell"], dev["flat_vecs"],
+            dev["flat_sq"], dev["flat_rows"], q)
+
+
+def _padded(a, n: int, fill, dtype) -> np.ndarray:
+    """`a` cast to `dtype` and padded with `fill` to n rows."""
+    out = np.full((n,) + np.shape(a)[1:], fill, dtype)
+    out[: len(a)] = a
+    return out
 
 
 def _in_sorted(arr: np.ndarray, v) -> bool:

@@ -2727,7 +2727,10 @@ declare_metric(
     "vec.brute, vec.sharded — the `family` attr of the setop.launch / "
     "vec.launch spans — and column#filter, column#narrow, "
     "column#scores: a value column's programs (ops/valcol.py), one per "
-    "valcol.launch span, whose `use` attr is the part after the #.",
+    "valcol.launch span, whose `use` attr is the part after the #; "
+    "vec.ivf_assign, vec.ivf_update: a vector index's update programs "
+    "(models/vector.py), the `family` attr of the ivf.apply.launch "
+    "spans.",
 )
 declare_metric(
     "counter", "setop_door_total{form=\"*\"}",
@@ -3370,9 +3373,54 @@ declare_metric(
 declare_metric(
     "gauge", "vector_index_build_seconds",
     "Wall seconds of the last vector index build on this process "
-    "(centroid train + assignment + layout) — incremental mutations "
-    "never restamp it, so movement here means a real rebuild "
-    "(models/vector.py).",
+    "(centroid train + assignment + layout), on either engine — "
+    "incremental mutations never restamp it (the quantized engine's "
+    "IVF takes them in its cells, the device snapshot in place), so "
+    "movement here means a real rebuild (models/vector.py).",
+)
+declare_metric(
+    "counter", "vector_ivf_appended_rows_total",
+    "IVF slab rows written on the device by the update programs that "
+    "apply committed vector writes in place (models/vector.py "
+    "_apply_pending; two for each row appended: its top-2 cells). The "
+    "host side of an update is the `ivf.apply` span (rows, "
+    "tombstones, spare_used, writes), each program's launch an "
+    "`ivf.apply.launch` span, the read-back of the cells an "
+    "`ivf.apply.wait`.",
+)
+declare_metric(
+    "counter", "vector_ivf_tombstoned_rows_total",
+    "IVF slab rows tombstoned on the device by the update programs "
+    "(row id -1, skipped by the probe as padding; two for each row "
+    "deleted or re-embedded).",
+)
+declare_metric(
+    "counter", "vector_ivf_apply_programs_total",
+    "Update programs a vector index launched to apply committed writes "
+    "to its device snapshot in place: one top-2 cell assignment and "
+    "one donating update per 64 pending rows (the ivf.apply.launch "
+    "spans).",
+)
+declare_metric(
+    "counter", "vector_ivf_rebuilds_total",
+    "Rebuilds of a vector index's device snapshot (corpus, IVF, upload) "
+    "on a search; per-reason split in the "
+    "vector_ivf_rebuilds_total{why=\"*\"} family.",
+)
+declare_metric(
+    "counter", "vector_ivf_rebuilds_total{why=\"*\"}",
+    "Per-reason split of vector_ivf_rebuilds_total: first (no snapshot "
+    "yet, or after a bulk load, a failed update or a stretch served by "
+    "the quantized engine), small (a snapshot under 4,096 rows, or a "
+    "sharded one, takes no writes in place), spare (its spare rows or "
+    "slabs ran out), dead (tombstoned rows passed a quarter of the live "
+    "ones).",
+)
+declare_metric(
+    "gauge", "vector_ivf_spare_slabs",
+    "Spare IVF slabs left in the newest device snapshot a vector index "
+    "built or updated: taken by cells as appended rows fill their last "
+    "slab; at 0 the next write that needs one rebuilds (why=spare).",
 )
 declare_metric(
     "gauge", "admission_inflight_queries",
