@@ -9,7 +9,21 @@ filter loop, worker/sort.go sortWithoutIndex).
 The candidates come as ONE flat uint32 array padded with UINT32_MAX
 plus their real count, in any order and with repeats (a level's rows
 laid end to end, as `setops.membership`'s flat form takes them): each
-is looked up on its own. Two uses:
+is looked up on its own, in the column's device form (`gather_keys`):
+
+- the TABLE form, `(table, base)`: `table[uid - base]` is the row's
+  key, -1 where the column holds no such uid; one gather a candidate.
+  query/valcol.py takes it where the column's uid span, rounded up to a
+  power of two, is at most twice its padded rows, so that the table is
+  no larger than the search form's two arrays (a predicate most of a
+  load's nodes carry: the loaders hand out uids in the order they meet
+  the nodes);
+- the SEARCH form, `(uids, rows, keys)`: the sorted uids padded as the
+  set ops pad, and each row's key; a searchsorted a candidate, which
+  sorts the column together with the candidates (`setops._searchsorted`
+  past its line), then two gathers.
+
+Both give every candidate the same (hit, key where hit). Two uses:
 
 - `column_filter`: mask of the candidates whose key lies in [lo, hi].
   The host turns a bound (lt/le/gt/ge/between a value) into that rank
@@ -28,7 +42,8 @@ in the order wanted (ranks here; float32 scores of a value variable in
 `Executor._order_uids_topk`, where rounding only adds ties).
 
 Static shapes, no data-dependent control flow; `need`, the bounds and
-the direction are runtime scalars, so one program per padded shape.
+the direction are runtime scalars (the table form's base too), so one
+program per padded shape and form.
 """
 
 from __future__ import annotations
@@ -39,18 +54,36 @@ import jax.numpy as jnp
 from dgraph_tpu.ops import setops
 
 
-def gather_keys(cand, n, uids, rows, keys):
-    """(hit, key): hit[i] = i < n and cand[i] is one of uids[:rows];
-    key[i] = that row's key (unspecified where not hit)."""
+def _search_keys(cand, uids, rows, keys):
     idx = setops._searchsorted(uids, cand)
     idx_c = jnp.minimum(idx, uids.shape[0] - 1)
     hit = (idx < rows) & (jnp.take(uids, idx_c) == cand)
-    return hit & setops._iota_mask(cand.shape[0], n), jnp.take(keys, idx_c)
+    return hit, jnp.take(keys, idx_c)
 
 
-def column_filter(cand, n, uids, rows, keys, lo, hi):
+def _table_keys(cand, table, base):
+    off = cand - base  # uint32: a candidate under `base` wraps past the table
+    size = table.shape[0]
+    key = jnp.take(table, jnp.minimum(off, jnp.uint32(size - 1)))
+    return (off < jnp.uint32(size)) & (key >= 0), key
+
+
+# a column's device forms by their operands: (lookup, how many)
+_FORMS = {"table": (_table_keys, 2), "search": (_search_keys, 3)}
+
+
+def gather_keys(cand, n, column):
+    """(hit, key): hit[i] = i < n and the column holds cand[i]; key[i] =
+    that row's key (unspecified where not hit). `column` is in either
+    form (module docstring), which its operands' count tells apart."""
+    lookup = _FORMS["table" if len(column) == 2 else "search"][0]
+    hit, key = lookup(cand, *column)
+    return hit & setops._iota_mask(cand.shape[0], n), key
+
+
+def column_filter(cand, n, column, lo, hi):
     """mask[i] = candidate i has a value whose rank is in [lo, hi]."""
-    hit, key = gather_keys(cand, n, uids, rows, keys)
+    hit, key = gather_keys(cand, n, column)
     return hit & (key >= lo) & (key <= hi)
 
 
@@ -92,10 +125,10 @@ def window_keep(key, valid, n, need):
     return keep, valued
 
 
-def column_narrow(cand, n, uids, rows, keys, need, desc):
+def column_narrow(cand, n, column, need, desc):
     """(keep, valued) for a leading order key: ranks ascend with the
     value, so ascending order wants the smallest."""
-    hit, key = gather_keys(cand, n, uids, rows, keys)
+    hit, key = gather_keys(cand, n, column)
     return window_keep(jnp.where(desc, key, -key), hit, n, need)
 
 
@@ -111,3 +144,18 @@ KERNELS = {
     "narrow": column_narrow,
     "scores": scores_narrow,
 }
+
+
+def program(use: str, form=None):
+    """`use`'s program with its arguments flat, as a dispatch passes
+    them: the candidates, their count, the operands of a column in
+    device form `form` (none for "scores"), then the use's scalars."""
+    fn = KERNELS[use]
+    if form is None:
+        return fn
+    width = _FORMS[form][1]
+
+    def flat(cand, n, *rest):
+        return fn(cand, n, rest[:width], *rest[width:])
+
+    return flat

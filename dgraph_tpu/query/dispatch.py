@@ -317,7 +317,7 @@ class SetOpDispatcher:
     """Batches pairwise sorted-set ops onto the device."""
 
     def __init__(self):
-        self._jit_cache: Dict[Tuple[str, int, int], object] = {}
+        self._jit_cache: Dict[tuple, object] = {}
         # serializes first-compilation per (op, shape) key: under
         # concurrent high-QPS traffic two queries hitting the same
         # cold bucket must not both pay the XLA compile
@@ -464,15 +464,16 @@ class SetOpDispatcher:
 
     def _run_device(
         self, family: str, fetch, operands, ids: int, padded: int,
-        keep=None, spans: str = "setop",
+        keep=None, spans: str = "setop", said=None,
     ) -> tuple:
         """What every device call shares after its operands are padded
         (`setop.pad`, at the call site, which also says how many real
         `ids` it pads to how many `padded` elements: counted here) and
         before its result is cut back into rows (`setop.split`, there
         too); a value column's programs open the same spans under
-        `valcol.` (`spans`), their launch naming its `use` where a set
-        op names its `family`: `setop.upload` of
+        `valcol.` (`spans`), their launch naming what `said` gives
+        (its `use` and the column's `form`) where a set op names its
+        `family`: `setop.upload` of
         the operands still on the host — a numpy array, or a `_Sharded`
         one for the mesh; a device array is a DeviceCache hit — and,
         through `keep(operands as the device holds them)`, the
@@ -503,10 +504,8 @@ class SetOpDispatcher:
             )
             if keep is not None:
                 keep(dev)
-        said = (
-            {"family": family} if spans == "setop"
-            else {"use": family.partition("#")[2]}
-        )
+        if said is None:
+            said = {"family": family}
         with TRACER.span(spans + ".launch", cpu=True, fine=True, **said):
             out = fetch()(*dev)
         # read for its wall time; its CPU time (the read-back's copy) is
@@ -671,14 +670,16 @@ class SetOpDispatcher:
     ) -> tuple:
         """One program of `ops/valcol.py` over `ids`, uint32 or float32,
         in any order: `use` "filter" or "narrow" against a resident
-        value column, `column` = (uids and keys as the device holds
-        them, rows, padded rows) with the ids' low 32 bits (query/
-        valcol.py sees to the high ones); "scores" with no column, the
-        ids then being float32 scores. Padded as the flat form pads, to
-        a power of four, so a cell's requests share one program. Spans
-        `valcol.pad` / `.upload` / `.launch` / `.wait`, family
-        `column#<use>`. Returns the program's outputs, the per-id ones
-        still padded."""
+        value column, `column` = (its device form, "table" or "search";
+        its operands as the program takes them; its padded size) with
+        the ids' low 32 bits (query/valcol.py sees to the high ones);
+        "scores" with no column, the ids then being float32 scores.
+        Padded as the flat form pads, to a power of four, so a cell's
+        requests share one program a form and padded size. Spans
+        `valcol.pad` / `.upload` / `.launch` (`use`, `form`) / `.wait`,
+        family `column#<use>`; a lookup in a column counts
+        `value_column_lookups_total{form}`. Returns the program's
+        outputs, the per-id ones still padded."""
         with TRACER.span("valcol.pad", cpu=True, fine=True) as sp:
             n = len(ids)
             pa = _pow4(n)
@@ -686,18 +687,20 @@ class SetOpDispatcher:
             A = np.full((pa,), fill, dtype=ids.dtype)
             A[:n] = ids
             sp.attrs.update(ids=n, padded=pa)
-        held, pb = (), 0
-        if column is not None:
-            uids, keys_, rows, pb = column
-            held = (uids, np.int32(rows), keys_)
-        return self._run_device(
+        form, held, size = column if column is not None else (None, (), 0)
+        said = {"use": use} if form is None else {"use": use, "form": form}
+        out = self._run_device(
             "column#" + use,
-            lambda: self._get_jitted_shared("column_" + use, pa, pb),
+            lambda: self._get_jitted_shared("column_" + use, pa, size, form),
             [A, np.int32(n), *held, *scalars],
             n,
             pa,
             spans="valcol",
+            said=said,
         )
+        if form is not None:
+            METRICS.inc(f'value_column_lookups_total{{form="{form}"}}')
+        return out
 
     def _union_rows_stacked(self, rows, b, row_tokens, b_token):
         """`union#shared`: a row's union needs the row's own output
@@ -1037,13 +1040,14 @@ class SetOpDispatcher:
                 out.append(join_segments({hi: kept}))
         return out
 
-    def _get_jitted_shared(self, op: str, pa: int, pb: int):
+    def _get_jitted_shared(self, op: str, pa: int, pb: int, form=None):
         """intersect / difference: ONE membership of the level's ids,
         flat (`pa` of them, padded), in `b`; the host keeps or drops by
         the mask. union: `setops.union` vmapped over a stack of rows
         `pa` wide, `b` unbatched. column_<use>: `ops/valcol.py`'s
-        program over `pa` flat ids and a column of `pb` rows."""
-        key = (op + "#shared", pa, pb)
+        program over `pa` flat ids and a column of padded size `pb` in
+        device form `form`."""
+        key = (op + "#shared", pa, pb, form)
         fn = self._jit_cache.get(key)
         if fn is None:
             with self._jit_lock:
@@ -1059,7 +1063,7 @@ class SetOpDispatcher:
                         # column is the shared operand
                         use = op[len("column_"):]
                         fn = setops.scoped(
-                            "valcol." + use, valcol.KERNELS[use]
+                            "valcol." + use, valcol.program(use, form)
                         )
                     else:
                         fn = setops.scoped(

@@ -24,7 +24,13 @@ predicate at least `_min_total()` candidates (the line that sends a
 set op to the device), by one scan of the predicate's data keys past
 the MemoryLayer; the device arrays live in the dispatcher's
 DeviceCache, under the predicate's data prefix, beside the set-op
-operands and inside the same byte bound. The column keeps a host copy
+operands and inside the same byte bound. A base goes up in one of two
+device forms (`ops/valcol.py`), chosen by its own shape wherever it goes
+up (a build, a merge, an upload after an eviction): a TABLE from uid
+to rank, one gather a lookup, where its uid span rounded up to a power
+of two is at most twice its padded rows (the table then takes no more
+of the device than the other form), else its SEARCH form, the sorted
+uids and their ranks. The column keeps a host copy
 of its uids and values, so arrays the cache evicted go up again with no
 scan. Once resident it also serves smaller candidate sets, from
 `_RESIDENT_MIN_IDS` up: half of an `snb.ic9` request's 47,000
@@ -129,20 +135,39 @@ def _key_dtype(tid):
 
 
 class Column:
-    """What the host keeps of a column: its base's timestamp and shape,
-    the distinct values its ranks index, and a copy of its rows (`uids`
+    """What the host keeps of a column: its base's timestamp and shape
+    (`size`: its table's, 0 where it went up in the search form), the
+    distinct values its ranks index, and a copy of its rows (`uids`
     their low 32 bits, `keys` their values' order keys) for a merge and
-    for an upload after an eviction; the two device arrays are the
+    for an upload after an eviction; the device arrays are the
     DeviceCache's, under `token`."""
 
     __slots__ = ("built_ts", "rows", "pb", "hi", "distinct", "token", "tid",
-                 "uids", "keys")
+                 "uids", "keys", "size")
 
     def __init__(self, built_ts, rows, pb, hi, distinct, token, tid=None,
-                 uids=None, keys=None):
+                 uids=None, keys=None, size=0):
         self.built_ts, self.rows, self.pb, self.hi = built_ts, rows, pb, hi
         self.distinct, self.token = distinct, token
-        self.tid, self.uids, self.keys = tid, uids, keys
+        self.tid, self.uids, self.keys, self.size = tid, uids, keys, size
+
+    @property
+    def form(self) -> str:
+        return "table" if self.size else "search"
+
+    @property
+    def nbytes(self) -> int:
+        """What its device arrays take: an int32 table, or uint32 uids
+        and int32 ranks."""
+        return self.size * 4 if self.size else self.pb * 8
+
+    def operands(self, arrays) -> tuple:
+        """The column as `run_column` takes it, from its device arrays:
+        (form, operands, padded size)."""
+        if self.size:
+            return "table", (*arrays, self.uids[0]), self.size
+        uids, keys_ = arrays
+        return "search", (uids, np.int32(self.rows), keys_), self.pb
 
     def rank_range(self, op: str, val: Val) -> Tuple[np.int32, np.int32]:
         """[lo, hi] of the ranks that pass `op val` (empty: hi < lo)."""
@@ -445,7 +470,7 @@ class ValueColumns:
                         or self._cols.get(prefix) is not col):
                     return
                 DISPATCHER.device_cache.put(new.token, [prefix], arrays,
-                                            new.pb * 8)
+                                            new.nbytes)
                 self._cols[prefix], self._prev[prefix] = new, col
                 log.trim(col.built_ts)
                 self._gauge()
@@ -475,32 +500,52 @@ def _fallback(why: str) -> None:
     METRICS.inc(f'value_column_fallback_total{{why="{why}"}}')
 
 
-def _device_arrays(uids: np.ndarray, rank: np.ndarray, pb: int):
-    """A column's two device arrays: the uids' low 32 bits padded as
-    the set ops pad, and each row's rank."""
+def _table_size(low: np.ndarray, pb: int, least: int = 0) -> int:
+    """The size of the table form of a base whose sorted low-32 uids are
+    `low`, padded to `pb` rows: the power of two that covers their span
+    (`least` at the least, a merge's old size, so that its program
+    stays), or 0 where that is over `2 * pb`, the search form's bytes
+    over the table's four a slot: the base then takes the search form."""
+    from dgraph_tpu.query.dispatch import _pow2
+
+    if not len(low):
+        return 0
+    size = max(least, _pow2(int(low[-1]) - int(low[0]) + 1))
+    return size if size <= 2 * pb else 0
+
+
+def _device_arrays(uids: np.ndarray, rank: np.ndarray, pb: int, size: int):
+    """A column's device arrays: where `size`, ONE int32 table of it,
+    each row's rank at its uid less the smallest and -1 in the slots no
+    uid holds; else the uids' low 32 bits padded as the set ops pad, and
+    each row's rank."""
     import jax.numpy as jnp
 
+    if size:
+        table = np.full((size,), -1, np.int32)
+        table[uids - uids[0]] = rank
+        return (jnp.asarray(table),)
     pad_k = np.zeros((pb,), np.int32)
     pad_k[: len(rank)] = rank
     return (jnp.asarray(setops.pad_sorted(uids, pb)), jnp.asarray(pad_k))
 
 
 def _upload(col: Column):
-    """A resident column's arrays again, from its host copy, after the
-    DeviceCache evicted them: no scan."""
+    """A resident column's arrays again, in its form, from its host
+    copy, after the DeviceCache evicted them: no scan."""
     from dgraph_tpu.query.dispatch import DISPATCHER
 
     arrays = _device_arrays(
-        col.uids, np.searchsorted(col.distinct, col.keys), col.pb)
+        col.uids, np.searchsorted(col.distinct, col.keys), col.pb, col.size)
     DISPATCHER.device_cache.put(col.token, [col.token[2]], arrays,
-                                col.pb * 8)
+                                col.nbytes)
     return arrays
 
 
 def _merged(cols: ValueColumns, prefix: bytes, col: Column, gen: int,
             top: int, delta: Optional[Delta]):
     """(Column, device arrays) of `col`'s rows with `delta` laid over
-    them: the base of a reader at `top`."""
+    them: the base of a reader at `top`, its form chosen anew."""
     from dgraph_tpu.query.dispatch import _pow2
 
     uids = (np.uint64(col.hi) << np.uint64(32)) | col.uids.astype(np.uint64)
@@ -518,14 +563,16 @@ def _merged(cols: ValueColumns, prefix: bytes, col: Column, gen: int,
     # the padded shape stays while the rows fit: a new one compiles anew
     pb = max(col.pb, _pow2(rows))
     low = uids.astype(np.uint32)
+    size = _table_size(low, pb, col.size)
     token = ("valcol", cols.uid, prefix, top, gen)
-    return (Column(top, rows, pb, col.hi, distinct, token, col.tid, low, vals),
-            _device_arrays(low, rank, pb))
+    return (Column(top, rows, pb, col.hi, distinct, token, col.tid, low, vals,
+                   size),
+            _device_arrays(low, rank, pb, size))
 
 
 def column_for(cache, st, ns: int, attr: str, lang: str, n: int):
-    """(Column, (device uids, device keys, rows, padded rows), Delta or
-    None) for `n` candidates of `attr` as this reader may see it, or
+    """(Column, the column as `run_column` takes it, Delta or None) for
+    `n` candidates of `attr` as this reader may see it, or
     None: the caller then reads value by value. `_min_total()`
     candidates build a column; one that is resident serves
     `_RESIDENT_MIN_IDS` or more."""
@@ -573,7 +620,7 @@ def _resident(cols, cache, prefix, build=None):
         arrays = DISPATCHER.device_cache.get(col.token)
         if arrays is None:
             arrays = _upload(col)  # evicted
-        return col, (*arrays, col.rows, col.pb), delta
+        return col, col.operands(arrays), delta
     if cache.read_ts < floor:
         _fallback("stale")
         return False
@@ -583,12 +630,12 @@ def _resident(cols, cache, prefix, build=None):
     if col is _UNFIT:
         _fallback("type")
         return False
-    return col, (*arrays, col.rows, col.pb), None
+    return col, col.operands(arrays), None
 
 
 def _build(cols, cache, prefix, gen, su, attr):
     """Scan the predicate at this reader's view, upload, publish unless
-    a drop came meanwhile. Returns (Column, (device uids, keys)) or
+    a drop came meanwhile. Returns (Column, its device arrays) or
     (_UNFIT, None)."""
     from dgraph_tpu.query.dispatch import DISPATCHER, _pow2
 
@@ -618,14 +665,16 @@ def _build(cols, cache, prefix, gen, su, attr):
         rows, pb = len(uid64), _pow2(len(uid64))
         # scanned in key order, so sorted
         low = uid64.astype(np.uint32)
-        arrays = _device_arrays(low, rank, pb)
+        size = _table_size(low, pb)
+        arrays = _device_arrays(low, rank, pb, size)
         token = ("valcol", cols.uid, prefix, cache.read_ts, gen)
         col = Column(cache.read_ts, rows, pb, int(his[0]), distinct, token,
-                     tid, low, key)
-        sp.attrs.update(rows=rows, bytes=pb * 8)
+                     tid, low, key, size)
+        sp.attrs.update(rows=rows, bytes=col.nbytes, form=col.form,
+                        span=int(low[-1]) - int(low[0]) + 1)
         METRICS.inc("value_column_builds_total")
         cols.publish(prefix, gen, col, lambda: DISPATCHER.device_cache.put(
-            token, [prefix], arrays, pb * 8))
+            token, [prefix], arrays, col.nbytes))
         return col, arrays
 
 

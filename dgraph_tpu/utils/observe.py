@@ -2825,6 +2825,15 @@ declare_metric(
     "and an eviction build nothing, so inside a steady window, writes "
     "and all, it reads 0.")
 declare_metric(
+    "counter", "value_column_lookups_total{form=\"*\"}",
+    "Lookups of candidates in a resident value column: one per "
+    "column#filter or column#narrow dispatch, by the device form the "
+    "column's base went up in (query/valcol.py): `table` (one gather "
+    "from uid to rank: a base whose uid span, rounded up to a power of "
+    "two, is at most twice its padded rows) or `search` (a searchsorted "
+    "over the sorted uids, which sorts the column with the candidates).",
+)
+declare_metric(
     "counter", "value_column_invalidations_total",
     "Resident value columns dropped because a change they cannot "
     "follow touched their predicate, before it became readable: a "

@@ -388,6 +388,99 @@ def test_the_window_cut_against_a_sort(kind):
         assert np.asarray(keep).tolist() == want.tolist(), (trial, need)
 
 
+# -- the column's two device forms -----------------------------------------
+
+TOP = 0xFFFFFFFF
+
+
+def _column_shape(name):
+    """(sorted low-32 uids, padded rows) of one column shape."""
+    rng = np.random.default_rng(4500 + len(name))
+
+    def some(lo, span, rows):  # `rows` of [lo, lo + span), both ends in
+        mid = rng.choice(np.arange(lo + 1, lo + span - 1), rows - 2,
+                         replace=False)
+        return np.sort(np.r_[lo, mid, lo + span - 1]).astype(np.uint32)
+
+    low = {
+        "dense": lambda: np.arange(7000, 7300, dtype=np.uint32),
+        "half_empty": lambda: some(90_000, 500, 250),
+        "at_the_line": lambda: some(5000, 256, 100),  # table == 2 * pb
+        "past_the_line": lambda: some(5000, 257, 100),
+        "one_row": lambda: np.array([77], np.uint32),
+        "lo_zero": lambda: some(0, 180, 150),
+        "top_uid": lambda: some(TOP - 299, 300, 260),
+    }[name]()
+    return low, dispatch._pow2(len(low))
+
+
+@pytest.mark.parametrize("shape", [
+    "dense", "half_empty", "at_the_line", "past_the_line", "one_row",
+    "lo_zero", "top_uid"])
+def test_the_table_form_answers_as_the_search_form(shape):
+    """Both device forms of one column give every candidate the same
+    filter mask and the same narrowing, bit for bit: candidates in any
+    order and repeated, under the smallest uid and past the span,
+    UINT32_MAX padding inside [0, n) (a candidate the delta holds),
+    and a column holding the largest low uid. A span wider than twice
+    the padded rows takes the search form; the table is built there
+    all the same, to hold it to the search form."""
+    import jax
+
+    low, pb = _column_shape(shape)
+    lo, span = int(low[0]), int(low[-1]) - int(low[0]) + 1
+    size = valcol._table_size(low, pb)
+    assert (size == 0) == (shape == "past_the_line"), (size, pb)
+    size = size or dispatch._pow2(span)
+    assert size == dispatch._pow2(span) >= span
+    rng = np.random.default_rng(45)
+    rank = rng.integers(0, 40, len(low)).astype(np.int32)
+    distinct = np.arange(40)
+
+    def column(size):
+        col = valcol.Column(0, len(low), pb, 0, distinct, None, uids=low,
+                            keys=distinct[rank], size=size)
+        return col.operands(valcol._device_arrays(low, rank, pb, size))
+
+    table, search = column(size), column(0)
+    assert (table[0], table[2], search[0], search[2]) == (
+        "table", size, "search", pb)
+    holes = np.setdiff1d(np.arange(lo, lo + span, dtype=np.uint32), low)
+    outside = [x % (1 << 32) for x in (lo - 1, lo - 1000, 0, lo + span,
+                                       lo + size, lo + size + 5, TOP - 1)]
+    cand = np.concatenate([
+        rng.choice(low, 700), rng.choice(holes, 100) if len(holes) else [],
+        outside]).astype(np.uint32)
+    cand = rng.permutation(cand)
+    cand[rng.choice(len(cand), 9, replace=False)] = TOP  # held by a delta
+    n = len(cand)
+    padded = np.full(dispatch._pow4(n), TOP, np.uint32)
+    padded[:n] = cand
+    held = np.isin(cand, low)
+    key = np.full(n, -1)
+    key[held] = rank[np.searchsorted(low, cand[held])]
+    run = {form: {use: jax.jit(kernels.program(use, form))
+                  for use in ("filter", "narrow")}
+           for form, ops, _ in (table, search)}
+
+    def both(use, *scalars):
+        return [[np.asarray(o) for o in jax.tree_util.tree_leaves(
+            run[form][use](padded, np.int32(n), *ops, *scalars))]
+            for form, ops, _ in (table, search)]
+
+    for a, b in ((0, 10), (5, 5), (20, np.iinfo(np.int32).max), (3, 2)):
+        got, want = both("filter", np.int32(a), np.int32(b))
+        assert got[0].tolist() == want[0].tolist()
+        passed = held & (key >= a) & (key <= b)
+        assert want[0][:n].tolist() == passed.tolist()
+        assert not want[0][n:].any()
+    for need in (1, 20, int(held.sum()) + 1):
+        for desc in (False, True):
+            got, want = both("narrow", np.int32(need), np.bool_(desc))
+            assert [g.tolist() for g in got] == [w.tolist() for w in want]
+            assert int(want[1]) == held.sum()
+
+
 @pytest.mark.parametrize("desc", [False, True])
 def test_a_value_var_order_is_cut_on_the_device_and_sorted_exactly(desc):
     """`_order_uids_topk`: float32 rounding makes ties of 2**24 and
@@ -625,18 +718,25 @@ def test_the_spans_of_a_column_dispatch_and_their_counters(
          "{ uid } }")
     server.query(q)  # the column is resident from here on
     before = METRICS.snapshot("device_dispatch_total")
+    looked = METRICS.snapshot("value_column_lookups_total")
     with TRACER.span("http.request") as root:
         server.query(q)
     after = METRICS.snapshot("device_dispatch_total")
+    looked = {k: v - looked.get(k, 0) for k, v in
+              METRICS.snapshot("value_column_lookups_total").items()}
     spans = TRACER.trace_spans(root.trace_id)
     names = [sp["name"] for sp in spans]
-    uses = [sp["attrs"]["use"] for sp in spans if sp["name"] == "valcol.launch"]
-    assert uses == ["filter", "narrow"]
-    for use in uses:
+    launches = [sp["attrs"] for sp in spans if sp["name"] == "valcol.launch"]
+    # `seen`: 480 uids of 1..600, a table of 1,024 slots against 512 rows
+    assert launches == [{"use": "filter", "form": "table"},
+                        {"use": "narrow", "form": "table"}]
+    for use in ("filter", "narrow"):
         key = f'device_dispatch_total{{family="column#{use}"}}'
         assert after[key] - before.get(key, 0) == 1
     assert after["device_dispatch_total"] - before[
         "device_dispatch_total"] == 2
+    assert looked['value_column_lookups_total{form="table"}'] == 2
+    assert not looked.get('value_column_lookups_total{form="search"}')
     for name in ("valcol.pad", "valcol.upload", "valcol.launch",
                  "valcol.wait"):
         assert names.count(name) == 2, name
@@ -646,8 +746,8 @@ def test_the_spans_of_a_column_dispatch_and_their_counters(
     assert pads[0]["ids"] == N and pads[0]["padded"] == 1024
     ups = [sp["attrs"] for sp in spans if sp["name"] == "valcol.upload"]
     assert pads[1]["padded"] == dispatch._pow4(pads[1]["ids"]) < 1024
-    assert ups == [{"bytes": 4 * p["padded"], "cache_hits": 2,
-                    "cache_misses": 1} for p in pads], ups
+    assert ups == [{"bytes": 4 * p["padded"], "cache_hits": 1,
+                    "cache_misses": 1} for p in pads], ups  # the table
     waits = [sp["attrs"] for sp in spans if sp["name"] == "valcol.wait"]
     assert waits[0]["bytes"] == 1024  # the mask
     assert waits[1]["bytes"] == pads[1]["padded"] + 4  # and the count
@@ -664,10 +764,54 @@ def test_the_build_is_a_span_with_its_size(line, monkeypatch):
     build = [sp for sp in TRACER.trace_spans(root.trace_id)
              if sp["name"] == "valcol.build"]
     assert len(build) == 1
-    assert build[0]["attrs"] == {"attr": "age", "rows": 200, "bytes": 2048}
+    assert build[0]["attrs"] == {"attr": "age", "rows": 200, "bytes": 1024,
+                                 "form": "table", "span": 200}
     assert METRICS.value("value_column_rows") >= 200
     total, count = METRICS.hist_stats("span_valcol.build_seconds")
     assert count >= 1 and total > 0
+
+
+@pytest.mark.parametrize("form", ["table", "search"])
+def test_a_column_takes_its_form_from_its_uid_span(line, monkeypatch, form):
+    """300 persons, every fifth without an `age`: on consecutive uids the
+    column goes up as a table (a span of 299 in 512 slots, against 256
+    padded rows), seven apart as its sorted uids (a span of 2,087).
+    Either form answers a filter and a narrowed order as the value loop
+    does, and every column dispatch counts one lookup of its form."""
+    step = 1 if form == "table" else 7
+    ages = {1 + step * i: (i * 37) % 41 for i in range(300) if i % 5}
+    s = Server()
+    s.alter("age: int .\nname: string @index(exact) .")
+    s.new_txn().mutate_rdf(set_rdf="\n".join(
+        f'<0x{u:x}> <name> "u{u}" .' + (
+            f'\n<0x{u:x}> <age> "{ages[u]}"^^<xs:int> .' if u in ages else "")
+        for u in (1 + step * i for i in range(300))), commit_now=True)
+    q = ("{ var(func: has(name)) @filter(lt(age, 20)) { m as uid } "
+         "q(func: uid(m), orderdesc: age, orderasc: name, first: 7) "
+         "{ uid } }")
+    lookups = ('value_column_lookups_total{form="table"}',
+               'value_column_lookups_total{form="search"}')
+    dispatches = ('device_dispatch_total{family="column#filter"}',
+                  'device_dispatch_total{family="column#narrow"}')
+    before = {c: METRICS.value(c) for c in lookups + dispatches}
+    with TRACER.span("http.request") as root:
+        got = _uids(s.query(q))
+    build = [sp["attrs"] for sp in TRACER.trace_spans(root.trace_id)
+             if sp["name"] == "valcol.build"]
+    assert build == [{"attr": "age", "rows": 240, "form": form,
+                      "span": max(ages) - min(ages) + 1,
+                      "bytes": 512 * 4 if form == "table" else 256 * 8}]
+    got_again = _uids(s.query(q))  # the column resident
+    moved = {c: METRICS.value(c) - before[c] for c in before}
+    assert moved[f'value_column_lookups_total{{form="{form}"}}'] == 4
+    assert sum(moved[c] for c in lookups) == sum(
+        moved[c] for c in dispatches)
+    want = sorted((u for u in ages if ages[u] < 20),
+                  key=lambda u: (-ages[u], f"u{u}"))[:7]
+    assert got == got_again == want
+    _off(monkeypatch)
+    old, moved = _moved(lambda: _uids(s.query(q)))
+    assert old == want and not any("column" in c for c in moved)
 
 
 # -- complex read 9 as the benchmark sends it ------------------------------

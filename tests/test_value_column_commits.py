@@ -218,6 +218,58 @@ def test_a_merge_keeps_what_readers_of_the_old_base_hold(monkeypatch):
     assert got.tolist() == [ages.get(int(u), 99) < 20 for u in ids]
 
 
+@pytest.mark.parametrize("how", ["merge", "upload"])
+@pytest.mark.parametrize("step", [1, 5])
+def test_a_merge_and_an_upload_keep_the_form_and_the_answers(
+        monkeypatch, how, step):
+    """A base goes up again in the form its shape chooses: a merge of
+    the delta into a new base (`_merged`) and an upload from the host
+    copy after the DeviceCache lost the arrays (`_upload`) keep a dense
+    column's table (534 uids over a span of 600: 1,024 slots) and a
+    spread one's sorted uids (a span of 2,996 over 1,024 padded rows),
+    and every reader's answers."""
+    from dgraph_tpu.query.dispatch import DISPATCHER
+
+    monkeypatch.setattr(dispatch, "_DEVICE_MIN_TOTAL", LINE)
+    form = "table" if step == 1 else "search"
+    ages = {step * u: a for u, a in _ages0().items()}
+    names = {step * u for u in range(1, N + 1)}
+    s = Server()
+    s.alter(SCHEMA)
+    s.new_txn().mutate_rdf(set_rdf="\n".join(
+        f'<0x{u:x}> <name> "u{u}" .'
+        + (f'\n<0x{u:x}> <age> "{ages[u]}"^^<xs:int> .' if u in ages else "")
+        for u in sorted(names)), commit_now=True)
+    assert _uids(s.query(_text("lt"))) == _want("lt", ages, names)
+    cols = s.mem.value_columns
+    prefix = keys.DataPrefix("age", keys.GALAXY_NS)
+    base = cols._cols[prefix]
+    assert (base.form, base.size) == (form, 1024 if step == 1 else 0)
+    if how == "merge":
+        # a new uid past the span and a changed value, then the merge
+        monkeypatch.setattr(valcol, "_DELTA_ROWS_MAX", 1)
+        rdf = (f'<0x{step * 601:x}> <name> "u{step * 601}" .\n'
+               f'<0x{step * 601:x}> <age> "3"^^<xs:int> .\n'
+               f'<0x{step * 7:x}> <age> "49"^^<xs:int> .')
+        s.new_txn().mutate_rdf(set_rdf=rdf, commit_now=True)
+        _settled(cols)
+        assert cols._prev[prefix] is base
+        ages = {**ages, step * 601: 3, step * 7: 49}
+        names = names | {step * 601}
+    else:
+        DISPATCHER.device_cache.invalidate([prefix])  # as an eviction would
+    for kind in (*FILTERS, "desc"):
+        got, moved = _moved(lambda: _uids(s.query(_text(kind))))
+        assert got == _want(kind, ages, names), kind
+        assert "value_column_builds_total" not in moved
+    col = cols._cols[prefix]
+    assert (col.form, col.size) == (form, base.size)
+    assert (col is base) == (how == "upload")
+    arrays = DISPATCHER.device_cache.get(col.token)
+    assert len(arrays) == (1 if step == 1 else 2)
+    assert DISPATCHER.device_cache._entries[col.token].nbytes == col.nbytes
+
+
 def test_the_delta_span_on_every_use(monkeypatch):
     monkeypatch.setattr(dispatch, "_DEVICE_MIN_TOTAL", LINE)
     from dgraph_tpu.utils import observe
